@@ -145,7 +145,7 @@ class TestCheckpoint:
         assert __import__("os").path.isdir(staging)
         assert not __import__("os").path.isdir(path)  # nothing partial
 
-        # Retry with a healthy tunnel: staged leaves are reused. The
+        # Retry with a healthy device: staged leaves are reused. The
         # simulated wedge carried no orphan thread, so the suspect
         # stamp needs the operator override (a real timeout's orphan
         # finishes and ensure_writable clears the flag itself).
@@ -230,7 +230,7 @@ class TestCheckpoint:
 
     def test_wedged_slab_fails_fast_with_bounded_lock_hold(
             self, tmp_path, monkeypatch):
-        """ADVICE r5 #2 regression: the FIRST slab timeout must fail
+        """Regression: the FIRST slab timeout must fail
         the save immediately — no retry/backoff while the
         writer-blocking read lock is held (the retry enqueues behind
         the wedged transfer and can never succeed until it clears, so
@@ -254,7 +254,7 @@ class TestCheckpoint:
             calls["n"] += 1
             if deadline_s is not None and calls["n"] > 3:
                 # Slow fake device: block for the full deadline the
-                # way a wedged tunnel does, then surface the timeout.
+                # way a blocked transfer does, then surface the timeout.
                 calls["wedged"] += 1
                 time.sleep(deadline_s)
                 err = TimeoutError("simulated slow device")

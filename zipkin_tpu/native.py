@@ -62,19 +62,29 @@ class _SpanColumns(ctypes.Structure):
     )]
 
 
-def _build(force: bool = False) -> str:
+def build(force: bool = False) -> str:
+    """Compile native/span_codec.cc into the shared object (skipped
+    when the .so is newer than the source, unless ``force``)."""
     if not force and os.path.exists(_SO) and (
         os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
     ):
         return _SO
+    # Link to a private name, then rename over the target: a process
+    # that dlopens concurrently (xdist workers, a running daemon) sees
+    # the old object or the new one, never a half-written file.
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O3", "-Wall", "-shared", "-fPIC", "-std=c++17",
-             "-o", _SO, _SRC],
+             "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120,
         )
+        os.replace(tmp, _SO)
     except (OSError, subprocess.SubprocessError) as e:
         raise NativeUnavailable(f"could not build native codec: {e}") from e
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
     return _SO
 
 
@@ -83,11 +93,11 @@ def _load() -> ctypes.CDLL:
     a stale or wrong-arch .so from a previous checkout must fall through
     to a fresh build, and a still-failing load must surface as
     NativeUnavailable so callers engage the pure-python fallback."""
-    path = _build()
+    path = build()
     try:
         return ctypes.CDLL(path)
     except OSError:
-        path = _build(force=True)
+        path = build(force=True)
         try:
             return ctypes.CDLL(path)
         except OSError as e:

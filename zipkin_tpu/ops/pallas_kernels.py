@@ -66,7 +66,7 @@ def _hist_kernel(idx_ref, w_ref, out_ref):
     # ``idx_ref[t]`` with a loop-carried ``t`` lowers cleanly — the
     # round-3 VMEM variant's dynamic LANE index was what Mosaic rejected
     # ("cannot statically prove index in dimension 2 is a multiple of
-    # 128", NOTES_r03.md §6), and a rank-2 (1, tile) SMEM block trips
+    # 128"), and a rank-2 (1, tile) SMEM block trips
     # the block-shape rule (second-to-last dim must be divisible by 8 or
     # equal the array dim). Rank-1 blocks only constrain the LAST dim
     # (tile % 128 == 0, asserted by the caller). The output stays
@@ -174,7 +174,7 @@ def cms_update(counts, idx_rows, weights=None, tile: int = DEFAULT_TILE):
 # for the SMEM row tiles and compiler temporaries inside the ~16 MB
 # core budget.
 ARENA_VMEM_BUDGET = 10 << 20
-ARENA_TILE = 512
+ARENA_TILE = 1024
 
 
 def arena_scatter_supported(total_slots: int, n_buckets: int) -> bool:
@@ -223,10 +223,13 @@ def _arena_kernel(bucket_ref, base_ref, slot0_ref, dmask_ref, valid_ref,
         @pl.when(valid_ref[t] != 0)
         def _():
             b = bucket_ref[t]
-            onehot_b = (lane == (b & 127)).astype(jnp.int32)
+            at_b = lane == (b & 127)
             crow = cur_ref[pl.ds(b >> 7, 1), :]
-            c = jnp.sum(crow * onehot_b)
-            cur_ref[pl.ds(b >> 7, 1), :] = crow + onehot_b
+            # Lane extract by masked MAX, not sum: cursors are >= 0, and
+            # an i32 sum promotes to i64 under the package's x64 mode,
+            # which Mosaic refuses ("64-bit types are not supported").
+            c = jnp.max(jnp.where(at_b, crow, jnp.int32(0)))
+            cur_ref[pl.ds(b >> 7, 1), :] = crow + at_b.astype(jnp.int32)
             # The claim: this row's FIFO slot, from the bucket's live
             # cursor. Writes land in arrival order, so an in-batch
             # overflow row is overwritten by its newest same-slot

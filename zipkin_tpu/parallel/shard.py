@@ -46,6 +46,20 @@ def _stack_states(config: dev.StoreConfig, n: int):
 DEP_SUMMARY_K = 1 << 14  # the single-chip deps-read compaction bound
 
 
+def _pmax64(x, axis: str):
+    """``lax.pmax`` for 64-bit values. The TPU lowers only SUM
+    all-reduces over 64-bit types ("Supported lowering only of Sum all
+    reduce" — refused at compile time, found by the chipless four-chip
+    compile in PR 22), so gather the shard values and reduce locally:
+    same result, n tiny rows of traffic."""
+    return jnp.max(jax.lax.all_gather(x, axis), axis=0)
+
+
+def _pmin64(x, axis: str):
+    """``lax.pmin`` twin of :func:`_pmax64`."""
+    return jnp.min(jax.lax.all_gather(x, axis), axis=0)
+
+
 def _summarize(state: dev.StoreState, axis: str,
                dep_k: int = DEP_SUMMARY_K) -> Dict[str, jnp.ndarray]:
     """Cross-shard global aggregates, computed inside shard_map."""
@@ -96,8 +110,8 @@ def _summarize(state: dev.StoreState, axis: str,
         "ann_svc_counts": ann_svc_counts,
         "hll_traces": hll_regs,
         "dep_moments": dep_moments,
-        "ts_min": jax.lax.pmin(state.ts_min, axis),
-        "ts_max": jax.lax.pmax(state.ts_max, axis),
+        "ts_min": _pmin64(state.ts_min, axis),
+        "ts_max": _pmax64(state.ts_max, axis),
     }
 
 
@@ -917,10 +931,10 @@ class ShardedSpanStore(WindowedAnalytics, SuspectGuard):
                 st = self._unstack(state)
                 mat = dev.query_durations(st, qids)
                 return jnp.stack([
-                    jax.lax.pmax(mat[0], self.axis),
-                    jax.lax.pmax(mat[1], self.axis),
-                    jax.lax.pmin(mat[2], self.axis),
-                    jax.lax.pmax(mat[3], self.axis),
+                    _pmax64(mat[0], self.axis),
+                    _pmax64(mat[1], self.axis),
+                    _pmin64(mat[2], self.axis),
+                    _pmax64(mat[3], self.axis),
                 ])
 
             return jax.jit(compat_shard_map(
@@ -940,10 +954,10 @@ class ShardedSpanStore(WindowedAnalytics, SuspectGuard):
                 st = self._unstack(state)
                 mat, exact = dev.iquery_durations(st, qids)
                 merged = jnp.stack([
-                    jax.lax.pmax(mat[0], self.axis),
-                    jax.lax.pmax(mat[1], self.axis),
-                    jax.lax.pmin(mat[2], self.axis),
-                    jax.lax.pmax(mat[3], self.axis),
+                    _pmax64(mat[0], self.axis),
+                    _pmax64(mat[1], self.axis),
+                    _pmin64(mat[2], self.axis),
+                    _pmax64(mat[3], self.axis),
                 ])
                 all_exact = jax.lax.pmin(
                     exact.astype(jnp.int32), self.axis
@@ -1538,9 +1552,9 @@ class ShardedSpanStore(WindowedAnalytics, SuspectGuard):
                 # ts range rides the same launch — running the full
                 # summary kernel just to clip two scalars would
                 # all-reduce every catalog array per windowed query.
-                ts_min = jnp.maximum(jax.lax.pmin(st.ts_min, self.axis),
+                ts_min = jnp.maximum(_pmin64(st.ts_min, self.axis),
                                      start_ts)
-                ts_max = jnp.minimum(jax.lax.pmax(st.ts_max, self.axis),
+                ts_max = jnp.minimum(_pmax64(st.ts_max, self.axis),
                                      end_ts)
                 return M.reduce_moments(banks, axis=0), ts_min, ts_max
 
