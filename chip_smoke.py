@@ -83,8 +83,11 @@ def free_port() -> int:
 
 
 def tail(path: str, n: int = 40) -> str:
+    """Last lines of a daemon log, minus XLA:CPU's page-long notices
+    about cached AOT results (rehearsals only; they bury the cause)."""
     with open(path, errors="replace") as f:
-        return "".join(f.readlines()[-n:])
+        lines = [ln for ln in f if "cpu_aot_loader.cc" not in ln]
+    return "".join(lines[-n:])
 
 
 # -- the daemon child --------------------------------------------------------
@@ -292,12 +295,12 @@ def compare_reads(daemon: Daemon, oracle: InMemorySpanStore,
     # the last window overlaps the previous one instead of shrinking).
     width = min(EXIST_BATCH, len(trace_ids))
     for lo in range(0, len(trace_ids), width):
-        batch = trace_ids[max(0, min(lo, len(trace_ids) - width)):][:width]
-        got = daemon.get_json(
-            "/api/traces_exist",
-            {"traceIds": ",".join(hex_id(t) for t in batch)})
-        if got["exist"] != sorted(hex_id(t) for t in batch):
-            missing = set(hex_id(t) for t in batch) - set(got["exist"])
+        lo = min(lo, len(trace_ids) - width)
+        batch = sorted(hex_id(t) for t in trace_ids[lo:lo + width])
+        got = daemon.get_json("/api/traces_exist",
+                              {"traceIds": ",".join(batch)})
+        if got["exist"] != batch:
+            missing = set(batch) - set(got["exist"])
             raise AssertionError(
                 f"/api/traces_exist: {len(missing)} acked traces "
                 f"unreadable, e.g. {sorted(missing)[:3]}")
@@ -341,31 +344,26 @@ def scrape(daemon: Daemon) -> dict:
     return out
 
 
-def metric(samples: dict, name: str) -> float:
-    if name not in samples:
-        raise KeyError(f"/metrics lacks {name}")
-    return samples[name]
-
-
 def report_metrics(daemon: Daemon, shards: int) -> None:
+    """Print the listed observables; a sample /metrics lacks is a
+    KeyError naming it."""
     m = scrape(daemon)
 
     def counter(name):
-        return metric(m, f'zipkin_store_counter{{name="{name}"}}')
+        return m[f'zipkin_store_counter{{name="{name}"}}']
 
     if not shards:
         # Single-device store observables (the sharded store exports
         # neither): compiles so far, and which rank / arena-scatter
         # implementations its compiled steps took (dev.active_paths).
-        say("jit_compiles_total",
-            int(metric(m, "zipkin_store_jit_compiles_total")))
+        say("jit_compiles_total", int(m["zipkin_store_jit_compiles_total"]))
         say("rank_path_counting", int(counter("rank_path_counting")))
         say("scatter_path_pallas", int(counter("scatter_path_pallas")))
     say("ring_occupancy", int(counter("ring_occupancy")))
-    say("wal_records_total", int(metric(m, "zipkin_wal_records_total")))
+    say("wal_records_total", int(m["zipkin_wal_records_total"]))
     for k in range(shards):
         # State on every device, not N shards on the first one.
-        occ = metric(m, f'zipkin_shard_occupancy{{shard="{k}"}}')
+        occ = m[f'zipkin_shard_occupancy{{shard="{k}"}}']
         say(f"shard_occupancy[{k}]", int(occ))
         if occ <= 0:
             raise AssertionError(f"shard {k} holds no spans")

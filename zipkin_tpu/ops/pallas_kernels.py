@@ -174,6 +174,9 @@ def cms_update(counts, idx_rows, weights=None, tile: int = DEFAULT_TILE):
 # for the SMEM row tiles and compiler temporaries inside the ~16 MB
 # core budget.
 ARENA_VMEM_BUDGET = 10 << 20
+# SMEM row tile. 1024 matches the T(1024) layout XLA gives 1-D i32
+# operands; Mosaic refused 512 ("XLA layout does not match Mosaic
+# layout") the first time the kernel met the TPU compiler (PR 22).
 ARENA_TILE = 1024
 
 
