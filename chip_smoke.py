@@ -63,7 +63,9 @@ N_WAVES = 3
 N_SERVICES = 64  # inside the daemon's default max_services=256
 END_TS = 2_000_000_000_000  # fixed query horizon, past every span
 QUERY_LIMIT = 10
-EXIST_BATCH = 1024
+# Ids per /api/traces_exist probe: a 35 KB URL (the server caps a
+# request line at 64 KB); fewer, wider probes — each may scan the ring.
+EXIST_BATCH = 2048
 CHECK_SERVICES = 16  # services compared per route after the last wave
 CHECK_TRACES = 32  # whole traces compared after the last wave
 BOOT_DEADLINE_S = 600.0  # generous: backend init + state allocation

@@ -154,7 +154,15 @@ _SLOW_TESTS = {
 }
 
 
+# The suite's CPU-hungriest files (TPU compiles for a described chip; a
+# daemon child plus a g++ build). They run after everything else, so
+# they never share the first minutes with bench_smoke's paired-timing
+# gates. A stable sort: every xdist worker collects the same order.
+_LAST_FILES = {"test_chip_compile.py", "test_chip_smoke.py"}
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
         if item.originalname in _SLOW_TESTS or item.name in _SLOW_TESTS:
             item.add_marker(pytest.mark.slow)
+    items.sort(key=lambda item: item.path.name in _LAST_FILES)
