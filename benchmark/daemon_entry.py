@@ -1,0 +1,59 @@
+"""Entry of the daemon child: ``zipkin_tpu.main.example.main`` itself,
+with two things round it that only the process on the chip can do.
+
+- At exit it writes the device's memory statistics (the peak on the
+  fullest chip) where the parent asked: the parent never touches JAX.
+- ``--fault NAME`` plants one of ``benchmark/tests/faults.py``'s broken
+  guarantees in the program before it starts. Only the control runs and
+  the tests pass it; a benchmark run never does.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+
+
+def write_memory_report(path: str) -> None:
+    import jax
+
+    peaks, kinds = [], []
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        peaks.append(int(stats.get("peak_bytes_in_use", 0)))
+        kinds.append(d.device_kind)
+    tmp = path + ".tmp"
+    with open(tmp, "w") as f:
+        json.dump({"memory_peak_bytes": max(peaks, default=0),
+                   "per_device": peaks, "kinds": kinds}, f)
+    os.replace(tmp, path)
+
+
+def main() -> None:
+    p = argparse.ArgumentParser()
+    p.add_argument("--memory-report", required=True)
+    p.add_argument("--fault", default="")
+    p.add_argument("rest", nargs=argparse.REMAINDER)
+    args = p.parse_args()
+    rest = args.rest[1:] if args.rest[:1] == ["--"] else args.rest
+    if args.fault:
+        import importlib.util
+
+        spec = importlib.util.spec_from_file_location(
+            "bench_faults", os.path.join(
+                os.path.dirname(os.path.abspath(__file__)),
+                "tests", "faults.py"))
+        faults = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(faults)
+        faults.plant(args.fault)
+    from zipkin_tpu.main import example
+
+    try:
+        example.main(rest)
+    finally:
+        write_memory_report(args.memory_report)
+
+
+if __name__ == "__main__":
+    main()

@@ -1,0 +1,440 @@
+"""Run one cell of BENCHMARK.json once.
+
+    python benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+
+The parent makes the span stream from the seed, starts the daemon child
+(the only process on the chip), warms the cell's shapes, drives the
+window through the daemon's two front doors (scribe TCP ``Log`` and
+HTTP GET), then compares what is read back with the plain reference
+and prints one JSON line. This parent never initialises a JAX backend.
+Nothing here names a cell: configurations, traffic mixes and per-layer
+metrics are files found by the names in BENCHMARK.json.
+
+``--platform cpu --capacity N`` is the rehearsal (README.md): the child
+on the CPU at a small ring; the last line then says ``cpu``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import importlib
+import json
+import math
+import os
+import shutil
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+
+os.environ["JAX_PLATFORMS"] = "cpu"  # the parent stays off the chip
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+sys.path.insert(0, HERE)
+
+import numpy as np  # noqa: E402
+
+import compare as compare_mod  # noqa: E402
+from daemon import Daemon, tail  # noqa: E402
+from gen import Stream  # noqa: E402
+from loadgen import Ingest, Reads, percentile  # noqa: E402
+from reference import Reference, hex_id  # noqa: E402
+
+BOOT_DEADLINE_S = 600.0
+STOP_DEADLINE_S = 300.0
+T_START = time.monotonic()
+
+
+def say(msg: str) -> None:
+    print(f"[{time.monotonic() - T_START:7.1f}s] {msg}", file=sys.stderr,
+          flush=True)
+
+
+def load_json(rel: str) -> dict:
+    with open(os.path.join(ROOT, rel)) as f:
+        return json.load(f)
+
+
+def build_codec() -> None:
+    """Build the native codec from native/span_codec.cc where the shared
+    object is missing or older than the source (the program's own rule;
+    a checkout has none, so its first run builds what git committed), in
+    a child, so that this process imports nothing of the program."""
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "from zipkin_tpu import native; native.build(); native.get_lib()"],
+        cwd=ROOT, capture_output=True, text=True,
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "PYTHONPATH": ROOT + os.pathsep
+             + os.environ.get("PYTHONPATH", "")})
+    if r.returncode != 0:
+        raise RuntimeError("native codec did not build:\n" + r.stderr[-2000:])
+
+
+def wait_visible(daemon, ref: Reference, frames: list,
+                 deadline_s: float = 600.0) -> float:
+    """An ack means durably appended, not yet committed. Wait until the
+    last span of each of the newest acked calls reads back whole."""
+    c = ref.stream.call_spans
+    t0 = time.monotonic()
+    for f in frames:
+        tid = ref.stream.trace_id_at(f * c + c - 1)
+        want = len(ref.trace(tid))
+        while True:
+            status, body = daemon.request("GET", f"/api/trace/{hex_id(tid)}")
+            if status == 200 and len(json.loads(body)) >= want:
+                break
+            if status not in (200, 404):
+                raise RuntimeError(f"trace read -> {status}: {body[:300]!r}")
+            if time.monotonic() - t0 > deadline_s:
+                raise TimeoutError("acked spans did not become readable")
+            daemon.check_alive()
+            time.sleep(0.1)
+    return time.monotonic() - t0
+
+
+def warm_reads(reads: Reads, spec: dict) -> int:
+    """Each route of the mix, through the window's own request path,
+    until its latency stops falling."""
+    w = spec.get("warm", {"min": 2, "max": 8})
+    n = 0
+    by_route = {}
+    for j in range(len(reads.kind)):
+        by_route.setdefault(reads.names[reads.kind[j]], []).append(j)
+    for route, js in by_route.items():
+        prev = None
+        for i, j in enumerate(js[:w["max"]]):
+            took = reads.get(j)
+            n += 1
+            if i + 1 >= w["min"] and prev is not None and took > 0.7 * prev:
+                break
+            prev = took
+    bad = [r for r in reads.records if r[5] != 200]
+    if bad:
+        raise RuntimeError(f"warm-up read failed: {bad[:3]}")
+    reads.records.clear()
+    return n
+
+
+def profile(daemon, seconds: float, out: dict) -> None:
+    try:
+        out["t0"] = time.monotonic()
+        status, body = daemon.request(
+            "POST", "/debug/profile", {"seconds": seconds})
+        out["t1"] = time.monotonic()
+        if status != 200:
+            raise RuntimeError(f"/debug/profile -> {status}: {body[:300]!r}")
+        out["dir"] = json.loads(body)["profileDir"]
+    except BaseException as e:
+        out["error"] = e
+
+
+def end_to_end(ingest, reads, window_s: float, t_end: float,
+               setup_s: float, call_spans: int) -> dict:
+    """Every end-to-end metric this traffic yields, over all the work of
+    the window: every call, every read, the whole window's seconds."""
+    out = {"setup_s": (setup_s, "s")}
+    calls = ingest.records
+    if calls:
+        lat = [(r[3] - (r[1] if r[1] is not None else r[2])) * 1e3
+               for r in calls if r[5]]
+        out["ack_p95_ms"] = (percentile(lat, 0.95), "ms")
+        in_window = sum(1 for r in calls if r[5] and r[3] <= t_end)
+        out["acked_spans_per_s"] = (in_window * call_spans / window_s,
+                                    "spans/s")
+    if reads is not None and reads.records:
+        lat = [(r[4] - r[2]) * 1e3 for r in reads.records if r[5] == 200]
+        out["read_p50_ms"] = (percentile(lat, 0.5), "ms")
+        out["read_p95_ms"] = (percentile(lat, 0.95), "ms")
+    return out
+
+
+def client_counts(ingest, reads, window_s, t_end, call_spans, prof) -> dict:
+    """The load generator's own counts and clocks, for the per-layer
+    readers (``{"client": name}`` terms)."""
+    calls = ingest.records
+    c = {
+        "window_s": window_s,
+        "log_calls_sent": ingest.sent_calls,
+        "try_later": ingest.try_later,
+        "acked_calls": sum(1 for r in calls if r[5]),
+        "acked_spans": sum(1 for r in calls if r[5]) * call_spans,
+        "acked_spans_in_window": sum(
+            1 for r in calls if r[5] and r[3] <= t_end) * call_spans,
+        "offered_calls": (math.ceil(window_s / ingest.interval)
+                          if ingest.interval else len(calls)),
+    }
+    late = [(r[2] - r[1]) * 1e3 for r in calls if r[1] is not None]
+    if late:
+        c["ingest_late_p95_ms"] = percentile(late, 0.95)
+    if reads is not None:
+        c["reads"] = len(reads.records)
+        c["reads_ok"] = sum(1 for r in reads.records if r[5] == 200)
+        rl = [(r[3] - r[2]) * 1e3 for r in reads.records]
+        if rl:
+            c["read_late_p95_ms"] = percentile(rl, 0.95)
+    if prof and "t1" in prof:
+        a, b = prof["t0"], prof["t1"]
+        c["traced_s"] = b - a
+        c["traced_acked_spans"] = sum(
+            1 for r in calls if r[5] and a <= r[3] <= b) * call_spans
+        if reads is not None:
+            c["traced_reads"] = sum(
+                1 for r in reads.records if a <= r[4] <= b)
+    return c
+
+
+def per_layer(bench: dict, workload: str, ctx: dict) -> dict:
+    """Each per-layer metric of this cell from its own file: the reader
+    named there is found by name under readers/."""
+    out = {}
+    sys.path.insert(0, os.path.join(HERE, "readers"))
+    for m in bench["per_layer"]:
+        if "workloads" in m and workload not in m["workloads"]:
+            continue
+        spec = load_json(os.path.join(
+            bench["paths"][0], "layer_metrics", m["name"] + ".json"))
+        reader = importlib.import_module(spec["source"]["reader"])
+        value = reader.read(spec["source"], ctx)
+        if value is not None:
+            out[m["name"]] = {"value": value, "unit": m["unit"]}
+        else:
+            say(f"per-layer {m['name']}: nothing to read")
+    return out
+
+
+def run_cell(args) -> dict:
+    bench = load_json("BENCHMARK.json")
+    cell = next((w for w in bench["workloads"]
+                 if w["name"] == args.workload), None)
+    if cell is None:
+        raise SystemExit(f"no workload {args.workload!r} in BENCHMARK.json")
+    conf_entry = next(c for c in bench["configs"]
+                      if c["name"] == cell["config"])
+    config = load_json(conf_entry["file"])
+    traffic = load_json(os.path.join(
+        bench["paths"][0], "traffic", cell["traffic"] + ".json"))
+    flags = list(config["daemon_flags"])
+    if args.capacity:
+        flags[flags.index("--capacity") + 1] = str(args.capacity)
+    platform = args.platform or config["platform"]
+    seconds = float(args.seconds)
+    c = traffic["call_spans"]
+    prefill = args.prefill_spans or traffic["prefill_spans"]
+    stream_spans = args.stream_spans or traffic["stream_spans"]
+    ing_spec, rd_spec = traffic["ingest"], traffic.get("reads")
+    if args.ingest_rate:
+        ing_spec = {**ing_spec, "spans_per_s": args.ingest_rate}
+    read_rate = args.read_rate or (rd_spec or {}).get("per_s")
+
+    build_codec()
+    # Inside the checkout, never /tmp: TMPDIR is the driver's per-side
+    # directory and the WAL of a run is a few hundred MB.
+    workdir = tempfile.mkdtemp(prefix="bench_run_")
+    daemon = Daemon(flags, platform, workdir, fault=args.fault)
+    say(f"daemon spawned; workdir {workdir}")
+    try:
+        t0 = time.monotonic()
+        stream = Stream(args.seed, min(traffic["pool_spans"], stream_spans),
+                        stream_spans, c, traffic["n_services"],
+                        traffic["pass_shift_us"])
+        n_prefill = prefill // c
+        stream.make_frames(n_prefill)
+        say(f"stream: {stream.n_spans} spans in {stream.n_frames} calls of "
+            f"{c}; pool and the warm-up's {n_prefill} frames made in "
+            f"{time.monotonic() - t0:.1f}s")
+        device = daemon.wait_boot(BOOT_DEADLINE_S)
+        say(f"boot line after {time.monotonic() - daemon.t_spawn:.1f}s: "
+            f"{device}")
+        if device["platform"] != platform:
+            raise RuntimeError(
+                f"the store's state is on {device['platform']!r}, "
+                f"not on {platform!r}")
+        if device["count"] != cell["chips"]:
+            raise RuntimeError(f"the cell asks for {cell['chips']} chip(s); "
+                               f"the state spans {device['count']}")
+
+        # -- warm-up: this cell's shapes, through the window's own doors --
+        ingest = Ingest(daemon.scribe_port, stream, ing_spec,
+                        daemon.check_alive)
+        t0 = time.monotonic()
+        ingest.run(0, n_prefill)
+        stream.make_frames()  # the window's, while the warm-up is sent
+        say(f"all frames made {time.monotonic() - t0:.1f}s into the warm-up")
+        ingest.join()
+        if any(not r[5] for r in ingest.records):
+            raise RuntimeError("a warm-up Log call was never acked")
+        acked = [r[0] for r in ingest.records]
+        ref = Reference(stream, acked)
+        lag = wait_visible(daemon, ref, sorted(acked)[-ing_spec[
+            "connections"]:])
+        say(f"prefill: {n_prefill} calls acked and visible in "
+            f"{time.monotonic() - t0:.1f}s (try_later {ingest.try_later})")
+        ingest.records.clear()
+        ingest.try_later = ingest.sent_calls = 0
+        rng = np.random.default_rng([int(args.seed), 0xBEAD])
+        reads = None
+        if rd_spec:
+            reads = Reads(daemon.http_port, stream, rd_spec, rng, ingest)
+            t0 = time.monotonic()
+            n = warm_reads(reads, rd_spec)
+            say(f"read warm-up: {n} reads in {time.monotonic() - t0:.1f}s")
+
+        # -- the window ----------------------------------------------------
+        before = daemon.scrape()
+        setup_s = time.monotonic() - T_START
+        say(f"window starts; setup_s {setup_s:.3f}")
+        w0, w_end = ingest.run(
+            n_prefill, stream.n_frames, seconds,
+            ing_spec.get("spans_per_s") if ing_spec["loop"] == "open"
+            else None)
+        if reads is not None:
+            reads.run(seconds, read_rate)
+        prof = {}
+        prof_thread = None
+        if args.trace:
+            trace_s = min(traffic.get("trace_seconds", 3.0), seconds / 2)
+            time.sleep(max(0.0, (seconds - trace_s) / 2))
+            prof_thread = threading.Thread(
+                target=profile, args=(daemon, trace_s, prof), daemon=True)
+            prof_thread.start()
+        ingest.join()
+        if reads is not None:
+            reads.join()
+        if prof_thread is not None:
+            prof_thread.join()
+            if "error" in prof:
+                raise prof["error"]
+        window_s = w_end - w0
+        after = daemon.scrape()
+        say(f"window closed: {len(ingest.records)} calls, try_later "
+            f"{ingest.try_later}"
+            + (f", {len(reads.records)} reads" if reads else ""))
+        if ingest.ran_out:
+            raise RuntimeError(
+                "the span stream ran out before the window ended: the "
+                "traffic file's stream_spans is too small for this rate")
+
+        # -- results of the window -------------------------------------------
+        e2e = end_to_end(ingest, reads, window_s, w_end, setup_s, c)
+        attempted = len(ingest.records) + (len(reads.records) if reads else 0)
+        failed = sum(1 for r in ingest.records if not r[5]) + (
+            sum(1 for r in reads.records if r[5] != 200) if reads else 0)
+        for r in (reads.records if reads else []):
+            if r[5] != 200:
+                say(f"failed read {r[1]} status {r[5]}")
+                break
+
+        # -- the comparison that decides `correct` ---------------------------
+        acked += [r[0] for r in ingest.records if r[5]]
+        ref = Reference(stream, acked)
+        newest = sorted(r[0] for r in ingest.records if r[5])[
+            -ing_spec["connections"]:]
+        lag = wait_visible(daemon, ref, newest or acked[-1:])
+        say(f"last acked spans visible {lag:.2f}s after the window")
+        t0 = time.monotonic()
+        numbers = compare_mod.compare(
+            daemon, ref, rng, traffic.get("compare", {}), say)
+        say(f"comparison took {time.monotonic() - t0:.1f}s")
+        rc = daemon.terminate(STOP_DEADLINE_S)
+        if rc != 0:
+            raise RuntimeError(f"daemon exited {rc} on SIGTERM")
+    except BaseException:
+        daemon.kill()
+        sys.stderr.write("---- daemon stdout (tail) ----\n"
+                         + tail(daemon.out_path)
+                         + "---- daemon stderr (tail) ----\n"
+                         + tail(daemon.err_path))
+        shutil.rmtree(workdir, ignore_errors=True)
+        raise
+
+    mem = daemon.memory_report()
+    dev = {"platform": device["platform"], "kind": device["kind"],
+           "count": device["count"],
+           "memory_peak_bytes": mem.get("memory_peak_bytes", 0),
+           "state_bytes": device["state_bytes"]}
+    result = {"attempted": attempted, "failed": failed}
+    if args.trace:
+        sys.path.insert(0, os.path.join(HERE, "readers"))
+        import trace_reduce
+
+        trace = trace_reduce.load(prof["dir"])
+        shutil.rmtree(prof["dir"], ignore_errors=True)
+        ctx = {"before": before, "after": after, "trace": trace,
+               "device_kind": device["kind"], "traffic": traffic,
+               "client": client_counts(ingest, reads, window_s, w_end, c,
+                                       prof)}
+        metrics = per_layer(bench, args.workload, ctx)
+        if trace is not None:
+            dev["busy_s"] = trace.busy_s
+            dev["window_s"] = trace.window_s
+            result["breakdown"] = trace.breakdown()
+        if args.dump_trace:
+            trace_reduce.dump(trace, args.dump_trace)
+    else:
+        metrics = {}
+        for m in bench["end_to_end"]:
+            if "workloads" in m and args.workload not in m["workloads"]:
+                continue
+            if m["name"] not in e2e or e2e[m["name"]][0] is None:
+                raise RuntimeError(f"this traffic yields no {m['name']}")
+            metrics[m["name"]] = {"value": e2e[m["name"]][0],
+                                  "unit": m["unit"]}
+    for name, (value, unit) in e2e.items():
+        say(f"{name} = {value} {unit}")
+    if reads is not None:
+        for route in reads.names:
+            lat = [(r[4] - r[2]) * 1e3 for r in reads.records
+                   if r[1] == route]
+            say(f"reads {route}: n {len(lat)} p50 {percentile(lat, 0.5)} "
+                f"p95 {percentile(lat, 0.95)} ms")
+        late = [(r[3] - r[2]) * 1e3 for r in reads.records]
+        say(f"reads sent late: p50 {percentile(late, 0.5)} p95 "
+            f"{percentile(late, 0.95)} max {max(late, default=None)} ms; "
+            f"last quarter p50 "
+            f"{percentile(late[-max(1, len(late) // 4):], 0.5)} ms")
+    shutil.rmtree(workdir, ignore_errors=True)
+
+    compared = {k: {"value": v, "limit": compare_mod.LIMITS[k]}
+                for k, v in numbers.items()}
+    correct = all(v["value"] <= v["limit"] for v in compared.values())
+    for k, v in compared.items():
+        print(f"compared {k}: {v['value']} (limit {v['limit']})",
+              file=sys.stderr, flush=True)
+    return {"correct": correct, **result, "metrics": metrics,
+            "device": dev, "compared": compared}
+
+
+def main(argv=None) -> int:
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--workload", required=True)
+    p.add_argument("--seed", type=int, default=0)
+    p.add_argument("--seconds", type=float, default=10.0)
+    p.add_argument("--trace", type=int, default=0, choices=(0, 1))
+    # rehearsal and the builder's sweeps; the driver passes none of these
+    p.add_argument("--platform", choices=("cpu", "tpu"), default=None)
+    p.add_argument("--capacity", type=int, default=0)
+    p.add_argument("--prefill-spans", type=int, default=0)
+    p.add_argument("--stream-spans", type=int, default=0)
+    p.add_argument("--ingest-rate", type=float, default=0.0)
+    p.add_argument("--read-rate", type=float, default=0.0)
+    p.add_argument("--fault", default="",
+                   help="plant a fault of tests/faults.py in the daemon "
+                        "(controls and tests only)")
+    p.add_argument("--dump-trace", default="",
+                   help="write a text summary of the trace's planes here")
+    args = p.parse_args(argv)
+    result = run_cell(args)
+    if "jax" in sys.modules:
+        from jax._src import xla_bridge
+
+        if xla_bridge.backends_are_initialized():
+            raise RuntimeError("the parent initialised a JAX backend")
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

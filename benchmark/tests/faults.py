@@ -1,0 +1,68 @@
+"""Broken guarantees, planted in the program by ``daemon_entry.py
+--fault NAME`` before the daemon starts. Each breaks one guarantee the
+configurations state (step 2 of "how correct is decided": the system
+states no precision, so the control breaks a guarantee), in the way a
+later PR would be tempted to: ack before the write is safe, answer from
+something cheaper than the whole truth.
+
+- ``lost_write``: one Log call of the window is acked OK and dropped
+  (the durability / read-your-acks guarantee).
+- ``not_whole``: a trace read drops the last annotation of one span
+  (read back whole).
+- ``stale_query``: an index query leaves out the newest trace (exact
+  answers).
+"""
+
+import os
+
+
+def plant(name: str) -> None:
+    {"lost_write": _lost_write, "not_whole": _not_whole,
+     "stale_query": _stale_query}[name]()
+
+
+def _lost_write() -> None:
+    from zipkin_tpu.ingest.collector import Collector
+
+    # Which durable Log call to drop: past the warm-up's, inside the
+    # window (the full-size cells prefill with 64 calls).
+    at = int(os.environ.get("BENCH_FAULT_AT", "70"))
+    real = Collector.ingest_thrift_durable
+    seen = [0]
+
+    def ingest_thrift_durable(self, payload):
+        seen[0] += 1
+        if seen[0] == at:
+            return 0  # acked by the receiver, never written
+        return real(self, payload)
+
+    Collector.ingest_thrift_durable = ingest_thrift_durable
+
+
+def _not_whole() -> None:
+    from zipkin_tpu.api.server import ApiServer
+
+    real = ApiServer._trace
+
+    def _trace(self, trace_id, params):
+        status, spans = real(self, trace_id, params)
+        if status == 200 and spans and spans[0]["annotations"]:
+            spans[0] = dict(spans[0],
+                            annotations=spans[0]["annotations"][:-1])
+        return status, spans
+
+    ApiServer._trace = _trace
+
+
+def _stale_query() -> None:
+    from zipkin_tpu.api.server import ApiServer
+
+    real = ApiServer._query
+
+    def _query(self, params):
+        status, body = real(self, params)
+        if status == 200 and len(body.get("traceIds", [])) > 1:
+            body = dict(body, traceIds=body["traceIds"][1:])
+        return status, body
+
+    ApiServer._query = _query

@@ -1,0 +1,94 @@
+"""The readers' arithmetic: prom_delta on two canned scrapes, the trace
+reduction on a small recorded trace, roofline bytes for one
+hand-worked batch."""
+
+import json
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.join(os.path.dirname(HERE), "readers"))
+
+import prom_delta  # noqa: E402
+import roofline  # noqa: E402
+import trace_reduce  # noqa: E402
+
+
+def test_prom_delta_on_two_canned_scrapes():
+    before = {"wal_sum": 1.0, 'serve_sum{tier="a"}': 2.0,
+              'serve_sum{tier="b"}': 1.0, 'serve_count{tier="a"}': 10.0,
+              'serve_count{tier="b"}': 5.0}
+    after = {"wal_sum": 1.5, 'serve_sum{tier="a"}': 3.0,
+             'serve_sum{tier="b"}': 1.5, 'serve_count{tier="a"}': 20.0,
+             'serve_count{tier="b"}': 10.0, "late_sum": 7.0}
+    ctx = {"before": before, "after": after,
+           "client": {"acked": 4000, "none": 0}}
+    ms_per_kspan = prom_delta.read(
+        {"num": [{"prom": "wal_sum"}], "den": [{"client": "acked"}],
+         "scale": 1e6}, ctx)
+    assert abs(ms_per_kspan - 125.0) < 1e-9     # 0.5 s over 4 kspans
+    serve_ms = prom_delta.read(
+        {"num": [{"prom": "serve_sum"}], "den": [{"prom": "serve_count"}],
+         "scale": 1e3}, ctx)
+    assert abs(serve_ms - 100.0) < 1e-9         # 1.5 s over 15 reads
+    # a sample that appeared during the window counts from 0
+    assert prom_delta.read({"num": [{"prom": "late_sum"}]}, ctx) == 7.0
+    # nothing to read is None, never 0
+    assert prom_delta.read({"num": [{"prom": "absent"}]}, ctx) is None
+    assert prom_delta.read({"num": [{"prom": "wal_sum"}],
+                            "den": [{"client": "none"}]}, ctx) is None
+
+
+def test_union_counts_overlaps_once():
+    assert trace_reduce.union_s([(0, 10), (5, 20), (30, 40)]) == 30e-9
+    assert trace_reduce.union_s([]) == 0.0
+
+
+def test_trace_reduction_on_the_recorded_trace():
+    with open(os.path.join(HERE, "recorded_trace.json")) as f:
+        rec = json.load(f)
+    t = trace_reduce.Trace(
+        {n: {k: [tuple(e) for e in v] for k, v in p.items()}
+         for n, p in rec["planes"].items()})
+    want = rec["by_hand"]
+    assert abs(t.window_s - want["window_s"]) < 1e-9
+    assert abs(t.busy_s - want["busy_s"]) < 1e-9
+    assert abs(t.module_s(["^jit_ingest_step"])
+               - want["ingest_step_s"]) < 1e-9
+    assert len(t.module_events(["^jit_ingest_step"])) == want["ingest_steps"]
+    ctx = {"trace": t, "client": {"traced_acked_spans": want["spans"]},
+           "device_kind": "TPU v5 lite", "traffic": rec["traffic"]}
+    idle = trace_reduce.read({"stat": "idle_pct"}, ctx)
+    assert abs(idle - 100 * (1 - want["busy_s"] / want["window_s"])) < 1e-9
+    per = trace_reduce.read(
+        {"stat": "module_ms_per", "patterns": ["^jit_ingest_step"],
+         "per": "traced_acked_spans", "per_unit": 1000.0}, ctx)
+    assert abs(per - 1e3 * want["ingest_step_s"]
+               / (want["spans"] / 1000.0)) < 1e-9
+    share = trace_reduce.read(
+        {"stat": "hbm_roofline_pct", "patterns": ["^jit_ingest_step"],
+         "bytes": "ingest_step"}, ctx)
+    assert 0 < share < 100
+    assert trace_reduce.read({"stat": "module_ms_per",
+                              "patterns": ["^no_such_module"],
+                              "per": "traced_acked_spans"}, ctx) is None
+    assert trace_reduce.read({"stat": "idle_pct"}, {"trace": None}) is None
+    b = t.breakdown()
+    assert 1 <= len(b["device_ops"]) <= 10 and len(b["idle_gaps"]) <= 10
+
+
+def test_roofline_bytes_for_one_hand_worked_batch():
+    traffic = {"call_spans": 2, "annotations_per_span": 6,
+               "binary_per_span": 2, "services_per_span": 2,
+               "indexed_annotations_per_span": 2}
+    # batch: 2*89 + 12*24 + 4*21 = 550 B, read once and written once
+    # index rows: 2*2*2 + 4 + 4*2 + (2 + 12 + 4) = 38, 24 B read + written
+    assert roofline.index_rows(2, 12, 4, 2, 4) == 38
+    assert roofline.ingest_step(traffic) == 2 * 550 + 2 * 24 * 38
+
+
+def test_a_device_kind_not_in_the_table_is_an_error():
+    with open(os.path.join(os.path.dirname(HERE), "readers",
+                           "peaks.json")) as f:
+        peaks = json.load(f)
+    assert "TPU v5 lite" in peaks["device_kinds"] and peaks["source"]
