@@ -12,16 +12,21 @@ Every number compared is exact, so every limit is 0:
   count over EVERY acked span that has a parent, so one lost or doubled
   call anywhere in the run shows;
 - ``routes_never_nonempty``: routes of the sample whose every answer was
-  empty (an empty answer equal to an empty reference proves nothing).
+  empty (an empty answer equal to an empty reference proves nothing);
+- ``acked_calls_never_readable`` (counted by run.py): of the newest acked
+  calls, those whose last span was still not readable a minute after the
+  window closed.
 """
 
 from __future__ import annotations
+
+import json
 
 from reference import Reference, canonical_trace, hex_id
 
 SELF_SERVICE = "zipkin-tpu"  # the daemon's self-trace service prefix
 LIMITS = {"answers_wrong": 0, "dependency_calls_off": 0,
-          "routes_never_nonempty": 0}
+          "routes_never_nonempty": 0, "acked_calls_never_readable": 0}
 
 
 def compare(daemon, ref: Reference, rng, spec: dict, say) -> dict:
@@ -34,7 +39,8 @@ def compare(daemon, ref: Reference, rng, spec: dict, say) -> dict:
         nonempty[route] = nonempty.get(route, 0) + bool(is_nonempty)
         if why:
             wrong += 1
-            say(f"WRONG {route}: {str(why)[:800]}")
+            if wrong <= 3:  # the first few say enough
+                say(f"WRONG {route}: {str(why)[:600]}")
 
     want = ref.services()
     got = [s for s in daemon.get_json("/api/services")
@@ -69,8 +75,6 @@ def compare(daemon, ref: Reference, rng, spec: dict, say) -> dict:
         if status != 200:
             judge("trace", f"{hex_id(tid)}: HTTP {status}", False)
             continue
-        import json
-
         got = canonical_trace(json.loads(body))
         judge("trace", None if got == w else
               f"{hex_id(tid)}: want {len(w)} spans {w[:1]} got {len(got)} "

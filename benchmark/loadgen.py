@@ -14,6 +14,8 @@ import threading
 import time
 import urllib.parse
 
+import numpy as np
+
 from gen import decode_reply
 
 OK, TRY_LATER = 0, 1
@@ -153,11 +155,18 @@ class Reads:
         self.ingest = ingest
         self.routes = spec["routes"]
         names = [m["route"] for m in spec["mix"]]
-        shares = [m["share"] for m in spec["mix"]]
-        total = sum(shares)
-        n = spec.get("schedule_len", 1 << 16)
-        self.kind = rng.choice(len(names), size=n,
-                               p=[s / total for s in shares])
+        # Every seed sends the same reads in another order: the mix is a
+        # fixed multiset per cycle (share x cycle_reads of each route),
+        # shuffled cycle by cycle.
+        per = spec["cycle_reads"]
+        counts = [round(m["share"] * per) for m in spec["mix"]]
+        if sum(counts) != per or not all(counts):
+            raise ValueError("the mix's shares do not fill a cycle of "
+                             f"{per} reads with whole counts: {counts}")
+        one = np.repeat(np.arange(len(names)), counts)
+        n = per * spec.get("schedule_cycles", 256)
+        self.kind = np.concatenate(
+            [rng.permutation(one) for _ in range(n // per)])
         self.names = names
         self.svc = rng.integers(0, len(stream.pool.services), size=n)
         self.u = rng.random(size=(n, 2))

@@ -74,11 +74,14 @@ def build_codec() -> None:
 
 
 def wait_visible(daemon, ref: Reference, frames: list,
-                 deadline_s: float = 600.0) -> float:
-    """An ack means durably appended, not yet committed. Wait until the
-    last span of each of the newest acked calls reads back whole."""
+                 deadline_s: float = 60.0) -> tuple:
+    """An ack means durably appended, not yet committed. Wait, up to a
+    minute, until the last span of each of the newest acked calls reads
+    back whole. Returns (seconds waited, calls that never did): late is
+    late, but an acked span that never comes is for ``correct``."""
     c = ref.stream.call_spans
     t0 = time.monotonic()
+    never = 0
     for f in frames:
         tid = ref.stream.trace_id_at(f * c + c - 1)
         want = len(ref.trace(tid))
@@ -89,10 +92,11 @@ def wait_visible(daemon, ref: Reference, frames: list,
             if status not in (200, 404):
                 raise RuntimeError(f"trace read -> {status}: {body[:300]!r}")
             if time.monotonic() - t0 > deadline_s:
-                raise TimeoutError("acked spans did not become readable")
+                never += 1
+                break
             daemon.check_alive()
             time.sleep(0.1)
-    return time.monotonic() - t0
+    return time.monotonic() - t0, never
 
 
 def warm_reads(reads: Reads, spec: dict) -> int:
@@ -147,7 +151,7 @@ def end_to_end(ingest, reads, window_s: float, t_end: float,
     if reads is not None and reads.records:
         lat = [(r[4] - r[2]) * 1e3 for r in reads.records if r[5] == 200]
         out["read_p50_ms"] = (percentile(lat, 0.5), "ms")
-        out["read_p95_ms"] = (percentile(lat, 0.95), "ms")
+        out["read_mean_ms"] = (sum(lat) / len(lat) if lat else None, "ms")
     return out
 
 
@@ -175,6 +179,10 @@ def client_counts(ingest, reads, window_s, t_end, call_spans, prof) -> dict:
         rl = [(r[3] - r[2]) * 1e3 for r in reads.records]
         if rl:
             c["read_late_p95_ms"] = percentile(rl, 0.95)
+        lat = [(r[4] - r[2]) * 1e3 for r in reads.records if r[5] == 200]
+        if lat:
+            c["read_p95_ms"] = percentile(lat, 0.95)
+            c["read_max_ms"] = max(lat)
     if prof and "t1" in prof:
         a, b = prof["t0"], prof["t1"]
         c["traced_s"] = b - a
@@ -268,8 +276,10 @@ def run_cell(args) -> dict:
             raise RuntimeError("a warm-up Log call was never acked")
         acked = [r[0] for r in ingest.records]
         ref = Reference(stream, acked)
-        lag = wait_visible(daemon, ref, sorted(acked)[-ing_spec[
-            "connections"]:])
+        _, never = wait_visible(daemon, ref, sorted(acked)[-ing_spec[
+            "connections"]:], deadline_s=600.0)
+        if never:
+            raise RuntimeError("the warm-up's spans never became readable")
         say(f"prefill: {n_prefill} calls acked and visible in "
             f"{time.monotonic() - t0:.1f}s (try_later {ingest.try_later})")
         ingest.records.clear()
@@ -332,11 +342,13 @@ def run_cell(args) -> dict:
         ref = Reference(stream, acked)
         newest = sorted(r[0] for r in ingest.records if r[5])[
             -ing_spec["connections"]:]
-        lag = wait_visible(daemon, ref, newest or acked[-1:])
-        say(f"last acked spans visible {lag:.2f}s after the window")
+        lag, never = wait_visible(daemon, ref, newest or acked[-1:])
+        say(f"last acked spans visible {lag:.2f}s after the window"
+            + (f"; {never} call(s) NEVER" if never else ""))
         t0 = time.monotonic()
         numbers = compare_mod.compare(
             daemon, ref, rng, traffic.get("compare", {}), say)
+        numbers["acked_calls_never_readable"] = never
         say(f"comparison took {time.monotonic() - t0:.1f}s")
         rc = daemon.terminate(STOP_DEADLINE_S)
         if rc != 0:

@@ -73,4 +73,6 @@ def test_a_planted_fault_reads_not_correct(fault, number):
 def test_a_fault_shows_under_reads_too():
     line = run_cell(first_cell("open"), "lost_write")
     assert line["correct"] is False
-    assert line["compared"]["dependency_calls_off"]["value"] > 0
+    c = line["compared"]
+    assert (c["dependency_calls_off"]["value"] > 0
+            or c["acked_calls_never_readable"]["value"] > 0)

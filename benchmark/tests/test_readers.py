@@ -92,3 +92,38 @@ def test_a_device_kind_not_in_the_table_is_an_error():
                            "peaks.json")) as f:
         peaks = json.load(f)
     assert "TPU v5 lite" in peaks["device_kinds"] and peaks["source"]
+
+
+def test_reduce_planes_keeps_device_planes_and_strips_fingerprints():
+    class E:
+        def __init__(self, name, start_ns, duration_ns):
+            self.name, self.start_ns, self.duration_ns = (
+                name, start_ns, duration_ns)
+
+    class L:
+        def __init__(self, name, events):
+            self.name, self.events = name, events
+
+    class P:
+        def __init__(self, name, lines):
+            self.name, self.lines = name, lines
+
+    class D:
+        planes = [
+            P("/host:CPU", [L("python", [E("x", 0, 1e9)])]),
+            P("/device:TPU:0", [
+                L("XLA Modules", [E("jit_ingest_step(123456)", 10, 100),
+                                  E("jit__iq_probe(77)", 200, 50)]),
+                L("XLA Ops", [E("fusion.1", 10, 60), E("fusion.2", 50, 60),
+                              E("copy.3", 200, 50)]),
+                L("Steps", [E("0", 0, 1000)])]),
+        ]
+
+    t = trace_reduce.reduce_planes(D)
+    assert list(t.planes) == ["/device:TPU:0"]
+    assert [e[0] for e in t.module_events(["^jit_ingest_step$"])] == [
+        "jit_ingest_step"]
+    assert abs(t.busy_s - 150e-9) < 1e-15       # 10..110 and 200..250
+    assert abs(t.window_s - 240e-9) < 1e-15     # the device plane's span
+    D.planes = D.planes[:1]
+    assert trace_reduce.reduce_planes(D) is None  # a CPU rehearsal
