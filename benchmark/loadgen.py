@@ -17,6 +17,7 @@ import urllib.parse
 import numpy as np
 
 from gen import decode_reply
+from reference import hex_id
 
 OK, TRY_LATER = 0, 1
 
@@ -191,8 +192,7 @@ class Reads:
         last = self.ingest.last_acked - self.spec.get("trace_lag_calls", 0)
         frame = max(0, last - int(u1 * self.spec.get("trace_recent_calls", 8)))
         c = self.stream.call_spans
-        tid = self.stream.trace_id_at(frame * c + int(u2 * c))
-        return f"{tid & (2**64 - 1):x}"
+        return hex_id(self.stream.trace_id_at(frame * c + int(u2 * c)))
 
     def run(self, seconds: float, per_s: float, first: int = 0) -> None:
         self.t0 = time.monotonic()

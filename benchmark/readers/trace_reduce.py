@@ -12,8 +12,12 @@ A metric of ``{"reader": "trace"}`` picks a ``stat`` below.
 from __future__ import annotations
 
 import glob
+import json
 import os
 import re
+
+import prom_delta
+import roofline
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 MODULE_LINE, OP_LINE = "XLA Modules", "XLA Ops"
@@ -33,6 +37,11 @@ def union_s(intervals) -> float:
     if cur_b is not None:
         total += cur_b - cur_a
     return total / 1e9
+
+
+def _top10(seconds_by_name: dict) -> list:
+    return [[k, v] for k, v in sorted(
+        seconds_by_name.items(), key=lambda kv: -kv[1])[:10]]
 
 
 class Trace:
@@ -65,7 +74,7 @@ class Trace:
         return sum(d for _, _, d in self.module_events(patterns)) / 1e9 / n
 
     def breakdown(self) -> dict:
-        ops, gaps = {}, []
+        ops, gaps = {}, {}
         for p in self.planes.values():
             for name, _, d in (p["ops"] or p["modules"]):
                 name = name[:160]
@@ -73,13 +82,9 @@ class Trace:
             mods = sorted(p["modules"], key=lambda e: e[1])
             for (n0, s0, d0), (_, s1, _) in zip(mods, mods[1:]):
                 if s1 > s0 + d0:
-                    gaps.append((f"after {n0}", (s1 - s0 - d0) / 1e9))
-        by_gap = {}
-        for name, g in gaps:
-            by_gap[name] = by_gap.get(name, 0.0) + g
-        top = lambda d: [[k, v] for k, v in sorted(
-            d.items(), key=lambda kv: -kv[1])[:10]]
-        return {"device_ops": top(ops), "idle_gaps": top(by_gap)}
+                    key = f"after {n0}"
+                    gaps[key] = gaps.get(key, 0.0) + (s1 - s0 - d0) / 1e9
+        return {"device_ops": _top10(ops), "idle_gaps": _top10(gaps)}
 
 
 def load(profile_dir: str):
@@ -134,8 +139,6 @@ def dump(trace, path: str, keep_modules: int = 3) -> None:
                 f.write(f"  at {s / 1e9:.6f}s for {d / 1e9:.6f}s {n}\n")
     # a cut-down copy for tests/recorded_trace.json: the first modules
     # of each plane with the operations inside their span
-    import json
-
     cut = {}
     for name, p in trace.planes.items():
         mods = sorted(p["modules"], key=lambda e: e[1])[:keep_modules]
@@ -166,16 +169,25 @@ def read(spec: dict, ctx: dict):
     if not events:
         return None
     seconds = trace.module_s(spec["patterns"])
-    if stat == "module_ms_per":
-        per = ctx["client"].get(spec["per"])
-        if not per:
+    if stat == "module_ms_per_unit":
+        # device ms of these programs per unit of work (a read, a
+        # thousand spans): the units the traced window held are the whole
+        # window's count scaled to the traced window's length, since the
+        # client cannot see which of its requests fell inside the capture.
+        count = ctx["client"].get(spec["per"])
+        whole = ctx["client"].get("window_s")
+        if not count or not whole or trace.window_s <= 0:
             return None
-        return 1e3 * seconds / (per / spec.get("per_unit", 1.0))
+        units = count * trace.window_s / whole / spec.get("per_unit", 1.0)
+        return 1e3 * seconds / units
+    if stat == "module_ms_per_event_unit":
+        # device ms per run of these programs, times runs per unit over
+        # the whole window (from the program's own launch counter)
+        runs_per_unit = prom_delta.read(spec["events_per_unit"], ctx)
+        if runs_per_unit is None:
+            return None
+        return 1e3 * seconds / len(events) * runs_per_unit
     if stat == "hbm_roofline_pct":
-        import json
-
-        import roofline
-
         with open(os.path.join(os.path.dirname(
                 os.path.abspath(__file__)), "peaks.json")) as f:
             peaks = json.load(f)["device_kinds"]

@@ -32,7 +32,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"  # the parent stays off the chip
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
-sys.path.insert(0, HERE)
+sys.path[:0] = [HERE, os.path.join(HERE, "readers")]
 
 import numpy as np  # noqa: E402
 
@@ -124,10 +124,8 @@ def warm_reads(reads: Reads, spec: dict) -> int:
 
 def profile(daemon, seconds: float, out: dict) -> None:
     try:
-        out["t0"] = time.monotonic()
         status, body = daemon.request(
             "POST", "/debug/profile", {"seconds": seconds})
-        out["t1"] = time.monotonic()
         if status != 200:
             raise RuntimeError(f"/debug/profile -> {status}: {body[:300]!r}")
         out["dir"] = json.loads(body)["profileDir"]
@@ -155,7 +153,7 @@ def end_to_end(ingest, reads, window_s: float, t_end: float,
     return out
 
 
-def client_counts(ingest, reads, window_s, t_end, call_spans, prof) -> dict:
+def client_counts(ingest, reads, window_s, t_end, call_spans) -> dict:
     """The load generator's own counts and clocks, for the per-layer
     readers (``{"client": name}`` terms)."""
     calls = ingest.records
@@ -164,17 +162,12 @@ def client_counts(ingest, reads, window_s, t_end, call_spans, prof) -> dict:
         "log_calls_sent": ingest.sent_calls,
         "try_later": ingest.try_later,
         "acked_calls": sum(1 for r in calls if r[5]),
-        "acked_spans": sum(1 for r in calls if r[5]) * call_spans,
         "acked_spans_in_window": sum(
             1 for r in calls if r[5] and r[3] <= t_end) * call_spans,
         "offered_calls": (math.ceil(window_s / ingest.interval)
                           if ingest.interval else len(calls)),
     }
-    late = [(r[2] - r[1]) * 1e3 for r in calls if r[1] is not None]
-    if late:
-        c["ingest_late_p95_ms"] = percentile(late, 0.95)
     if reads is not None:
-        c["reads"] = len(reads.records)
         c["reads_ok"] = sum(1 for r in reads.records if r[5] == 200)
         rl = [(r[3] - r[2]) * 1e3 for r in reads.records]
         if rl:
@@ -183,14 +176,6 @@ def client_counts(ingest, reads, window_s, t_end, call_spans, prof) -> dict:
         if lat:
             c["read_p95_ms"] = percentile(lat, 0.95)
             c["read_max_ms"] = max(lat)
-    if prof and "t1" in prof:
-        a, b = prof["t0"], prof["t1"]
-        c["traced_s"] = b - a
-        c["traced_acked_spans"] = sum(
-            1 for r in calls if r[5] and a <= r[3] <= b) * call_spans
-        if reads is not None:
-            c["traced_reads"] = sum(
-                1 for r in reads.records if a <= r[4] <= b)
     return c
 
 
@@ -198,7 +183,6 @@ def per_layer(bench: dict, workload: str, ctx: dict) -> dict:
     """Each per-layer metric of this cell from its own file: the reader
     named there is found by name under readers/."""
     out = {}
-    sys.path.insert(0, os.path.join(HERE, "readers"))
     for m in bench["per_layer"]:
         if "workloads" in m and workload not in m["workloads"]:
             continue
@@ -242,7 +226,8 @@ def run_cell(args) -> dict:
     # directory and the WAL of a run is a few hundred MB.
     workdir = tempfile.mkdtemp(prefix="bench_run_")
     daemon = Daemon(flags, platform, workdir, fault=args.fault)
-    say(f"daemon spawned; workdir {workdir}")
+    say(f"{args.workload} seed {args.seed} seconds {seconds} trace "
+        f"{args.trace}; daemon spawned; workdir {workdir}")
     try:
         t0 = time.monotonic()
         stream = Stream(args.seed, min(traffic["pool_spans"], stream_spans),
@@ -369,15 +354,13 @@ def run_cell(args) -> dict:
            "state_bytes": device["state_bytes"]}
     result = {"attempted": attempted, "failed": failed}
     if args.trace:
-        sys.path.insert(0, os.path.join(HERE, "readers"))
         import trace_reduce
 
         trace = trace_reduce.load(prof["dir"])
         shutil.rmtree(prof["dir"], ignore_errors=True)
         ctx = {"before": before, "after": after, "trace": trace,
                "device_kind": device["kind"], "traffic": traffic,
-               "client": client_counts(ingest, reads, window_s, w_end, c,
-                                       prof)}
+               "client": client_counts(ingest, reads, window_s, w_end, c)}
         metrics = per_layer(bench, args.workload, ctx)
         if trace is not None:
             dev["busy_s"] = trace.busy_s

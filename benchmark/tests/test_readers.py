@@ -60,18 +60,28 @@ def test_trace_reduction_on_the_recorded_trace():
            "device_kind": "TPU v5 lite", "traffic": rec["traffic"]}
     idle = trace_reduce.read({"stat": "idle_pct"}, ctx)
     assert abs(idle - 100 * (1 - want["busy_s"] / want["window_s"])) < 1e-9
+    ctx["client"].update(window_s=4 * want["window_s"], reads_ok=8)
     per = trace_reduce.read(
-        {"stat": "module_ms_per", "patterns": ["^jit_ingest_step"],
-         "per": "traced_acked_spans", "per_unit": 1000.0}, ctx)
-    assert abs(per - 1e3 * want["ingest_step_s"]
-               / (want["spans"] / 1000.0)) < 1e-9
+        {"stat": "module_ms_per_unit", "patterns": ["^jit_ingest_step"],
+         "per": "reads_ok", "per_unit": 1.0}, ctx)
+    # 8 units over a window four times the traced one: 2 inside the trace
+    assert abs(per - 1e3 * want["ingest_step_s"] / 2) < 1e-6
+    ctx.update(before={"launches_count": 10.0}, after={"launches_count": 30.0})
+    ctx["client"]["acked_spans_in_window"] = 40960
+    per = trace_reduce.read(
+        {"stat": "module_ms_per_event_unit", "patterns": ["^jit_ingest_step"],
+         "events_per_unit": {"num": [{"prom": "launches_count"}],
+                             "den": [{"client": "acked_spans_in_window"}],
+                             "scale": 1000.0}}, ctx)
+    # 20 launches for 40.96 kspans; two runs in the trace
+    assert abs(per - 1e3 * want["ingest_step_s"] / 2 * 20 / 40.96) < 1e-6
     share = trace_reduce.read(
         {"stat": "hbm_roofline_pct", "patterns": ["^jit_ingest_step"],
          "bytes": "ingest_step"}, ctx)
     assert 0 < share < 100
-    assert trace_reduce.read({"stat": "module_ms_per",
+    assert trace_reduce.read({"stat": "module_ms_per_unit",
                               "patterns": ["^no_such_module"],
-                              "per": "traced_acked_spans"}, ctx) is None
+                              "per": "reads_ok"}, ctx) is None
     assert trace_reduce.read({"stat": "idle_pct"}, {"trace": None}) is None
     b = t.breakdown()
     assert 1 <= len(b["device_ops"]) <= 10 and len(b["idle_gaps"]) <= 10
