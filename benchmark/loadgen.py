@@ -116,7 +116,7 @@ class Ingest:
                     self._call(sock, n, due)
             finally:
                 sock.close()
-        except BaseException as e:  # surfaced by join()
+        except Exception as e:  # surfaced by join()
             self.errors.append(e)
 
     def _call(self, sock, n: int, due) -> None:
@@ -222,7 +222,7 @@ class Reads:
                 if wait > 0:
                     time.sleep(wait)
                 self.get(j, due)
-        except BaseException as e:
+        except Exception as e:
             self.errors.append(e)
 
     def get(self, j: int, due: float = None) -> float:

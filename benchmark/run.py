@@ -17,6 +17,7 @@ on the CPU at a small ring; the last line then says ``cpu``.
 from __future__ import annotations
 
 import argparse
+import glob
 import importlib
 import json
 import math
@@ -129,7 +130,7 @@ def profile(daemon, seconds: float, out: dict) -> None:
         if status != 200:
             raise RuntimeError(f"/debug/profile -> {status}: {body[:300]!r}")
         out["dir"] = json.loads(body)["profileDir"]
-    except BaseException as e:
+    except Exception as e:
         out["error"] = e
 
 
@@ -179,33 +180,44 @@ def client_counts(ingest, reads, window_s, t_end, call_spans) -> dict:
     return c
 
 
-def per_layer(bench: dict, workload: str, ctx: dict) -> dict:
-    """Each per-layer metric of this cell from its own file: the reader
+def per_layer(workload: str, ctx: dict) -> dict:
+    """Each per-layer metric whose own file lists this cell; the reader
     named there is found by name under readers/."""
     out = {}
-    for m in bench["per_layer"]:
-        if "workloads" in m and workload not in m["workloads"]:
+    for path in sorted(glob.glob(os.path.join(HERE, "layer_metrics",
+                                              "*.json"))):
+        with open(path) as f:
+            spec = json.load(f)
+        if workload not in spec["workloads"]:
             continue
-        spec = load_json(os.path.join(
-            bench["paths"][0], "layer_metrics", m["name"] + ".json"))
         reader = importlib.import_module(spec["source"]["reader"])
         value = reader.read(spec["source"], ctx)
         if value is not None:
-            out[m["name"]] = {"value": value, "unit": m["unit"]}
+            out[spec["name"]] = {"value": value, "unit": spec["unit"]}
         else:
-            say(f"per-layer {m['name']}: nothing to read")
+            say(f"per-layer {spec['name']}: nothing to read")
     return out
+
+
+def find_cell(bench: dict, args) -> dict:
+    """The cell's entry in BENCHMARK.json; or, with --config and
+    --traffic, a cell that is not one of the benchmark's (yet): a mix kept
+    under Open questions, a sweep."""
+    cell = next((w for w in bench["workloads"]
+                 if w["name"] == args.workload), None)
+    if cell is None and args.config and args.traffic:
+        cell = {"name": args.workload, "config": args.config,
+                "traffic": args.traffic, "chips": 1, "declared": False}
+    if cell is None:
+        raise SystemExit(f"no workload {args.workload!r} in BENCHMARK.json")
+    return cell
 
 
 def run_cell(args) -> dict:
     bench = load_json("BENCHMARK.json")
-    cell = next((w for w in bench["workloads"]
-                 if w["name"] == args.workload), None)
-    if cell is None:
-        raise SystemExit(f"no workload {args.workload!r} in BENCHMARK.json")
-    conf_entry = next(c for c in bench["configs"]
-                      if c["name"] == cell["config"])
-    config = load_json(conf_entry["file"])
+    cell = find_cell(bench, args)
+    config = load_json(os.path.join(
+        bench["paths"][0], "configs", cell["config"] + ".json"))
     traffic = load_json(os.path.join(
         bench["paths"][0], "traffic", cell["traffic"] + ".json"))
     flags = list(config["daemon_flags"])
@@ -361,13 +373,15 @@ def run_cell(args) -> dict:
         ctx = {"before": before, "after": after, "trace": trace,
                "device_kind": device["kind"], "traffic": traffic,
                "client": client_counts(ingest, reads, window_s, w_end, c)}
-        metrics = per_layer(bench, args.workload, ctx)
+        metrics = per_layer(args.workload, ctx)
         if trace is not None:
             dev["busy_s"] = trace.busy_s
             dev["window_s"] = trace.window_s
             result["breakdown"] = trace.breakdown()
         if args.dump_trace:
             trace_reduce.dump(trace, args.dump_trace)
+    elif not cell.get("declared", True):
+        metrics = {k: {"value": v, "unit": u} for k, (v, u) in e2e.items()}
     else:
         metrics = {}
         for m in bench["end_to_end"]:
@@ -409,6 +423,9 @@ def main(argv=None) -> int:
     p.add_argument("--seconds", type=float, default=10.0)
     p.add_argument("--trace", type=int, default=0, choices=(0, 1))
     # rehearsal and the builder's sweeps; the driver passes none of these
+    p.add_argument("--config", default="",
+                   help="with --traffic: run a cell BENCHMARK.json lacks")
+    p.add_argument("--traffic", default="")
     p.add_argument("--platform", choices=("cpu", "tpu"), default=None)
     p.add_argument("--capacity", type=int, default=0)
     p.add_argument("--prefill-spans", type=int, default=0)

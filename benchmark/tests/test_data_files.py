@@ -47,6 +47,12 @@ def test_benchmark_json_names_units_and_files():
         assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
         assert m["better"] in ("lower", "higher")
         assert set(m.get("workloads", cells)) <= cells
+    # a metric's file and its entry agree, both ways, on the declared cells
+    on_file = {load(p)["name"]: load(p) for p in glob.glob(
+        os.path.join(BENCH, "layer_metrics", "*.json"))}
+    declared = {m["name"] for m in b["per_layer"]}
+    for name, spec in on_file.items():
+        assert (name in declared) == bool(set(spec["workloads"]) & cells), name
     for m in b["per_layer"]:
         spec = load(os.path.join(BENCH, "layer_metrics", m["name"] + ".json"))
         for k in ("layer", "unit", "moves", "workloads"):

@@ -20,9 +20,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 
 
-def run_cell(workload: str, fault: str) -> dict:
+def run_cell(workload: str, fault: str, extra=()) -> dict:
     cmd = [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
-           "--workload", workload, "--seed", "2147483659", "--seconds", "2",
+           "--workload", workload, *extra, "--seed", "2147483659", "--seconds", "2",
            "--trace", "0", "--platform", "cpu", "--capacity", "262144",
            "--prefill-spans", "4096", "--stream-spans", "81920",
            "--ingest-rate", "4000", "--read-rate", "4"]
@@ -71,7 +71,10 @@ def test_a_planted_fault_reads_not_correct(fault, number):
 
 
 def test_a_fault_shows_under_reads_too():
-    line = run_cell(first_cell("open"), "lost_write")
+    # the read mix kept for a later cell (PERF.md, Open questions)
+    line = run_cell("ui-reads-live", "lost_write",
+                    ("--config", "allinone-wal-ring22",
+                     "--traffic", "ui-reads-live"))
     assert line["correct"] is False
     c = line["compared"]
     assert (c["dependency_calls_off"]["value"] > 0
