@@ -1,13 +1,15 @@
 """The comparison that decides ``correct``: after the window, a sample
 of requests drawn from the seed goes through the daemon's own HTTP
 routes (the routes the window drives) and each answer is held against
-the plain reference's (``reference.py``) for the spans acked ``OK``.
+the plain reference's (``reference.py``) for the spans acked ``OK``;
+then, with the daemon gone, its write-ahead log is held against the
+acks (``walcheck.py``).
 
 Every number compared is exact, so every limit is 0:
 
 - ``answers_wrong``: sampled answers that differ (services, span names,
-  the three query kinds, whole traces: the longest and the last acked
-  among them);
+  the three query kinds, whole traces drawn from the newest spans the
+  deployment holds whole: the longest and the last acked among them);
 - ``dependency_calls_off``: sum over links of |calls - reference's|, a
   count over EVERY acked span that has a parent, so one lost or doubled
   call anywhere in the run shows;
@@ -15,7 +17,9 @@ Every number compared is exact, so every limit is 0:
   empty (an empty answer equal to an empty reference proves nothing);
 - ``acked_calls_never_readable`` (counted by run.py): of the newest acked
   calls, those whose last span was still not readable a minute after the
-  window closed.
+  window closed;
+- ``acked_spans_not_in_wal``, ``acks_before_durable`` (``walcheck.py``):
+  durability, read off the disk and the fsync journal.
 """
 
 from __future__ import annotations
@@ -26,7 +30,8 @@ from reference import Reference, canonical_trace, hex_id
 
 SELF_SERVICE = "zipkin-tpu"  # the daemon's self-trace service prefix
 LIMITS = {"answers_wrong": 0, "dependency_calls_off": 0,
-          "routes_never_nonempty": 0, "acked_calls_never_readable": 0}
+          "routes_never_nonempty": 0, "acked_calls_never_readable": 0,
+          "acked_spans_not_in_wal": 0, "acks_before_durable": 0}
 
 
 def compare(daemon, ref: Reference, rng, spec: dict, say) -> dict:
@@ -68,7 +73,7 @@ def compare(daemon, ref: Reference, rng, spec: dict, say) -> dict:
     n = ref.n_spans()
     picks = [ref.longest_trace(), ref.trace_id_of(n - 1)]
     picks += [ref.trace_id_of(int(i)) for i in rng.integers(
-        0, n, size=spec.get("traces", 48))]
+        ref.first_retained, n, size=spec.get("traces", 48))]
     for tid in dict.fromkeys(picks):
         w = ref.trace(tid)
         status, body = daemon.request("GET", f"/api/trace/{hex_id(tid)}")

@@ -47,8 +47,10 @@ class Daemon:
         self.out_path = os.path.join(workdir, "daemon.out")
         self.err_path = os.path.join(workdir, "daemon.err")
         self.mem_path = os.path.join(workdir, "device_memory.json")
+        self.fsync_path = os.path.join(workdir, "fsyncs.txt")
         cmd = [sys.executable, os.path.join(HERE, "daemon_entry.py"),
-               "--memory-report", self.mem_path]
+               "--memory-report", self.mem_path,
+               "--fsync-journal", self.fsync_path]
         if fault:
             cmd += ["--fault", fault]
         cmd += ["--", "--platform", platform, "--host", "127.0.0.1",

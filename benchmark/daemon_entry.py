@@ -3,7 +3,10 @@ with two things round it that only the process on the chip can do.
 
 - At exit it writes the device's memory statistics (the peak on the
   fullest chip) where the parent asked: the parent never touches JAX.
-- ``--fault NAME`` plants one of ``benchmark/tests/faults.py``'s broken
+- It keeps a journal of every ``os.fsync`` that returned (monotonic
+  seconds, inode, the file's size when the fsync began), as ``strace``
+  would from outside: ``walcheck.py`` holds the acks against it.
+- ``--fault NAME[,NAME]`` plants one or more of ``benchmark/tests/faults.py``'s broken
   guarantees in the program before it starts. Only the control runs and
   the tests pass it; a benchmark run never does.
 """
@@ -30,9 +33,24 @@ def write_memory_report(path: str) -> None:
     os.replace(tmp, path)
 
 
+def journal_fsyncs(path: str) -> None:
+    import time
+
+    real = os.fsync
+    out = open(path, "a", buffering=1)
+
+    def fsync(fd):
+        st = os.fstat(fd)
+        real(fd)
+        out.write(f"{time.monotonic():.6f} {st.st_ino} {st.st_size}\n")
+
+    os.fsync = fsync
+
+
 def main() -> None:
     p = argparse.ArgumentParser()
     p.add_argument("--memory-report", required=True)
+    p.add_argument("--fsync-journal", required=True)
     p.add_argument("--fault", default="")
     p.add_argument("rest", nargs=argparse.REMAINDER)
     args = p.parse_args()
@@ -46,7 +64,9 @@ def main() -> None:
                 "tests", "faults.py"))
         faults = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(faults)
-        faults.plant(args.fault)
+        for name in args.fault.split(","):
+            faults.plant(name)
+    journal_fsyncs(args.fsync_journal)
     from zipkin_tpu.main import example
 
     try:

@@ -8,17 +8,20 @@ thrift and to complete scribe ``Log`` frames. Nothing is imported from
 the program: what this file makes is the benchmark's input AND the
 plain reference's data (``reference.py``).
 
-The stream is a POOL of spans followed by re-keyed passes over it:
-pass k XORs every trace/span/parent id with a salt drawn from the seed
-and adds ``k * pass_shift_us`` to every timestamp, so later passes are
-newer (a live stream whose frontier moves) and ids never repeat.
-Stream position p is pool span ``p % pool`` in pass ``p // pool``.
+The stream is a POOL of spans followed by re-keyed passes over it,
+without end: pass k XORs every trace/span/parent id with a salt drawn
+from the seed and adds ``k * pass_shift_us`` to every timestamp, so
+later passes are newer (a live stream whose frontier moves) and ids
+never repeat. Stream position p is pool span ``p % pool`` in pass
+``p // pool``.
 """
 
 from __future__ import annotations
 
 import binascii
 import struct
+import threading
+import time
 
 import numpy as np
 
@@ -247,33 +250,100 @@ def _grow_trees(rng, n_spans: int, n_services: int, max_depth: int):
 
 
 class Stream:
-    """The finite span stream of a run and its scribe frames."""
+    """The span stream of a run, without end, and its scribe frames.
 
-    def __init__(self, seed: int, pool_spans: int, stream_spans: int,
-                 call_spans: int, n_services: int, pass_shift_us: int):
-        if pool_spans % call_spans or stream_spans % call_spans:
-            raise ValueError("pool and stream must be whole calls")
+    Frames are made a pass at a time by a producer thread that stays
+    ``ahead`` frames in front of the sender and are dropped once sent,
+    so a run of any length or rate holds a bounded number of them. A
+    sender that has to wait for a frame adds to ``starved_s``."""
+
+    def __init__(self, seed: int, pool_spans: int, call_spans: int,
+                 n_services: int, pass_shift_us: int, ahead: int = 128):
+        if pool_spans % call_spans:
+            raise ValueError("the pool must be whole calls")
+        self.seed = int(seed)
         self.pool = Pool(seed, pool_spans, n_services)
         self.call_spans = call_spans
-        self.n_spans = stream_spans
-        self.n_frames = stream_spans // call_spans
+        self.per_pass = pool_spans // call_spans
         self.pass_shift_us = pass_shift_us
-        n_pass = -(-stream_spans // pool_spans)
-        rng = np.random.default_rng([int(seed), 0x5A17])
-        self.salts = [0] + [int(x) for x in _distinct_ids(rng, n_pass - 1)] \
-            if n_pass > 1 else [0]
-        self.frames = []
-        self._maker = self._frames()
+        self.ahead = ahead
+        self.salts = [0]
+        self.frames = {}
+        self.made = 0          # frames [0, made) were made
+        self.taken = 0         # highest frame number asked for, plus one
+        self.starved_s = 0.0
+        self.error = None
+        self._cond = threading.Condition()
+        self._stop = False
+        self._thread = threading.Thread(target=self._produce, daemon=True)
+        self._thread.start()
 
-    def make_frames(self, upto: int = None) -> None:
-        """Encode frames [len(frames), upto) (all that are left by
-        default): a run makes the warm-up's first and the rest while
-        the warm-up is being sent."""
-        upto = self.n_frames if upto is None else min(upto, self.n_frames)
-        while len(self.frames) < upto:
-            self.frames.append(next(self._maker))
+    def salt(self, k: int) -> int:
+        """Pass k's salt: a function of the seed and k alone, distinct
+        from every earlier pass's."""
+        while len(self.salts) <= k:
+            j = len(self.salts)
+            rng = np.random.default_rng([self.seed, 0x5A17, j])
+            x = int(rng.integers(1, 2**62))
+            while x in self.salts:
+                x = int(rng.integers(1, 2**62))
+            self.salts.append(x)
+        return self.salts[k]
 
-    def _frames(self):
+    def frame(self, n: int) -> bytes:
+        """Frame n, once: it is dropped from the stream's memory."""
+        with self._cond:
+            self.taken = max(self.taken, n + 1)
+            self._cond.notify_all()
+            if n not in self.frames:
+                t0 = time.monotonic()
+                while n not in self.frames:
+                    if self.error is not None:
+                        raise self.error
+                    if n < self.made:
+                        raise KeyError(f"frame {n} was already taken")
+                    self._cond.wait(1.0)
+                self.starved_s += time.monotonic() - t0
+            return self.frames.pop(n)
+
+    def wait_made(self, n: int) -> None:
+        """Block until frames [0, n) are made (set-up, before sending)."""
+        with self._cond:
+            self.taken = max(self.taken, n - self.ahead)
+            self._cond.notify_all()
+            while self.made < n:
+                if self.error is not None:
+                    raise self.error
+                self._cond.wait(1.0)
+
+    def close(self) -> None:
+        with self._cond:
+            self._stop = True
+            self._cond.notify_all()
+        self._thread.join()
+
+    def _produce(self) -> None:
+        try:
+            k = 0
+            while True:
+                with self._cond:
+                    while (not self._stop
+                           and self.made >= self.taken + self.ahead):
+                        self._cond.wait(1.0)
+                    if self._stop:
+                        return
+                for frame in self._pass_frames(k):
+                    with self._cond:
+                        self.frames[self.made] = frame
+                        self.made += 1
+                        self._cond.notify_all()
+                k += 1
+        except BaseException as e:  # surfaced by frame()/wait_made()
+            with self._cond:
+                self.error = e
+                self._cond.notify_all()
+
+    def _pass_frames(self, k: int):
         pool, c = self.pool, self.call_spans
         head = (struct.pack(">I", VERSION_1 | MSG_CALL) + _s(b"Log")
                 + struct.pack(">i", 0) + _fh(T_LIST, 1)
@@ -282,23 +352,21 @@ class Stream:
         b64 = binascii.b2a_base64
         pack = struct.Struct(">i").pack
         starts = pool.starts.tolist()
-        per_pass = pool.n // c
-        for k in range(len(self.salts)):
-            raw = pool.pass_bytes(self.salts[k], k * self.pass_shift_us)
-            mv = memoryview(raw)
-            for f in range(per_pass):
-                parts = [head]
-                for i in range(f * c, (f + 1) * c):
-                    m = b64(mv[starts[i]:starts[i + 1]], newline=False)
-                    parts += (entry, pack(len(m)), m, b"\x00")
-                parts.append(b"\x00")
-                payload = b"".join(parts)
-                yield pack(len(payload)) + payload
+        raw = pool.pass_bytes(self.salt(k), k * self.pass_shift_us)
+        mv = memoryview(raw)
+        for f in range(self.per_pass):
+            parts = [head]
+            for i in range(f * c, (f + 1) * c):
+                m = b64(mv[starts[i]:starts[i + 1]], newline=False)
+                parts += (entry, pack(len(m)), m, b"\x00")
+            parts.append(b"\x00")
+            payload = b"".join(parts)
+            yield pack(len(payload)) + payload
 
     # stream position -> pool span / pass
     def trace_id_at(self, pos: int) -> int:
         k, i = divmod(pos, self.pool.n)
-        return int(self.pool.trace_id[i]) ^ self.salts[k]
+        return int(self.pool.trace_id[i]) ^ self.salt(k)
 
 
 def decode_reply(frame: bytes) -> int:

@@ -1,7 +1,7 @@
 """The one general load generator: scribe ``Log`` calls and HTTP reads,
 closed or open loop, as a traffic file says. One process, a thread per
-connection; the threads only ``sendall``/``recv`` frames made before
-the window (``gen.Stream``) and note the clock.
+connection; the threads only ``sendall``/``recv`` frames that
+``gen.Stream``'s producer made ahead of them and note the clock.
 """
 
 from __future__ import annotations
@@ -41,8 +41,8 @@ def _recv_exact(sock, n: int) -> bytes:
 
 
 class Ingest:
-    """Sends frames [first, limit) of the stream over ``connections``
-    sockets. Closed loop: each connection sends its next call when the
+    """Sends the stream's frames from ``first`` on (up to ``limit``,
+    where one is given) over ``connections`` sockets. Closed loop: each connection sends its next call when the
     last was answered. Open loop: call n is due at t0 + n * interval and
     is timed from then. TRY_LATER is resent after a backoff."""
 
@@ -55,10 +55,9 @@ class Ingest:
         self.try_later = 0
         self.sent_calls = 0     # sends, resends included
         self.last_acked = -1
-        self.ran_out = False
         self.errors = []
 
-    def run(self, first: int, limit: int, seconds: float = None,
+    def run(self, first: int, limit: int = None, seconds: float = None,
             open_rate_spans_per_s: float = None) -> tuple:
         """Drive until ``limit`` frames are taken or ``seconds`` passed;
         in-flight calls are waited for. Returns (t0, t_end)."""
@@ -87,9 +86,7 @@ class Ingest:
             if self.t_end is not None and now >= self.t_end:
                 return None
             n = self.next
-            if n >= self.limit:
-                if self.t_end is not None:
-                    self.ran_out = True
+            if self.limit is not None and n >= self.limit:
                 return None
             due = None
             if self.interval is not None:
@@ -120,7 +117,7 @@ class Ingest:
             self.errors.append(e)
 
     def _call(self, sock, n: int, due) -> None:
-        frame = self.stream.frames[n]
+        frame = self.stream.frame(n)
         backoff = self.spec.get("try_later_backoff_s", 0.05)
         t_first = None
         for tries in range(1, self.spec.get("max_tries", 100) + 1):

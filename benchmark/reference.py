@@ -7,7 +7,12 @@ no index, no cache, no ring) and importing nothing of the program. The
 original it mirrors is ``zipkin_tpu/store/memory.py`` behind
 ``zipkin_tpu/api/server.py``'s route table (PERF.md, Open questions).
 
-Guarantee held: every span acked OK is read back, and read back whole.
+Guarantees held: every span acked OK is read back, and read back
+whole, while it is among the newest ``retained`` acked spans (the
+deployment's rings keep the newest rows and overwrite the oldest: a
+span older than that may be gone, in part or whole, and nothing is
+asked of it); what the daemon keeps for all time (service and span
+names, dependency links) counts every acked span.
 Answers are compared in a canonical order (spans by id, annotations by
 (timestamp, value), binary annotations by key): order inside a trace is
 not part of the guarantee.
@@ -25,16 +30,24 @@ def hex_id(x: int) -> str:
 
 
 class Reference:
-    def __init__(self, stream, acked_frames):
-        """``acked_frames``: numbers of the frames acked OK."""
+    def __init__(self, stream, acked_frames, retained: int = None):
+        """``acked_frames``: numbers of the frames acked OK, any order.
+        ``retained``: how many of the newest acked spans the deployment
+        holds whole (None: all of them)."""
         self.stream, self.pool = stream, stream.pool
         c = stream.call_spans
-        acked = np.zeros(stream.n_frames, bool)
-        acked[list(acked_frames)] = True
-        self.pos = np.flatnonzero(np.repeat(acked, c))  # stream positions
+        frames = np.unique(np.asarray(list(acked_frames), np.int64))
+        self.frames = frames
+        self.pos = (frames[:, None] * c + np.arange(c)[None, :]).ravel()
         self.k, self.i = np.divmod(self.pos, self.pool.n)
-        self.mask = np.zeros(stream.n_frames * c, bool)
+        stream.salt(int(self.k.max()) if len(self.k) else 0)
+        self.mask = np.zeros((int(frames[-1]) + 1) * c if len(frames)
+                             else 0, bool)
         self.mask[self.pos] = True
+        n = len(self.pos)
+        # whole frames: a trace is cut only where a call's edge cuts it
+        self.first_retained = 0 if retained is None else max(
+            0, n - (retained // c) * c)
 
     def n_spans(self) -> int:
         return len(self.pos)
@@ -149,8 +162,10 @@ class Reference:
     # -- which traces to ask for ---------------------------------------------------
 
     def longest_trace(self) -> int:
-        sizes = np.bincount(self.pool.trace_idx[self.i]
-                            + self.k * self.pool.n_traces)
+        """The longest among the traces still held whole."""
+        r = slice(self.first_retained, None)
+        sizes = np.bincount(self.pool.trace_idx[self.i[r]]
+                            + self.k[r] * self.pool.n_traces)
         key = int(np.argmax(sizes))
         k, t = divmod(key, self.pool.n_traces)
         i = int(np.searchsorted(self.pool.trace_idx, t))
@@ -158,6 +173,14 @@ class Reference:
 
     def trace_id_of(self, nth_acked: int) -> int:
         return self.stream.trace_id_at(int(self.pos[nth_acked]))
+
+    def span_keys(self):
+        """(trace ids, span ids, frame number) of every acked span, as
+        sent: what the write-ahead log has to hold."""
+        p = self.pool
+        salts = np.asarray(self.stream.salts, np.int64)[self.k]
+        return (p.trace_id[self.i] ^ salts, p.span_id[self.i] ^ salts,
+                self.pos // self.stream.call_spans)
 
 
 def canonical_trace(spans: list) -> list:

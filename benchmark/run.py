@@ -17,7 +17,6 @@ on the CPU at a small ring; the last line then says ``cpu``.
 from __future__ import annotations
 
 import argparse
-import glob
 import importlib
 import json
 import math
@@ -38,6 +37,7 @@ sys.path[:0] = [HERE, os.path.join(HERE, "readers")]
 import numpy as np  # noqa: E402
 
 import compare as compare_mod  # noqa: E402
+import walcheck  # noqa: E402
 from daemon import Daemon, tail  # noqa: E402
 from gen import Stream  # noqa: E402
 from loadgen import Ingest, Reads, percentile  # noqa: E402
@@ -150,7 +150,7 @@ def end_to_end(ingest, reads, window_s: float, t_end: float,
     if reads is not None and reads.records:
         lat = [(r[4] - r[2]) * 1e3 for r in reads.records if r[5] == 200]
         out["read_p50_ms"] = (percentile(lat, 0.5), "ms")
-        out["read_mean_ms"] = (sum(lat) / len(lat) if lat else None, "ms")
+        out["read_p95_ms"] = (percentile(lat, 0.95), "ms")
     return out
 
 
@@ -180,42 +180,60 @@ def client_counts(ingest, reads, window_s, t_end, call_spans) -> dict:
     return c
 
 
-def per_layer(workload: str, ctx: dict) -> dict:
-    """Each per-layer metric whose own file lists this cell; the reader
-    named there is found by name under readers/."""
+def per_layer(bench: dict, workload: str, ctx: dict) -> dict:
+    """Each per-layer metric of BENCHMARK.json that this cell reports;
+    how it is read is in the metric's own file, and the reader named
+    there is found by name under readers/."""
     out = {}
-    for path in sorted(glob.glob(os.path.join(HERE, "layer_metrics",
-                                              "*.json"))):
-        with open(path) as f:
-            spec = json.load(f)
-        if workload not in spec["workloads"]:
+    for m in bench["per_layer"]:
+        if not reports(m, bench, workload):
             continue
+        spec = load_json(os.path.join(
+            bench["paths"][0], "layer_metrics", m["name"] + ".json"))
         reader = importlib.import_module(spec["source"]["reader"])
         value = reader.read(spec["source"], ctx)
         if value is not None:
-            out[spec["name"]] = {"value": value, "unit": spec["unit"]}
+            out[m["name"]] = {"value": value, "unit": m["unit"]}
         else:
-            say(f"per-layer {spec['name']}: nothing to read")
+            say(f"per-layer {m['name']}: nothing to read")
     return out
 
 
-def find_cell(bench: dict, args) -> dict:
-    """The cell's entry in BENCHMARK.json; or, with --config and
-    --traffic, a cell that is not one of the benchmark's (yet): a mix kept
-    under Open questions, a sweep."""
-    cell = next((w for w in bench["workloads"]
-                 if w["name"] == args.workload), None)
-    if cell is None and args.config and args.traffic:
-        cell = {"name": args.workload, "config": args.config,
-                "traffic": args.traffic, "chips": 1, "declared": False}
-    if cell is None:
-        raise SystemExit(f"no workload {args.workload!r} in BENCHMARK.json")
-    return cell
+def ring_fill(scrape: dict) -> str:
+    """The rings' occupancy as /metrics has it, for the run's log."""
+    return ", ".join(
+        f"{k.split(chr(34))[1]} {int(v)}" for k, v in sorted(scrape.items())
+        if k.startswith("zipkin_store_counter{")
+        and ("ring_occupancy" in k or "ring_laps" in k))
+
+
+def reports(metric: dict, bench: dict, workload: str) -> bool:
+    """Whether this cell reports the metric: the cells its entry lists
+    or, where it lists none, every cell that reports the end-to-end
+    metric it moves (all cells, for an end-to-end metric)."""
+    if "workloads" in metric:
+        return workload in metric["workloads"]
+    if "moves" not in metric:
+        return True
+    moved = next(m for m in bench["end_to_end"]
+                 if m["name"] == metric["moves"])
+    return reports(moved, bench, workload)
+
+
+def lap_spans(config: dict, traffic: dict, capacity: int) -> int:
+    """Spans of this traffic that fill the ring that fills first."""
+    per_span = {"span": 1, "annotation": traffic["annotations_per_span"],
+                "binary": traffic["binary_per_span"]}
+    return min(capacity * rows // per_span[ring]
+               for ring, rows in config["ring_rows_per_capacity_row"].items())
 
 
 def run_cell(args) -> dict:
     bench = load_json("BENCHMARK.json")
-    cell = find_cell(bench, args)
+    cell = next((w for w in bench["workloads"]
+                 if w["name"] == args.workload), None)
+    if cell is None:
+        raise SystemExit(f"no workload {args.workload!r} in BENCHMARK.json")
     config = load_json(os.path.join(
         bench["paths"][0], "configs", cell["config"] + ".json"))
     traffic = load_json(os.path.join(
@@ -226,30 +244,32 @@ def run_cell(args) -> dict:
     platform = args.platform or config["platform"]
     seconds = float(args.seconds)
     c = traffic["call_spans"]
-    prefill = args.prefill_spans or traffic["prefill_spans"]
-    stream_spans = args.stream_spans or traffic["stream_spans"]
+    # The window runs on full rings: the pre-fill is as many laps of the
+    # ring that fills first as the traffic file says, in whole calls.
+    lap = lap_spans(config, traffic, int(flags[flags.index("--capacity") + 1]))
+    n_prefill = math.ceil(traffic["prefill_laps"] * lap / c)
+    retained = int(config["retained_whole_share"] * lap)
     ing_spec, rd_spec = traffic["ingest"], traffic.get("reads")
-    if args.ingest_rate:
-        ing_spec = {**ing_spec, "spans_per_s": args.ingest_rate}
-    read_rate = args.read_rate or (rd_spec or {}).get("per_s")
+    # where a control drops a call, it drops one of the window's
+    os.environ.setdefault("BENCH_FAULT_AT", str(n_prefill + 6))
 
     build_codec()
-    # Inside the checkout, never /tmp: TMPDIR is the driver's per-side
-    # directory and the WAL of a run is a few hundred MB.
+    # Inside TMPDIR, the driver's per-side directory: the WAL of a run is
+    # some hundreds of MB.
     workdir = tempfile.mkdtemp(prefix="bench_run_")
     daemon = Daemon(flags, platform, workdir, fault=args.fault)
     say(f"{args.workload} seed {args.seed} seconds {seconds} trace "
         f"{args.trace}; daemon spawned; workdir {workdir}")
+    stream = None
     try:
         t0 = time.monotonic()
-        stream = Stream(args.seed, min(traffic["pool_spans"], stream_spans),
-                        stream_spans, c, traffic["n_services"],
-                        traffic["pass_shift_us"])
-        n_prefill = prefill // c
-        stream.make_frames(n_prefill)
-        say(f"stream: {stream.n_spans} spans in {stream.n_frames} calls of "
-            f"{c}; pool and the warm-up's {n_prefill} frames made in "
-            f"{time.monotonic() - t0:.1f}s")
+        stream = Stream(args.seed, traffic["pool_spans"], c,
+                        traffic["n_services"], traffic["pass_shift_us"],
+                        traffic["frames_ahead"])
+        stream.wait_made(min(n_prefill, traffic["frames_ahead"]))
+        say(f"stream: calls of {c} spans; one lap is {lap} spans, pre-fill "
+            f"{n_prefill} calls, held whole {retained}; pool and first "
+            f"frames made in {time.monotonic() - t0:.1f}s")
         device = daemon.wait_boot(BOOT_DEADLINE_S)
         say(f"boot line after {time.monotonic() - daemon.t_spawn:.1f}s: "
             f"{device}")
@@ -261,23 +281,22 @@ def run_cell(args) -> dict:
             raise RuntimeError(f"the cell asks for {cell['chips']} chip(s); "
                                f"the state spans {device['count']}")
 
-        # -- warm-up: this cell's shapes, through the window's own doors --
+        # -- pre-fill and warm-up: this cell's shapes, through the window's
+        # own doors, until the rings are full -------------------------------
         ingest = Ingest(daemon.scribe_port, stream, ing_spec,
                         daemon.check_alive)
         t0 = time.monotonic()
         ingest.run(0, n_prefill)
-        stream.make_frames()  # the window's, while the warm-up is sent
-        say(f"all frames made {time.monotonic() - t0:.1f}s into the warm-up")
         ingest.join()
         if any(not r[5] for r in ingest.records):
-            raise RuntimeError("a warm-up Log call was never acked")
-        acked = [r[0] for r in ingest.records]
-        ref = Reference(stream, acked)
-        _, never = wait_visible(daemon, ref, sorted(acked)[-ing_spec[
+            raise RuntimeError("a pre-fill Log call was never acked")
+        ack_time = {r[0]: r[3] for r in ingest.records}
+        ref = Reference(stream, ack_time, retained)
+        _, never = wait_visible(daemon, ref, sorted(ack_time)[-ing_spec[
             "connections"]:], deadline_s=600.0)
         if never:
-            raise RuntimeError("the warm-up's spans never became readable")
-        say(f"prefill: {n_prefill} calls acked and visible in "
+            raise RuntimeError("the pre-fill's spans never became readable")
+        say(f"pre-fill: {n_prefill} calls acked and visible in "
             f"{time.monotonic() - t0:.1f}s (try_later {ingest.try_later})")
         ingest.records.clear()
         ingest.try_later = ingest.sent_calls = 0
@@ -291,14 +310,16 @@ def run_cell(args) -> dict:
 
         # -- the window ----------------------------------------------------
         before = daemon.scrape()
+        starved0 = stream.starved_s
         setup_s = time.monotonic() - T_START
-        say(f"window starts; setup_s {setup_s:.3f}")
+        say(f"window starts; setup_s {setup_s:.3f}; rings "
+            + ring_fill(before))
         w0, w_end = ingest.run(
-            n_prefill, stream.n_frames, seconds,
+            n_prefill, None, seconds,
             ing_spec.get("spans_per_s") if ing_spec["loop"] == "open"
             else None)
         if reads is not None:
-            reads.run(seconds, read_rate)
+            reads.run(seconds, rd_spec["per_s"])
         prof = {}
         prof_thread = None
         if args.trace:
@@ -317,12 +338,13 @@ def run_cell(args) -> dict:
         window_s = w_end - w0
         after = daemon.scrape()
         say(f"window closed: {len(ingest.records)} calls, try_later "
-            f"{ingest.try_later}"
+            f"{ingest.try_later}; rings " + ring_fill(after)
             + (f", {len(reads.records)} reads" if reads else ""))
-        if ingest.ran_out:
+        starved = stream.starved_s - starved0
+        if starved > 0.01 * window_s:
             raise RuntimeError(
-                "the span stream ran out before the window ended: the "
-                "traffic file's stream_spans is too small for this rate")
+                f"the senders waited {starved:.2f}s for frames: the load "
+                "generator, not the daemon, set this window's rate")
 
         # -- results of the window -------------------------------------------
         e2e = end_to_end(ingest, reads, window_s, w_end, setup_s, c)
@@ -335,21 +357,29 @@ def run_cell(args) -> dict:
                 break
 
         # -- the comparison that decides `correct` ---------------------------
-        acked += [r[0] for r in ingest.records if r[5]]
-        ref = Reference(stream, acked)
+        ack_time.update({r[0]: r[3] for r in ingest.records if r[5]})
+        ref = Reference(stream, ack_time, retained)
         newest = sorted(r[0] for r in ingest.records if r[5])[
             -ing_spec["connections"]:]
-        lag, never = wait_visible(daemon, ref, newest or acked[-1:])
+        lag, never = wait_visible(daemon, ref, newest or sorted(ack_time)[-1:])
         say(f"last acked spans visible {lag:.2f}s after the window"
             + (f"; {never} call(s) NEVER" if never else ""))
         t0 = time.monotonic()
         numbers = compare_mod.compare(
             daemon, ref, rng, traffic.get("compare", {}), say)
         numbers["acked_calls_never_readable"] = never
-        say(f"comparison took {time.monotonic() - t0:.1f}s")
+        say(f"comparison over HTTP took {time.monotonic() - t0:.1f}s")
         rc = daemon.terminate(STOP_DEADLINE_S)
         if rc != 0:
             raise RuntimeError(f"daemon exited {rc} on SIGTERM")
+        t0 = time.monotonic()
+        wal_dir = flags[flags.index("--wal-dir") + 1].replace(
+            "{workdir}", workdir)
+        numbers.update(walcheck.check(
+            wal_dir, daemon.fsync_path, ref, ack_time,
+            traffic["annotations_per_span"], traffic["binary_per_span"], say))
+        say(f"the log held against the acks in "
+            f"{time.monotonic() - t0:.1f}s")
     except BaseException:
         daemon.kill()
         sys.stderr.write("---- daemon stdout (tail) ----\n"
@@ -358,6 +388,9 @@ def run_cell(args) -> dict:
                          + tail(daemon.err_path))
         shutil.rmtree(workdir, ignore_errors=True)
         raise
+    finally:
+        if stream is not None:
+            stream.close()
 
     mem = daemon.memory_report()
     dev = {"platform": device["platform"], "kind": device["kind"],
@@ -373,19 +406,17 @@ def run_cell(args) -> dict:
         ctx = {"before": before, "after": after, "trace": trace,
                "device_kind": device["kind"], "traffic": traffic,
                "client": client_counts(ingest, reads, window_s, w_end, c)}
-        metrics = per_layer(args.workload, ctx)
+        metrics = per_layer(bench, args.workload, ctx)
         if trace is not None:
             dev["busy_s"] = trace.busy_s
             dev["window_s"] = trace.window_s
             result["breakdown"] = trace.breakdown()
         if args.dump_trace:
             trace_reduce.dump(trace, args.dump_trace)
-    elif not cell.get("declared", True):
-        metrics = {k: {"value": v, "unit": u} for k, (v, u) in e2e.items()}
     else:
         metrics = {}
         for m in bench["end_to_end"]:
-            if "workloads" in m and args.workload not in m["workloads"]:
+            if not reports(m, bench, args.workload):
                 continue
             if m["name"] not in e2e or e2e[m["name"]][0] is None:
                 raise RuntimeError(f"this traffic yields no {m['name']}")
@@ -399,11 +430,6 @@ def run_cell(args) -> dict:
                    if r[1] == route]
             say(f"reads {route}: n {len(lat)} p50 {percentile(lat, 0.5)} "
                 f"p95 {percentile(lat, 0.95)} ms")
-        late = [(r[3] - r[2]) * 1e3 for r in reads.records]
-        say(f"reads sent late: p50 {percentile(late, 0.5)} p95 "
-            f"{percentile(late, 0.95)} max {max(late, default=None)} ms; "
-            f"last quarter p50 "
-            f"{percentile(late[-max(1, len(late) // 4):], 0.5)} ms")
     shutil.rmtree(workdir, ignore_errors=True)
 
     compared = {k: {"value": v, "limit": compare_mod.LIMITS[k]}
@@ -422,16 +448,9 @@ def main(argv=None) -> int:
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--seconds", type=float, default=10.0)
     p.add_argument("--trace", type=int, default=0, choices=(0, 1))
-    # rehearsal and the builder's sweeps; the driver passes none of these
-    p.add_argument("--config", default="",
-                   help="with --traffic: run a cell BENCHMARK.json lacks")
-    p.add_argument("--traffic", default="")
+    # the rehearsal; the driver passes neither
     p.add_argument("--platform", choices=("cpu", "tpu"), default=None)
     p.add_argument("--capacity", type=int, default=0)
-    p.add_argument("--prefill-spans", type=int, default=0)
-    p.add_argument("--stream-spans", type=int, default=0)
-    p.add_argument("--ingest-rate", type=float, default=0.0)
-    p.add_argument("--read-rate", type=float, default=0.0)
     p.add_argument("--fault", default="",
                    help="plant a fault of tests/faults.py in the daemon "
                         "(controls and tests only)")

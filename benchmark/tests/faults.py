@@ -7,6 +7,8 @@ something cheaper than the whole truth.
 
 - ``lost_write``: one Log call of the window is acked OK and dropped
   (the durability / read-your-acks guarantee).
+- ``ack_before_fsync``: a Log call is acked once appended, without
+  waiting for the group commit's fsync (durability).
 - ``not_whole``: a trace read drops the last annotation of one span
   (read back whole).
 - ``stale_query``: an index query leaves out the newest trace (exact
@@ -17,16 +19,15 @@ import os
 
 
 def plant(name: str) -> None:
-    {"lost_write": _lost_write, "not_whole": _not_whole,
-     "stale_query": _stale_query}[name]()
+    {"lost_write": _lost_write, "ack_before_fsync": _ack_before_fsync,
+     "not_whole": _not_whole, "stale_query": _stale_query}[name]()
 
 
 def _lost_write() -> None:
     from zipkin_tpu.ingest.collector import Collector
 
-    # Which durable Log call to drop: past the warm-up's, inside the
-    # window (the full-size cells prefill with 64 calls).
-    at = int(os.environ.get("BENCH_FAULT_AT", "70"))
+    # Which durable Log call to drop: run.py names one of the window's.
+    at = int(os.environ["BENCH_FAULT_AT"])
     real = Collector.ingest_thrift_durable
     seen = [0]
 
@@ -37,6 +38,12 @@ def _lost_write() -> None:
         return real(self, payload)
 
     Collector.ingest_thrift_durable = ingest_thrift_durable
+
+
+def _ack_before_fsync() -> None:
+    from zipkin_tpu.ingest.collector import Collector
+
+    Collector._wal_barrier = lambda self: None
 
 
 def _not_whole() -> None:

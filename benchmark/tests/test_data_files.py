@@ -47,21 +47,35 @@ def test_benchmark_json_names_units_and_files():
         assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
         assert m["better"] in ("lower", "higher")
         assert set(m.get("workloads", cells)) <= cells
-    # a metric's file and its entry agree, both ways, on the declared cells
+    # every metric has a file of its own and every file an entry; which
+    # cells report it is said once, in BENCHMARK.json
     on_file = {load(p)["name"]: load(p) for p in glob.glob(
         os.path.join(BENCH, "layer_metrics", "*.json"))}
-    declared = {m["name"] for m in b["per_layer"]}
-    for name, spec in on_file.items():
-        assert (name in declared) == bool(set(spec["workloads"]) & cells), name
+    assert set(on_file) == {m["name"] for m in b["per_layer"]}
     for m in b["per_layer"]:
-        spec = load(os.path.join(BENCH, "layer_metrics", m["name"] + ".json"))
-        for k in ("layer", "unit", "moves", "workloads"):
+        spec = on_file[m["name"]]
+        assert "workloads" not in spec
+        for k in ("layer", "unit", "moves"):
             assert spec[k] == m[k], (m["name"], k)
         assert os.path.exists(os.path.join(
             BENCH, "readers", spec["source"]["reader"] + ".py"))
         moved = e2e[m["moves"]]
         # each listed cell reports the end-to-end metric it should move
-        assert set(m["workloads"]) <= set(moved.get("workloads", cells))
+        assert set(m.get("workloads", ())) <= set(moved.get("workloads", cells))
+
+
+def test_configs_and_traffic_state_the_lap():
+    b = load(os.path.join(ROOT, "BENCHMARK.json"))
+    confs = {c["name"]: load(os.path.join(ROOT, c["file"]))
+             for c in b["configs"]}
+    for w in b["workloads"]:
+        conf = confs[w["config"]]
+        t = load(os.path.join(BENCH, "traffic", w["traffic"] + ".json"))
+        assert set(conf["ring_rows_per_capacity_row"]) == {
+            "span", "annotation", "binary"}
+        assert 0 < conf["retained_whole_share"] < 1
+        # the window runs on full rings
+        assert t["prefill_laps"] >= 1.0 and t["frames_ahead"] >= 16
 
 
 def test_no_cell_name_in_harness_code():

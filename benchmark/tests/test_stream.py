@@ -36,14 +36,16 @@ def frame_messages(frame: bytes) -> list:
 def test_rekeyed_frame_decodes_to_the_same_span_with_new_ids():
     from zipkin_tpu.wire.thrift import span_from_bytes
 
-    s = gen.Stream(2**31 + 7, 512, 2048, 64, 8, 10_000_000)
-    s.make_frames(3)
-    assert len(s.frames) == 3
-    s.make_frames()
-    assert s.n_frames == 32 and len(s.salts) == 4 and s.salts[0] == 0
+    s = gen.Stream(2**31 + 7, 512, 64, 8, 10_000_000, ahead=8)
+    s.wait_made(3)
+    frames = {f: s.frame(f) for f in range(32)}  # four passes, made ahead
+    s.close()
+    assert len(s.salts) >= 4 and s.salts[0] == 0
+    assert len(set(s.salts)) == len(s.salts)
+    assert len(s.frames) <= 16  # taken frames are dropped
     pool = s.pool
     for f in (0, 9, 31):
-        msgs = frame_messages(s.frames[f])
+        msgs = frame_messages(frames[f])
         assert len(msgs) == 64
         for j in (0, 17, 63):
             pos = f * 64 + j
@@ -67,13 +69,13 @@ def test_rekeyed_frame_decodes_to_the_same_span_with_new_ids():
 
 
 def test_same_seed_same_stream_and_trees_keep_their_shape():
-    a = gen.Stream(5, 512, 1024, 64, 8, 10_000_000)
-    b = gen.Stream(5, 512, 1024, 64, 8, 10_000_000)
-    c = gen.Stream(6, 512, 1024, 64, 8, 10_000_000)
+    a = gen.Stream(5, 512, 64, 8, 10_000_000)
+    b = gen.Stream(5, 512, 64, 8, 10_000_000)
+    c = gen.Stream(6, 512, 64, 8, 10_000_000)
+    fa, fb, fc = ([x.frame(n) for n in range(16)] for x in (a, b, c))
     for x in (a, b, c):
-        x.make_frames()
-    assert a.frames == b.frames and len(a.frames) == 16
-    assert a.frames != c.frames
+        x.close()
+    assert fa == fb and fa != fc
     p = a.pool
     # a parent comes before its child, in the same trace
     kids = p.parent_pos >= 0
@@ -82,14 +84,16 @@ def test_same_seed_same_stream_and_trees_keep_their_shape():
 
 
 def test_reference_reads_back_what_was_acked():
-    s = gen.Stream(11, 512, 1024, 64, 8, 10_000_000)
-    ref = Reference(s, range(s.n_frames))
+    s = gen.Stream(11, 512, 64, 8, 10_000_000)
+    s.close()
+    n_frames = 16
+    ref = Reference(s, range(n_frames))
     assert ref.n_spans() == 1024
     tid = ref.longest_trace()
     spans = ref.trace(tid)
     assert len(spans) >= 2 and all(x["traceId"] == f"{tid:x}" for x in spans)
     # with half of the calls never acked, their spans are not expected
-    half = Reference(s, range(0, s.n_frames, 2))
+    half = Reference(s, range(0, n_frames, 2))
     assert half.n_spans() == 512
     total = sum(ref.dependency_calls().values())
     assert total == int((s.pool.parent_pos >= 0).sum()) * 2
@@ -100,3 +104,21 @@ def test_reference_reads_back_what_was_acked():
     ids = [f"{t:x}" for _, t in ranked[:10]]
     assert ref.check_query(svc, 10, ids) is None
     assert ref.check_query(svc, 10, ids[1:]) is not None
+
+
+def test_only_the_newest_spans_are_held_to_be_whole():
+    s = gen.Stream(11, 512, 64, 8, 10_000_000)
+    s.close()
+    ref = Reference(s, range(16), retained=300)  # whole calls: 256 spans
+    assert ref.first_retained == 1024 - 256
+    # what is kept for all time still counts every acked span
+    assert ref.n_spans() == 1024
+    assert sum(ref.dependency_calls().values()) == \
+        int((s.pool.parent_pos >= 0).sum()) * 2
+    # the longest trace is looked for among the newest spans only
+    tid = ref.longest_trace()
+    newest = {ref.trace_id_of(n) for n in range(ref.first_retained, 1024)}
+    assert tid in newest
+    tids, sids, frame = ref.span_keys()
+    assert len(tids) == 1024 and frame[0] == 0 and frame[-1] == 15
+    assert tids[-1] == ref.trace_id_of(1023)
