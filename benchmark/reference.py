@@ -95,6 +95,11 @@ class Reference:
         """None when ``got_ids`` (hex) is a right answer, else why not.
         Ties in the timestamp may come in either order."""
         ranked = self.ranked_traces(service)
+        if any(self._before_retained(t) for _, t in ranked[:limit]):
+            raise RuntimeError(
+                "a trace among the newest by timestamp is older than what "
+                "the deployment holds whole: the traffic's pool is too "
+                "long for this ring")
         ts_of = {hex_id(t): ts for ts, t in ranked}
         want_ts = [ts for ts, _ in ranked[:limit]]
         if len(set(got_ids)) != len(got_ids):
@@ -104,6 +109,17 @@ class Reference:
             return (f"want {[hex_id(t) for _, t in ranked[:limit]]} "
                     f"got {got_ids}")
         return None
+
+    def _before_retained(self, trace_id: int) -> bool:
+        """Whether any acked span of the trace is older than the spans
+        held whole."""
+        p = self.pool
+        cut = int(self.pos[self.first_retained])
+        for k, salt in enumerate(self.stream.salts):
+            idx = np.flatnonzero(p.trace_id == (trace_id ^ salt))
+            if len(idx) and k * p.n + int(idx[0]) < cut:
+                return True
+        return False
 
     # -- /api/trace/<id> --------------------------------------------------------
 

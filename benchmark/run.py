@@ -134,19 +134,27 @@ def profile(daemon, seconds: float, out: dict) -> None:
         out["error"] = e
 
 
-def end_to_end(ingest, reads, window_s: float, t_end: float,
-               setup_s: float, call_spans: int) -> dict:
+def end_to_end(ingest, reads, w0: float, t_end: float, setup_s: float,
+               call_spans: int, say) -> dict:
     """Every end-to-end metric this traffic yields, over all the work of
-    the window: every call, every read, the whole window's seconds."""
+    the window and all its time: every call sent within ``--seconds``,
+    from the window's start until the last of them was answered."""
     out = {"setup_s": (setup_s, "s")}
-    calls = ingest.records
-    if calls:
+    done = [r for r in ingest.records if r[5]]
+    if done:
         lat = [(r[3] - (r[1] if r[1] is not None else r[2])) * 1e3
-               for r in calls if r[5]]
+               for r in done]
         out["ack_p95_ms"] = (percentile(lat, 0.95), "ms")
-        in_window = sum(1 for r in calls if r[5] and r[3] <= t_end)
-        out["acked_spans_per_s"] = (in_window * call_spans / window_s,
+        work_s = max(r[3] for r in done) - w0
+        out["acked_spans_per_s"] = (len(done) * call_spans / work_s,
                                     "spans/s")
+        # The daemon acks in bursts (it runs ahead of the device, then
+        # waits for it), so the count inside a fixed number of seconds
+        # turns on where the last burst is cut; it is printed beside.
+        inside = sum(1 for r in done if r[3] <= t_end)
+        say(f"{len(done)} calls acked in {work_s:.3f}s; {inside} of them "
+            f"inside the first {t_end - w0:.0f}s, "
+            f"{inside * call_spans / (t_end - w0):.1f} spans/s")
     if reads is not None and reads.records:
         lat = [(r[4] - r[2]) * 1e3 for r in reads.records if r[5] == 200]
         out["read_p50_ms"] = (percentile(lat, 0.5), "ms")
@@ -154,29 +162,24 @@ def end_to_end(ingest, reads, window_s: float, t_end: float,
     return out
 
 
-def client_counts(ingest, reads, window_s, t_end, call_spans) -> dict:
+def client_counts(ingest, reads, w0: float, seconds: float,
+                  call_spans: int) -> dict:
     """The load generator's own counts and clocks, for the per-layer
-    readers (``{"client": name}`` terms)."""
-    calls = ingest.records
+    readers (``{"client": name}`` terms): over the same calls and the
+    same seconds as the end-to-end rate, which the two /metrics scrapes
+    bracket."""
+    done = [r for r in ingest.records if r[5]]
     c = {
-        "window_s": window_s,
+        "window_s": max((r[3] for r in done), default=w0 + seconds) - w0,
         "log_calls_sent": ingest.sent_calls,
         "try_later": ingest.try_later,
-        "acked_calls": sum(1 for r in calls if r[5]),
-        "acked_spans_in_window": sum(
-            1 for r in calls if r[5] and r[3] <= t_end) * call_spans,
-        "offered_calls": (math.ceil(window_s / ingest.interval)
-                          if ingest.interval else len(calls)),
+        "acked_calls": len(done),
+        "acked_spans": len(done) * call_spans,
+        "offered_calls": (math.ceil(seconds / ingest.interval)
+                          if ingest.interval else len(ingest.records)),
     }
     if reads is not None:
         c["reads_ok"] = sum(1 for r in reads.records if r[5] == 200)
-        rl = [(r[3] - r[2]) * 1e3 for r in reads.records]
-        if rl:
-            c["read_late_p95_ms"] = percentile(rl, 0.95)
-        lat = [(r[4] - r[2]) * 1e3 for r in reads.records if r[5] == 200]
-        if lat:
-            c["read_p95_ms"] = percentile(lat, 0.95)
-            c["read_max_ms"] = max(lat)
     return c
 
 
@@ -248,8 +251,12 @@ def run_cell(args) -> dict:
     # ring that fills first as the traffic file says, in whole calls.
     lap = lap_spans(config, traffic, int(flags[flags.index("--capacity") + 1]))
     n_prefill = math.ceil(traffic["prefill_laps"] * lap / c)
-    retained = int(config["retained_whole_share"] * lap)
     ing_spec, rd_spec = traffic["ingest"], traffic.get("reads")
+    # Held to be whole: the configuration's share of a lap (the rest is
+    # room for the daemon's own self-trace rows), less the calls that
+    # can be in flight at once, which may be committed out of send order.
+    retained = (int(config["retained_whole_share"] * lap)
+                - ing_spec["connections"] * c)
     # where a control drops a call, it drops one of the window's
     os.environ.setdefault("BENCH_FAULT_AT", str(n_prefill + 6))
 
@@ -263,7 +270,12 @@ def run_cell(args) -> dict:
     stream = None
     try:
         t0 = time.monotonic()
-        stream = Stream(args.seed, traffic["pool_spans"], c,
+        # Three passes over the pool fit in what is held whole, so the
+        # newest traces by timestamp (the last two passes') are all held:
+        # the traffic file's pool, cut only where a rehearsal's ring is
+        # too small for it.
+        stream = Stream(args.seed,
+                        min(traffic["pool_spans"], retained // 3 // c * c), c,
                         traffic["n_services"], traffic["pass_shift_us"],
                         traffic["frames_ahead"])
         stream.wait_made(min(n_prefill, traffic["frames_ahead"]))
@@ -335,19 +347,26 @@ def run_cell(args) -> dict:
             prof_thread.join()
             if "error" in prof:
                 raise prof["error"]
-        window_s = w_end - w0
         after = daemon.scrape()
         say(f"window closed: {len(ingest.records)} calls, try_later "
             f"{ingest.try_later}; rings " + ring_fill(after)
             + (f", {len(reads.records)} reads" if reads else ""))
+        acks = sorted(r[3] - w0 for r in ingest.records if r[5])
+        say("acks by second of the window: " + " ".join(
+            str(sum(1 for a in acks if k <= a < k + 1))
+            for k in range(math.ceil(seconds))))
+        gaps = sorted(((b - a, a) for a, b in zip([0.0] + acks, acks)),
+                      reverse=True)[:3]
+        say("longest waits for the next ack: " + ", ".join(
+            f"{g * 1e3:.0f} ms from {a:.2f}s" for g, a in gaps))
         starved = stream.starved_s - starved0
-        if starved > 0.01 * window_s:
+        if starved > 0.01 * seconds:
             raise RuntimeError(
                 f"the senders waited {starved:.2f}s for frames: the load "
                 "generator, not the daemon, set this window's rate")
 
         # -- results of the window -------------------------------------------
-        e2e = end_to_end(ingest, reads, window_s, w_end, setup_s, c)
+        e2e = end_to_end(ingest, reads, w0, w_end, setup_s, c, say)
         attempted = len(ingest.records) + (len(reads.records) if reads else 0)
         failed = sum(1 for r in ingest.records if not r[5]) + (
             sum(1 for r in reads.records if r[5] != 200) if reads else 0)
@@ -405,7 +424,7 @@ def run_cell(args) -> dict:
         shutil.rmtree(prof["dir"], ignore_errors=True)
         ctx = {"before": before, "after": after, "trace": trace,
                "device_kind": device["kind"], "traffic": traffic,
-               "client": client_counts(ingest, reads, window_s, w_end, c)}
+               "client": client_counts(ingest, reads, w0, seconds, c)}
         metrics = per_layer(bench, args.workload, ctx)
         if trace is not None:
             dev["busy_s"] = trace.busy_s

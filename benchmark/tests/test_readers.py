@@ -67,11 +67,11 @@ def test_trace_reduction_on_the_recorded_trace():
     # 8 units over a window four times the traced one: 2 inside the trace
     assert abs(per - 1e3 * want["ingest_step_s"] / 2) < 1e-6
     ctx.update(before={"launches_count": 10.0}, after={"launches_count": 30.0})
-    ctx["client"]["acked_spans_in_window"] = 40960
+    ctx["client"]["acked_spans"] = 40960
     per = trace_reduce.read(
         {"stat": "module_ms_per_event_unit", "patterns": ["^jit_ingest_step"],
          "events_per_unit": {"num": [{"prom": "launches_count"}],
-                             "den": [{"client": "acked_spans_in_window"}],
+                             "den": [{"client": "acked_spans"}],
                              "scale": 1000.0}}, ctx)
     # 20 launches for 40.96 kspans; two runs in the trace
     assert abs(per - 1e3 * want["ingest_step_s"] / 2 * 20 / 40.96) < 1e-6

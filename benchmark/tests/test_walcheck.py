@@ -77,3 +77,10 @@ def test_the_log_is_held_against_the_acks(tmp_path):
     assert r["acked_spans_not_in_wal"] == 1
     r = walcheck.check(wal_dir, journal, ref, late, 5, 2, lines.append)
     assert r["acked_spans_not_in_wal"] == 301
+
+
+def test_an_empty_log_holds_nothing(tmp_path):
+    ref = FakeRef([7, 14], [1, 2], [0, 0])
+    r = walcheck.check(str(tmp_path), str(tmp_path / "none.txt"), ref,
+                       {0: 1.0}, 6, 2, lambda m: None)
+    assert r == {"acked_spans_not_in_wal": 2, "acks_before_durable": 0}

@@ -112,14 +112,14 @@ def check(wal_dir: str, fsync_path: str, ref, ack_time: dict,
     """``ack_time``: {frame number: monotonic seconds its OK arrived}."""
     wal = read_wal(wal_dir)
     want_tid, want_sid, frame = ref.span_keys()
+    if not len(wal["span_id"]):  # nothing journaled: one row that matches none
+        wal.update({k: np.full(1, -1, np.int64) for k in (
+            "trace_id", "span_id", "n_ann", "n_bann", "inode", "end")})
     order = np.argsort(wal["span_id"], kind="stable")
     sids = wal["span_id"][order]
-    at = np.searchsorted(sids, want_sid)
-    at = np.minimum(at, max(len(sids) - 1, 0))
-    row = order[at] if len(sids) else np.zeros(len(want_sid), np.int64)
-    found = (len(sids) > 0) & (sids[at] == want_sid) if len(sids) else \
-        np.zeros(len(want_sid), bool)
-    whole = found & (wal["trace_id"][row] == want_tid) \
+    at = np.minimum(np.searchsorted(sids, want_sid), len(sids) - 1)
+    row = order[at]
+    whole = (sids[at] == want_sid) & (wal["trace_id"][row] == want_tid) \
         & (wal["n_ann"][row] == annotations_per_span) \
         & (wal["n_bann"][row] == binary_per_span)
     missing = int((~whole).sum())
@@ -128,17 +128,14 @@ def check(wal_dir: str, fsync_path: str, ref, ack_time: dict,
     # of its segment that returned with the file at least that long
     fsyncs = read_fsyncs(fsync_path)
     durable_at = np.full(len(want_sid), np.inf)
-    for inode, (times, sizes) in fsyncs.items():
+    for inode, (returned, sizes) in fsyncs.items():
         sel = whole & (wal["inode"][row] == inode)
         j = np.searchsorted(sizes, wal["end"][row[sel]], side="left")
-        ok = j < len(times)
-        t = np.full(len(j), np.inf)
-        t[ok] = times[j[ok]]
-        durable_at[sel] = t
-    times = np.full(int(frame.max()) + 1 if len(frame) else 0, -np.inf)
-    times[list(ack_time)] = list(ack_time.values())
-    acked_at = times[frame]
-    early = np.unique(frame[whole & (durable_at > acked_at)])
+        durable_at[sel] = np.append(returned, np.inf)[j]
+    acked = np.full(int(frame.max()) + 1 if len(frame) else 0, -np.inf)
+    acked[list(ack_time)] = list(ack_time.values())
+    late = whole & (durable_at > acked[frame])
+    early = np.unique(frame[late])
     say(f"wal: {wal['bytes']} bytes, {wal['records']} records, "
         f"{len(wal['span_id'])} spans "
         f"journaled, {sum(len(t) for t, _ in fsyncs.values())} fsyncs; "
@@ -147,7 +144,7 @@ def check(wal_dir: str, fsync_path: str, ref, ack_time: dict,
         say(f"WRONG wal: {missing} acked spans not journaled whole, first "
             f"in call {int(frame[~whole][0])}")
     if len(early):
-        lead = (durable_at - acked_at)[whole & (durable_at > acked_at)]
+        lead = (durable_at - acked[frame])[late]
         say(f"WRONG wal: {len(early)} calls acked before their fsync had "
             f"returned, e.g. call {int(early[0])}; by up to "
             f"{float(np.max(lead)) * 1e3:.1f} ms")
