@@ -274,21 +274,24 @@ class Stream:
         self.starved_s = 0.0
         self.error = None
         self._cond = threading.Condition()
+        self._salt_lock = threading.Lock()
         self._stop = False
         self._thread = threading.Thread(target=self._produce, daemon=True)
         self._thread.start()
 
     def salt(self, k: int) -> int:
         """Pass k's salt: a function of the seed and k alone, distinct
-        from every earlier pass's."""
-        while len(self.salts) <= k:
-            j = len(self.salts)
-            rng = np.random.default_rng([self.seed, 0x5A17, j])
-            x = int(rng.integers(1, 2**62))
-            while x in self.salts:
+        from every earlier pass's. (The producer and the reference both
+        ask, from different threads.)"""
+        with self._salt_lock:
+            while len(self.salts) <= k:
+                rng = np.random.default_rng(
+                    [self.seed, 0x5A17, len(self.salts)])
                 x = int(rng.integers(1, 2**62))
-            self.salts.append(x)
-        return self.salts[k]
+                while x in self.salts:
+                    x = int(rng.integers(1, 2**62))
+                self.salts.append(x)
+            return self.salts[k]
 
     def frame(self, n: int) -> bytes:
         """Frame n, once: it is dropped from the stream's memory."""
@@ -305,16 +308,6 @@ class Stream:
                     self._cond.wait(1.0)
                 self.starved_s += time.monotonic() - t0
             return self.frames.pop(n)
-
-    def wait_made(self, n: int) -> None:
-        """Block until frames [0, n) are made (set-up, before sending)."""
-        with self._cond:
-            self.taken = max(self.taken, n - self.ahead)
-            self._cond.notify_all()
-            while self.made < n:
-                if self.error is not None:
-                    raise self.error
-                self._cond.wait(1.0)
 
     def close(self) -> None:
         with self._cond:
@@ -338,7 +331,7 @@ class Stream:
                         self.made += 1
                         self._cond.notify_all()
                 k += 1
-        except BaseException as e:  # surfaced by frame()/wait_made()
+        except BaseException as e:  # surfaced by frame()
             with self._cond:
                 self.error = e
                 self._cond.notify_all()

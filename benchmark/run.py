@@ -276,12 +276,10 @@ def run_cell(args) -> dict:
         # too small for it.
         stream = Stream(args.seed,
                         min(traffic["pool_spans"], retained // 3 // c * c), c,
-                        traffic["n_services"], traffic["pass_shift_us"],
-                        traffic["frames_ahead"])
-        stream.wait_made(min(n_prefill, traffic["frames_ahead"]))
+                        traffic["n_services"], traffic["pass_shift_us"])
         say(f"stream: calls of {c} spans; one lap is {lap} spans, pre-fill "
-            f"{n_prefill} calls, held whole {retained}; pool and first "
-            f"frames made in {time.monotonic() - t0:.1f}s")
+            f"{n_prefill} calls, held whole {retained}; pool made in "
+            f"{time.monotonic() - t0:.1f}s")
         device = daemon.wait_boot(BOOT_DEADLINE_S)
         say(f"boot line after {time.monotonic() - daemon.t_spawn:.1f}s: "
             f"{device}")

@@ -75,7 +75,7 @@ def test_configs_and_traffic_state_the_lap():
             "span", "annotation", "binary"}
         assert 0 < conf["retained_whole_share"] < 1
         # the window runs on full rings
-        assert t["prefill_laps"] >= 1.0 and t["frames_ahead"] >= 16
+        assert t["prefill_laps"] >= 1.0
 
 
 def test_no_cell_name_in_harness_code():

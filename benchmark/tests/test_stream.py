@@ -37,7 +37,6 @@ def test_rekeyed_frame_decodes_to_the_same_span_with_new_ids():
     from zipkin_tpu.wire.thrift import span_from_bytes
 
     s = gen.Stream(2**31 + 7, 512, 64, 8, 10_000_000, ahead=8)
-    s.wait_made(3)
     frames = {f: s.frame(f) for f in range(32)}  # four passes, made ahead
     s.close()
     assert len(s.salts) >= 4 and s.salts[0] == 0
