@@ -45,6 +45,7 @@ from reference import Reference, hex_id  # noqa: E402
 
 BOOT_DEADLINE_S = 600.0
 STOP_DEADLINE_S = 300.0
+MAX_FILLS = 3  # pre-fills tried before a run gives up (each a fresh daemon)
 T_START = time.monotonic()
 
 
@@ -261,53 +262,84 @@ def run_cell(args) -> dict:
     os.environ.setdefault("BENCH_FAULT_AT", str(n_prefill + 6))
 
     build_codec()
-    # Inside TMPDIR, the driver's per-side directory: the WAL of a run is
-    # some hundreds of MB.
-    workdir = tempfile.mkdtemp(prefix="bench_run_")
-    daemon = Daemon(flags, platform, workdir, fault=args.fault)
-    say(f"{args.workload} seed {args.seed} seconds {seconds} trace "
-        f"{args.trace}; daemon spawned; workdir {workdir}")
-    stream = None
+    daemon = stream = None
     try:
-        t0 = time.monotonic()
-        # Three passes over the pool fit in what is held whole, so the
-        # newest traces by timestamp (the last two passes') are all held:
-        # the traffic file's pool, cut only where a rehearsal's ring is
-        # too small for it.
-        stream = Stream(args.seed,
-                        min(traffic["pool_spans"], retained // 3 // c * c), c,
-                        traffic["n_services"], traffic["pass_shift_us"])
-        say(f"stream: calls of {c} spans; one lap is {lap} spans, pre-fill "
-            f"{n_prefill} calls, held whole {retained}; pool made in "
-            f"{time.monotonic() - t0:.1f}s")
-        device = daemon.wait_boot(BOOT_DEADLINE_S)
-        say(f"boot line after {time.monotonic() - daemon.t_spawn:.1f}s: "
-            f"{device}")
-        if device["platform"] != platform:
+        # -- boot, pre-fill and warm-up: this cell's shapes, through the
+        # window's own doors, until the rings are full. The window runs on
+        # a state that the reference describes exactly: a call answered
+        # TRY_LATER is resent, and the daemon may have stored it before it
+        # pushed back (it does where the ack's wait for the fsync times
+        # out), so a daemon whose pre-fill was pushed back is stopped and
+        # a fresh one is filled with the stream's next calls. --------------
+        first = 0
+        for attempt in range(1, MAX_FILLS + 1):
+            # Inside TMPDIR, the driver's per-side directory: the WAL of a
+            # run is some hundreds of MB.
+            workdir = tempfile.mkdtemp(prefix="bench_run_")
+            daemon = Daemon(flags, platform, workdir, fault=args.fault)
+            say(f"{args.workload} seed {args.seed} seconds {seconds} trace "
+                f"{args.trace}; daemon spawned; workdir {workdir}")
+            if stream is None:  # made while the daemon boots
+                t0 = time.monotonic()
+                # Three passes over the pool fit in what is held whole, so
+                # the newest traces by timestamp (the last two passes') are
+                # all held: the traffic file's pool, cut only where a
+                # rehearsal's ring is too small for it.
+                stream = Stream(
+                    args.seed,
+                    min(traffic["pool_spans"], retained // 3 // c * c), c,
+                    traffic["n_services"], traffic["pass_shift_us"])
+                say(f"stream: calls of {c} spans; one lap is {lap} spans, "
+                    f"pre-fill {n_prefill} calls, held whole {retained}; "
+                    f"pool made in {time.monotonic() - t0:.1f}s")
+            device = daemon.wait_boot(BOOT_DEADLINE_S)
+            say(f"boot line after {time.monotonic() - daemon.t_spawn:.1f}s: "
+                f"{device}")
+            if device["platform"] != platform:
+                raise RuntimeError(
+                    f"the store's state is on {device['platform']!r}, "
+                    f"not on {platform!r}")
+            if device["count"] != cell["chips"]:
+                raise RuntimeError(
+                    f"the cell asks for {cell['chips']} chip(s); "
+                    f"the state spans {device['count']}")
+            ingest = Ingest(daemon.scribe_port, stream, ing_spec,
+                            daemon.check_alive)
+            t0 = time.monotonic()
+            ingest.run(first, first + n_prefill)
+            ingest.join()
+            if any(not r[5] for r in ingest.records):
+                raise RuntimeError("a pre-fill Log call was never acked")
+            ack_time = {r[0]: r[3] for r in ingest.records}
+            ref = Reference(stream, ack_time, retained)
+            _, never = wait_visible(daemon, ref, sorted(ack_time)[-ing_spec[
+                "connections"]:], deadline_s=600.0)
+            if never:
+                raise RuntimeError(
+                    "the pre-fill's spans never became readable")
+            # The daemon gives up an ack's wait for the fsync after 30 s:
+            # the slowest call says how near a stall (a program loaded or
+            # compiled under the store's lock) came to that.
+            slowest = max(ingest.records, key=lambda r: r[3] - r[2])
+            say(f"pre-fill: {n_prefill} calls acked and visible in "
+                f"{time.monotonic() - t0:.1f}s (try_later "
+                f"{ingest.try_later}; slowest call {slowest[0]} "
+                f"{slowest[3] - slowest[2]:.1f}s)")
+            if not ingest.try_later:
+                break
+            say(f"pre-fill {attempt} was pushed back: calls "
+                f"{sorted(r[0] for r in ingest.records if r[4] > 1)} were "
+                f"resent; a fresh daemon is filled from call "
+                f"{first + n_prefill} on")
+            rc = daemon.terminate(STOP_DEADLINE_S)
+            if rc != 0:
+                raise RuntimeError(f"daemon exited {rc} on SIGTERM")
+            shutil.rmtree(workdir, ignore_errors=True)
+            first += n_prefill
+        else:
             raise RuntimeError(
-                f"the store's state is on {device['platform']!r}, "
-                f"not on {platform!r}")
-        if device["count"] != cell["chips"]:
-            raise RuntimeError(f"the cell asks for {cell['chips']} chip(s); "
-                               f"the state spans {device['count']}")
-
-        # -- pre-fill and warm-up: this cell's shapes, through the window's
-        # own doors, until the rings are full -------------------------------
-        ingest = Ingest(daemon.scribe_port, stream, ing_spec,
-                        daemon.check_alive)
-        t0 = time.monotonic()
-        ingest.run(0, n_prefill)
-        ingest.join()
-        if any(not r[5] for r in ingest.records):
-            raise RuntimeError("a pre-fill Log call was never acked")
-        ack_time = {r[0]: r[3] for r in ingest.records}
-        ref = Reference(stream, ack_time, retained)
-        _, never = wait_visible(daemon, ref, sorted(ack_time)[-ing_spec[
-            "connections"]:], deadline_s=600.0)
-        if never:
-            raise RuntimeError("the pre-fill's spans never became readable")
-        say(f"pre-fill: {n_prefill} calls acked and visible in "
-            f"{time.monotonic() - t0:.1f}s (try_later {ingest.try_later})")
+                f"{MAX_FILLS} pre-fills in a row were pushed back "
+                "(TRY_LATER): no daemon state that the reference describes")
         ingest.records.clear()
         ingest.try_later = ingest.sent_calls = 0
         rng = np.random.default_rng([int(args.seed), 0xBEAD])
@@ -325,7 +357,7 @@ def run_cell(args) -> dict:
         say(f"window starts; setup_s {setup_s:.3f}; rings "
             + ring_fill(before))
         w0, w_end = ingest.run(
-            n_prefill, None, seconds,
+            first + n_prefill, None, seconds,
             ing_spec.get("spans_per_s") if ing_spec["loop"] == "open"
             else None)
         if reads is not None:
@@ -398,12 +430,13 @@ def run_cell(args) -> dict:
         say(f"the log held against the acks in "
             f"{time.monotonic() - t0:.1f}s")
     except BaseException:
-        daemon.kill()
-        sys.stderr.write("---- daemon stdout (tail) ----\n"
-                         + tail(daemon.out_path)
-                         + "---- daemon stderr (tail) ----\n"
-                         + tail(daemon.err_path))
-        shutil.rmtree(workdir, ignore_errors=True)
+        if daemon is not None:
+            daemon.kill()
+            sys.stderr.write("---- daemon stdout (tail) ----\n"
+                             + tail(daemon.out_path)
+                             + "---- daemon stderr (tail) ----\n"
+                             + tail(daemon.err_path))
+            shutil.rmtree(workdir, ignore_errors=True)
         raise
     finally:
         if stream is not None:

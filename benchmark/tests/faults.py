@@ -9,6 +9,14 @@ something cheaper than the whole truth.
   (the durability / read-your-acks guarantee).
 - ``ack_before_fsync``: a Log call is acked once appended, without
   waiting for the group commit's fsync (durability).
+- ``stored_then_pushed_back``: one Log call of the window is stored and
+  then answered TRY_LATER, as the program itself does where an ack's
+  wait for the fsync times out; the client resends it, so it is stored
+  twice (exact answers: every dependency link of the call counts double).
+- ``prefill_pushed_back``: the same to the third call of the pre-fill,
+  in the first daemon of a run only (the file ``BENCH_FAULT_MARK`` names
+  is made when it fires; without the variable, in every daemon). This is
+  no control: run.py has to see the push-back and fill a fresh daemon.
 - ``not_whole``: a trace read drops the last annotation of one span
   (read back whole).
 - ``stale_query``: an index query leaves out the newest trace (exact
@@ -20,6 +28,8 @@ import os
 
 def plant(name: str) -> None:
     {"lost_write": _lost_write, "ack_before_fsync": _ack_before_fsync,
+     "stored_then_pushed_back": _stored_then_pushed_back,
+     "prefill_pushed_back": _prefill_pushed_back,
      "not_whole": _not_whole, "stale_query": _stale_query}[name]()
 
 
@@ -38,6 +48,37 @@ def _lost_write() -> None:
         return real(self, payload)
 
     Collector.ingest_thrift_durable = ingest_thrift_durable
+
+
+def _push_back_after_storing(at: int) -> None:
+    from zipkin_tpu.ingest.collector import Collector
+    from zipkin_tpu.wal.log import WalDurabilityError
+
+    real = Collector.ingest_thrift_durable
+    seen = [0]
+
+    def ingest_thrift_durable(self, payload):
+        seen[0] += 1
+        mine = seen[0]  # other calls come in while this one is stored
+        stored = real(self, payload)
+        if mine == at:  # the receiver maps this to TRY_LATER
+            raise WalDurabilityError("planted: stored, then pushed back")
+        return stored
+
+    Collector.ingest_thrift_durable = ingest_thrift_durable
+
+
+def _stored_then_pushed_back() -> None:
+    _push_back_after_storing(int(os.environ["BENCH_FAULT_AT"]))
+
+
+def _prefill_pushed_back() -> None:
+    mark = os.environ.get("BENCH_FAULT_MARK")
+    if mark:
+        if os.path.exists(mark):
+            return
+        open(mark, "w").close()
+    _push_back_after_storing(3)
 
 
 def _ack_before_fsync() -> None:

@@ -20,16 +20,23 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 
 
-def run_cell(workload: str, fault: str) -> dict:
+def last_line(r) -> dict:
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def run_cell(workload: str, fault: str, env: dict = None,
+             want_rc: int = None):
+    """The result line of a run that exits 0 or, where ``want_rc`` is
+    given, the finished process itself."""
     cmd = [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
            "--workload", workload, "--seed", "2147483659", "--seconds", "2",
            "--trace", "0", "--platform", "cpu", "--capacity", "262144"]
     if fault:
         cmd += ["--fault", fault]
-    r = subprocess.run(cmd, cwd=ROOT, capture_output=True,
-                       text=True, timeout=900)
-    assert r.returncode == 0, r.stderr[-3000:]
-    return json.loads(r.stdout.strip().splitlines()[-1])
+    r = subprocess.run(cmd, cwd=ROOT, capture_output=True, text=True,
+                       timeout=900, env={**os.environ, **(env or {})})
+    assert r.returncode == (want_rc or 0), r.stderr[-3000:]
+    return r if want_rc is not None else last_line(r)
 
 
 def first_cell(loop: str) -> str:
@@ -47,6 +54,7 @@ def first_cell(loop: str) -> str:
     ("", ()),
     ("lost_write", ("acked_spans_not_in_wal", "dependency_calls_off")),
     ("ack_before_fsync", ("acks_before_durable",)),
+    ("stored_then_pushed_back", ("dependency_calls_off",)),
     ("not_whole", ("answers_wrong",)),
     ("stale_query", ("answers_wrong",)),
 ])
@@ -66,3 +74,21 @@ def test_a_planted_fault_reads_not_correct(fault, numbers):
         over = {k for k, v in line["compared"].items()
                 if v["value"] > v["limit"]}
         assert over >= set(numbers)
+
+
+def test_a_pushed_back_prefill_is_done_again_on_a_fresh_daemon(tmp_path):
+    """A call stored and then answered TRY_LATER is resent and stored
+    twice: the run may not go on with that daemon."""
+    r = run_cell(first_cell("closed"), "prefill_pushed_back",
+                 {"BENCH_FAULT_MARK": str(tmp_path / "fired")}, want_rc=0)
+    assert "pre-fill 1 was pushed back" in r.stderr
+    assert "pre-fill 2 was pushed back" not in r.stderr
+    line = last_line(r)
+    assert line["correct"] is True
+    assert all(v["value"] == 0 for v in line["compared"].values())
+
+
+def test_prefills_pushed_back_in_a_row_fail_the_run():
+    r = run_cell(first_cell("closed"), "prefill_pushed_back", want_rc=1)
+    assert "pre-fills in a row were pushed back" in r.stderr
+    assert not r.stdout.strip()
