@@ -137,6 +137,56 @@ class TestLineageTracker:
                   if labels and labels[0][0] == "stage"}
         assert {"append", "fsync"} <= stages
 
+    def test_slow_sink_holds_no_ack_and_loses_no_span(self, tmp_path):
+        """The sink (store.apply in the daemon: the encode lock, a
+        launch, at worst a compile of a minute) runs on the tracker's
+        own thread, never on the WAL's group-commit thread: a sink
+        that sleeps 2 s delays no wait_durable, and what buffers
+        meanwhile (within MAX_PENDING) is all delivered."""
+        from zipkin_tpu.wal import WriteAheadLog
+
+        got = []
+        sinking = threading.Event()
+
+        def slow_sink(spans):
+            sinking.set()
+            time.sleep(2.0)
+            got.extend(spans)
+
+        reg = obs.Registry()
+        t = LineageTracker(slow_sink, registry=reg, sample_every=1)
+        wal = WriteAheadLog(str(tmp_path / "wal"), fsync="interval",
+                            interval_s=0.01, registry=reg)
+        wal.set_on_durable(t.on_durable)
+        waits = []
+        try:
+            for i in range(40):  # 3 spans a unit: the sink wakes at 32
+                seq = wal.append(b"unit %d" % i)
+                t.note_append(seq, t.stamp())
+                t0 = time.perf_counter()
+                assert wal.wait_durable(seq, timeout=5.0)
+                waits.append(time.perf_counter() - t0)
+                if i == 20:
+                    assert sinking.wait(5.0)  # the slow call is running
+            assert max(waits) < 0.5, max(waits)
+        finally:
+            wal.close()
+        t.flush()  # waits for the call in flight, then sinks the rest
+        assert len(got) == 3 * 40
+        assert reg.get("zipkin_lineage_spans_dropped_total").value == 0
+
+    def test_buffer_bounded_while_the_sink_is_stuck(self):
+        """What no sink call has taken stays under MAX_PENDING spans;
+        the rest is dropped and counted."""
+        reg = obs.Registry()
+        t = LineageTracker(lambda spans: None, registry=reg,
+                           sample_every=1)
+        for seq in range(t.MAX_PENDING):  # 2 spans a unit, none woken
+            t.note_append(seq, t.stamp())
+        assert len(t._buf) == t.MAX_PENDING
+        assert reg.get("zipkin_lineage_spans_dropped_total").value \
+            == t.MAX_PENDING
+
     def test_pending_bounded(self):
         got, sink = _drain_spans()
         t = LineageTracker(sink, sample_every=1)

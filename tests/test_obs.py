@@ -745,3 +745,223 @@ class TestQueryEngineMetricSplit:
         # point): p99 well under the device dispatch floor.
         p99 = eng.h_serve.labels(tier="sketch").quantile_values([0.99])
         assert p99[0] < 0.01, p99
+
+
+# ---------------------------------------------------------------------------
+# One span for every stage of a Log call (obs.stage), on the profiler's
+# clock; the gauges that joined /metrics with them
+# ---------------------------------------------------------------------------
+
+CALL_SPANS = {
+    "ingest.call", "ingest.read_frame", "ingest.decode",
+    "store.lock_wait", "store.encode", "wal.append", "wal.durable_wait",
+    "store.commit", "store.dispatch", "store.device_sync_wait",
+    "wal.fsync", "lineage.flush",
+}
+PIPELINE_SPANS = {"pipeline.feed_stall", "pipeline.h2d", "pipeline.commit"}
+
+
+def _host_events(profile_dir):
+    """{span name: [stats dict per event]} of the stage spans on the
+    host planes of a capture, through the by-hand reducer's own reader
+    (scripts/trace_stages.py), which this puts under test too."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "trace_stages", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "scripts", "trace_stages.py"))
+    trace_stages = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(trace_stages)
+    spans, modules = trace_stages.read(profile_dir)
+    assert modules == []  # no device plane on the CPU
+    assert trace_stages.reduce(spans, modules)["stages"]
+    events = {}
+    for name, _line, _start, _dur, stats in spans:
+        events.setdefault(name, []).append(stats)
+    return events
+
+
+class TestStageSpans:
+    def test_stage_observes_family_child_or_given_sketch(self):
+        fam = obs.stage_family()
+        assert obs.default_registry().get(
+            "zipkin_ingest_stage_seconds") is fam
+        child = fam.labels(stage="unit_test")
+        n0 = child.count
+        with obs.stage("obs.unit_test", unit=None) as st:
+            pass
+        assert child.count == n0 + 1 and st.seconds >= 0.0
+        own = obs.LatencySketch("own_seconds", "h")
+        with obs.stage("obs.unit_test", own, unit=7) as st:
+            st.less = 10.0  # a wait timed elsewhere: never negative
+        assert own.count == 1 and own.sum == 0.0
+        assert child.count == n0 + 1
+        # done() ends the span early, once
+        with obs.stage("obs.unit_test", own) as st:
+            st.done()
+            first = st.seconds
+        assert own.count == 2 and st.seconds == first
+        text = obs.default_registry().render_text()
+        assert 'zipkin_ingest_stage_seconds_count{stage="unit_test"}' in text
+
+    @pytest.mark.parametrize("depth", [0, 2], ids=["serial", "pipelined"])
+    def test_capture_holds_every_span_by_name(self, tmp_path, monkeypatch,
+                                              depth):
+        """A CPU capture through capture() while the served write path
+        ingests three thrift batches: every span of the table is on a
+        host line of the .xplane.pb, the units of wal.append,
+        pipeline.h2d and store.commit agree, and the capture's own
+        start and stop take under a second (the Python tracer, off
+        now, took seconds)."""
+        import time
+
+        from zipkin_tpu.ingest.receiver import ResultCode
+        from zipkin_tpu.obs import profile as obs_profile
+        from zipkin_tpu.testing.scribe_rig import ScribeRig
+        from zipkin_tpu.tracegen import generate_traces
+
+        rig = ScribeRig(str(tmp_path / "wal"), pipeline_depth=depth,
+                        lineage=True)
+        spans = [s for t in generate_traces(n_traces=24, max_depth=3,
+                                            n_services=6) for s in t]
+        try:
+            # compile outside the capture; then every launch syncs
+            assert rig.log(spans[0::4]) == ResultCode.OK
+            rig.store.drain_pipeline()
+            rig.tracker.flush()
+            rig.store.drain_pipeline()
+            rig.store.INGEST_SYNC_EVERY = 1
+            if depth:
+                # a commit slow enough that the queues fill: the feed
+                # stall is a span only where the queue was full
+                real = rig.store._commit_unit
+
+                def slow_commit(unit):
+                    time.sleep(0.2)  # an ack takes a 50 ms group commit
+                    real(unit)
+
+                rig.store._commit_unit = slow_commit
+            obs_profile.capture(0.01)  # the profiler's one-off start-up
+            drove = []
+
+            def drive(_seconds):
+                t0 = time.thread_time()
+                for i in (1, 2, 3):
+                    for _ in range(3 if depth else 1):
+                        assert rig.log(spans[i::4]) == ResultCode.OK
+                rig.store.drain_pipeline()
+                rig.tracker.flush()
+                rig.store.drain_pipeline()
+                drove.append(time.thread_time() - t0)
+
+            # capture() sleeps through its window: drive it instead
+            # (its own `time` only; time.sleep is every thread's)
+            import types
+
+            monkeypatch.setattr(obs_profile, "time",
+                                types.SimpleNamespace(sleep=drive))
+            # this thread's CPU seconds, which the other workers of a
+            # loaded test run do not stretch: the Python tracer's start
+            # and stop were work on the capturing thread
+            t0 = time.thread_time()
+            out_dir, _ = obs_profile.capture(1.0, str(tmp_path / "prof"))
+            start_and_stop = time.thread_time() - t0 - drove[0]
+        finally:
+            rig.store.__dict__.pop("_commit_unit", None)
+            rig.close()
+        events = _host_events(out_dir)
+        want = CALL_SPANS | (PIPELINE_SPANS if depth else set())
+        assert want <= set(events), sorted(want - set(events))
+        units = {name: {e["unit"] for e in events[name] if "unit" in e}
+                 for name in ("wal.append", "store.commit")
+                 + (("pipeline.h2d",) if depth else ())}
+        assert units["wal.append"] and len(set(map(frozenset,
+                                                    units.values()))) == 1
+        assert all("call" in e and "conn" in e
+                   for e in events["ingest.call"])
+        assert all(e["bytes"] > 0 for e in events["ingest.read_frame"])
+        assert start_and_stop < 1.0, start_and_stop
+
+    def test_ingest_step_names_its_phases(self):
+        """jax.named_scope round the sections of ingest_step: the names
+        reach the lowered text (metadata only), so a device trace's
+        tf_op says which phase an op belongs to."""
+        from zipkin_tpu.store import device as dev
+        from zipkin_tpu.testing.scribe_rig import CONFIG
+        from zipkin_tpu.tracegen import generate_traces
+
+        spans = [s for t in generate_traces(n_traces=4, max_depth=3,
+                                            n_services=4) for s in t]
+        from zipkin_tpu.store.tpu import TpuSpanStore
+
+        store = TpuSpanStore(CONFIG, registry=obs.Registry())
+        batch = store.codec.encode(spans)
+        db = dev.make_device_batch(
+            batch, name_lc_id=store._name_lc_ids(batch),
+            indexable=np.ones(batch.n_spans, bool),
+            pad_spans=64, pad_anns=256, pad_banns=128)
+        text = dev.ingest_step.lower(store.state, db).as_text(
+            debug_info=True)
+        for phase in ("ring_write", "annotation_ring_write",
+                      "span_table_insert", "dependency_join",
+                      "index_segments", "index_write", "sketch_update",
+                      "counters"):
+            assert f"ingest.{phase}" in text, phase
+        store.close()
+
+    def test_daemon_metrics_carry_the_tcp_door_and_the_frontiers(
+            self, tmp_path):
+        """/metrics of the all-in-one daemon with --scribe-port: the
+        TCP receiver's own entry accounting (not only the HTTP
+        route's), the WAL's two frontiers and, where the backend
+        reports them, the device's memory."""
+        import socket
+
+        from zipkin_tpu.ingest.receiver import ResultCode
+        from zipkin_tpu.ingest.scribe_server import ScribeClient
+        from zipkin_tpu.main.example import (
+            build_app,
+            build_parser,
+            start_scribe,
+        )
+        from zipkin_tpu.testing.scribe_rig import log_entries
+
+        with socket.socket() as s:
+            s.bind(("127.0.0.1", 0))
+            port = s.getsockname()[1]
+        args = build_parser().parse_args([
+            "--capacity", "256", "--window-seconds", "0",
+            "--no-self-trace-ingest", "--host", "127.0.0.1",
+            "--wal-dir", str(tmp_path / "wal"), "--scribe-port", str(port)])
+        store, collector, api, _shipper = build_app(args)
+        srv = start_scribe(args, store, collector, api)
+        client = ScribeClient("127.0.0.1", port)
+        try:
+            assert client.log(log_entries([span(1), span(2)])) \
+                == ResultCode.OK
+            status, payload = api.handle("GET", "/metrics", {})
+        finally:
+            client.close()
+            srv.shutdown()
+            srv.server_close()
+            collector.close()
+            store.wal.close()
+        assert status == 200
+        text = payload.body.decode()
+        samples = dict(line.rsplit(" ", 1) for line in text.splitlines()
+                       if line and not line.startswith("#"))
+        tcp = 'zipkin_scribe_entries{transport="tcp",result="%s"}'
+        assert samples[tcp % "received"] == "2"
+        assert samples[tcp % "pushed_back"] == "0"
+        assert samples[
+            'zipkin_scribe_entries{transport="http",result="received"}'
+        ] == "0"
+        assert float(samples["zipkin_wal_last_seq"]) >= 1
+        assert (float(samples["zipkin_wal_durable_seq"])
+                == float(samples["zipkin_wal_last_seq"]))  # it was acked
+        assert "# TYPE zipkin_device_memory_bytes gauge" in text
+        import jax
+
+        if jax.devices()[0].memory_stats():
+            assert 'zipkin_device_memory_bytes{kind="peak"}' in text

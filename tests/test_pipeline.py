@@ -313,3 +313,60 @@ def test_ingest_latency_metrics_split():
     assert d["zipkin_store_ingest_step_seconds_sum"] > 0
     assert store.counters()["jit_compiles"] == dev.compile_count() > 0
     store.close()
+
+
+@pytest.mark.parametrize("depth", [0, 2], ids=["serial", "pipelined"])
+def test_stage_counts_per_log_call(tmp_path, depth):
+    """N Log calls through a ScribeServer socket: every stage of the
+    call is observed once a call (stage 1 twice on the serial path,
+    round the journal), the sync wait once a sync, and the sketches
+    the benchmark reads count what they counted before the stages
+    were rewritten onto obs.stage (the parent commit gives the same
+    numbers for this input: one launch unit, one append and, under
+    fsync=batch, one fsync a call)."""
+    from zipkin_tpu import obs
+    from zipkin_tpu.ingest.receiver import ResultCode
+    from zipkin_tpu.testing.scribe_rig import ScribeRig
+
+    stages = ("call", "read_frame", "decode", "lock_wait", "encode",
+              "durable_wait", "commit", "device_sync_wait")
+    fam = obs.stage_family()
+    rig = ScribeRig(str(tmp_path / "wal"), pipeline_depth=depth,
+                    fsync="batch")
+    try:
+        before = {s: fam.labels(stage=s).count for s in stages}
+        n = 5
+        spans = _spans(n_traces=4 * n)
+        for i in range(n):
+            assert rig.log(spans[i::n]) == ResultCode.OK
+        rig.store.drain_pipeline()
+        # the last call's span ends on the handler's thread, after the
+        # reply that the client has already read
+        deadline = time.monotonic() + 5.0
+        while (fam.labels(stage="call").count - before["call"] < n
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        count = {s: fam.labels(stage=s).count - before[s] for s in stages}
+        d = rig.registry.as_dict()
+    finally:
+        rig.close()
+    for s in ("call", "read_frame", "decode", "lock_wait",
+              "durable_wait", "commit"):
+        assert count[s] == n, (s, count)
+    assert count["encode"] == (n if depth else 2 * n)
+    syncs = d["zipkin_store_ingest_step_seconds_count"]
+    assert count["device_sync_wait"] == syncs == 1  # launch 1 of 32
+    assert d["zipkin_collector_write_seconds_count"] == n
+    assert d["zipkin_store_ingest_dispatch_seconds_count"] == n
+    assert d["zipkin_store_ingest_launches_total"] == n
+    assert d["zipkin_wal_append_seconds_count"] == n
+    assert d["zipkin_wal_fsync_seconds_count"] == n
+    assert d["zipkin_wal_last_seq"] == d["zipkin_wal_durable_seq"] == n
+    assert d["zipkin_store_jit_compiles_total"] == dev.compile_count()
+    if depth:
+        assert d["zipkin_store_pipeline_encode_seconds_count"] == n
+        assert d["zipkin_store_pipeline_stage_seconds_count"] == n
+        assert d["zipkin_store_pipeline_commit_seconds_count"] == n
+        assert d["zipkin_store_pipeline_stall_seconds_total"] >= 0.0
+    # every sum is a time: none negative, the call holds its parts
+    assert all(v >= 0 for k, v in d.items() if k.endswith("_sum"))

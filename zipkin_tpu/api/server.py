@@ -193,12 +193,7 @@ class ApiServer:
             JsonReceiver(collector.accept) if collector is not None else None
         )
         if self.scribe is not None:
-            scribe = self.scribe
-            self.registry.register(obs.CallbackFamily(
-                "zipkin_scribe_entries",
-                "Scribe receiver entry accounting "
-                "(received/ignored/bad/pushed_back)",
-                "result", lambda: dict(scribe.stats)))
+            self.scribe.export_stats(self.registry, "http")
         # Runtime-adjustable vars (HttpVar.scala:30 / the old
         # /config/sampleRate endpoint): name → (getter, setter).
         self.vars = {}

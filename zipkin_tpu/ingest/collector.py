@@ -26,6 +26,7 @@ from typing import Optional, Sequence
 from zipkin_tpu import obs
 from zipkin_tpu.ingest.queue import ItemQueue
 from zipkin_tpu.models.span import Span
+from zipkin_tpu.obs.stages import stage
 from zipkin_tpu.sampler.adaptive import (
     AdaptiveConfig,
     AdaptiveSampleRateController,
@@ -102,7 +103,8 @@ class Collector:
         self._h_write = reg.register(obs.LatencySketch(
             "zipkin_collector_write_seconds",
             "Collector batch processing latency: decode + sample + "
-            "store write, per queue item"))
+            "store write, per queue item or per durable call (the "
+            "ack's wait for the fsync is wal.durable_wait's)"))
         # Sampler-stage metrics ride the collector's registration (the
         # sampler already locks its own counts; these adapt them).
         reg.register(obs.Gauge(
@@ -189,7 +191,8 @@ class Collector:
     def ingest_durable(self, spans: Sequence[Span]) -> int:
         """Synchronous span ingest + durable-append barrier; returns
         the stored count. Drop-in ``process`` target for receivers."""
-        stored = self._write_spans(list(spans))
+        with stage("collector.write", self._h_write):
+            stored = self._write_spans(list(spans))
         self._wal_barrier()
         return stored
 
@@ -198,7 +201,8 @@ class Collector:
         drop-in ``process_thrift`` target for receivers."""
         segments = [payload] if isinstance(payload, (bytes, bytearray)) \
             else list(payload)
-        stored = self._write_thrift(segments)
+        with stage("collector.write", self._h_write):
+            stored = self._write_thrift(segments)
         self._wal_barrier()
         return stored
 
@@ -212,7 +216,9 @@ class Collector:
         if wal is not None:
             from zipkin_tpu.wal.log import WalDurabilityError
 
-            if not wal.wait_durable(wal.last_seq):
+            with stage("wal.durable_wait"):
+                durable = wal.wait_durable(wal.last_seq)
+            if not durable:
                 raise WalDurabilityError(
                     "timed out waiting for the WAL durable frontier; "
                     "refusing to ack")
@@ -255,18 +261,17 @@ class Collector:
 
     def _write(self, item) -> None:
         """Queue worker entry: time the step, process, self-trace."""
-        t0 = time.perf_counter()
         stored = 0
+        step = stage("collector.write", self._h_write)
         try:
-            if isinstance(item, _ThriftPayload):
-                stored = self._write_thrift(item.segments)
-            else:
-                stored = self._write_spans(item)
+            with step:
+                if isinstance(item, _ThriftPayload):
+                    stored = self._write_thrift(item.segments)
+                else:
+                    stored = self._write_spans(item)
         finally:
-            dt = time.perf_counter() - t0
-            self._h_write.observe(dt)
             if self.tracer is not None:
-                self._emit_self_span(dt, stored)
+                self._emit_self_span(step.seconds, stored)
 
     def _emit_self_span(self, dt_s: float, stored: int) -> None:
         from zipkin_tpu.client import B3Headers

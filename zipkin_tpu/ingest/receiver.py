@@ -9,13 +9,14 @@ base64 (KafkaProcessor.scala:25) and is covered by ``decode_thrift``.
 
 from __future__ import annotations
 
+import base64
 import enum
 import json
 import threading
 from typing import Callable, Dict, Iterable, List, Optional, Sequence
 
+from zipkin_tpu import obs
 from zipkin_tpu.ingest.queue import QueueFullException
-from zipkin_tpu.wal.log import WalDurabilityError
 from zipkin_tpu.models.span import (
     Annotation,
     AnnotationType,
@@ -24,7 +25,6 @@ from zipkin_tpu.models.span import (
     Span,
 )
 from zipkin_tpu.wire.thrift import (
-    ThriftError,
     scribe_message_to_span,
     spans_from_bytes,
 )
@@ -65,77 +65,70 @@ class ScribeReceiver:
         with self._stats_lock:
             self.stats[key] += n
 
+    def export_stats(self, registry, transport: str) -> None:
+        """This receiver's entry accounting on /metrics, read at
+        scrape: ``zipkin_scribe_entries{transport, result}``, one
+        family for every scribe door of the process (the HTTP route's
+        receiver and the TCP server's are different objects)."""
+        fam = registry.get("zipkin_scribe_entries")
+        if fam is None:
+            fam = registry.register(obs.Gauge(
+                "zipkin_scribe_entries",
+                "Scribe receiver entry accounting "
+                "(received/ignored/bad/pushed_back) per transport",
+                labelnames=("transport", "result")))
+        for key in self.stats:
+            fam.labels(transport=transport, result=key).set_function(
+                lambda k=key: self.stats[k])
+
     def log(self, entries: Sequence[tuple]) -> ResultCode:
-        """entries: (category, message) pairs — the Scribe.Log call.
+        """entries: (category, message) pairs — the Scribe.Log call."""
+        return self.deliver(self.decode(entries))
 
-        With ``process_thrift`` wired (Collector.accept_thrift), decoded
-        payloads stay raw thrift bytes end-to-end and the columnar
-        native parser runs on the collector worker — span objects are
-        never built on the hot path (the scrooge-decode role,
-        ScribeSpanReceiver.scala:96-107).
-        """
-        if self.process_thrift is not None:
-            return self._log_fast(entries)
-        spans: List[Span] = []
+    def decode(self, entries: Sequence[tuple]) -> list:
+        """Category filter + payload decode: what ``deliver`` takes.
+
+        With ``process_thrift`` wired (Collector.accept_thrift),
+        decoded payloads stay raw thrift bytes end-to-end and the
+        columnar native parser runs behind ``deliver`` — span objects
+        are never built on the hot path (the scrooge-decode role,
+        ScribeSpanReceiver.scala:96-107). Segments keep entry
+        boundaries so the collector can isolate a thrift-corrupt entry
+        instead of dropping the whole batch."""
+        fast = self.process_thrift is not None
+        out: list = []
         for category, message in entries:
             self._bump("received")
             if category.lower() not in self.categories:
                 self._bump("ignored")
                 continue
             try:
-                spans.append(scribe_message_to_span(message))
-            except ThriftError:
-                self._bump("bad")
-        if not spans:
-            return ResultCode.OK
-        try:
-            self.process(spans)
-        except (QueueFullException, WalDurabilityError):
-            # Queue full and not-yet-durable are the same answer on
-            # the wire: don't ack, client retries (the ack-after-
-            # durable-append contract, docs/DURABILITY.md).
-            self._bump("pushed_back")
-            return ResultCode.TRY_LATER
-        except Exception:
-            # The durable entries run the whole store write path on
-            # this handler thread, so its exception surface (suspect
-            # store, closing store) lands here; any of it maps to
-            # TRY_LATER — a torn connection would read as a lost batch
-            # to clients that only retry on the wire code.
-            self._bump("pushed_back")
-            return ResultCode.TRY_LATER
-        return ResultCode.OK
-
-    def _log_fast(self, entries: Sequence[tuple]) -> ResultCode:
-        import base64
-        import binascii
-
-        raws: List[bytes] = []
-        for category, message in entries:
-            self._bump("received")
-            if category.lower() not in self.categories:
-                self._bump("ignored")
-                continue
-            try:
+                if not fast:
+                    out.append(scribe_message_to_span(message))
+                    continue
                 if isinstance(message, str):
                     message = message.encode("ascii")
-                raws.append(base64.b64decode(message, validate=False))
-            except (binascii.Error, ValueError):
+                out.append(base64.b64decode(message, validate=False))
+            except ValueError:  # ThriftError, binascii.Error, non-ascii
                 self._bump("bad")
-        if not raws:
+        return out
+
+    def deliver(self, payloads: list) -> ResultCode:
+        """Hand decoded payloads on; any failure is TRY_LATER."""
+        if not payloads:
             return ResultCode.OK
         try:
-            # Segments keep entry boundaries so the collector can
-            # isolate a thrift-corrupt entry instead of dropping the
-            # whole batch.
-            self.process_thrift(raws)
-        except (QueueFullException, WalDurabilityError):
-            # See log(): not-yet-durable == backpressure on the wire.
-            self._bump("pushed_back")
-            return ResultCode.TRY_LATER
+            (self.process_thrift or self.process)(payloads)
         except Exception:
-            # See log(): any store-path failure is TRY_LATER, never a
-            # torn connection.
+            # Queue full (QueueFullException) and not-yet-durable
+            # (WalDurabilityError) are the same answer on the wire:
+            # don't ack, client retries (the ack-after-durable-append
+            # contract, docs/DURABILITY.md). The durable entries run
+            # the whole store write path on this handler thread, so
+            # its exception surface (suspect store, closing store)
+            # lands here too; any of it maps to TRY_LATER — a torn
+            # connection would read as a lost batch to clients that
+            # only retry on the wire code.
             self._bump("pushed_back")
             return ResultCode.TRY_LATER
         return ResultCode.OK
@@ -190,8 +183,6 @@ def span_from_json(d: dict) -> Span:
         t = AnnotationType[b.get("type", "STRING")]
         value = b.get("value", "")
         if t == AnnotationType.BYTES and isinstance(value, str):
-            import base64
-
             value = base64.b64decode(value)
         banns.append(
             BinaryAnnotation(
@@ -234,8 +225,6 @@ def binary_annotation_to_json(b) -> dict:
     value = b.value
     if isinstance(value, (bytes, bytearray)):
         if b.annotation_type == AnnotationType.BYTES:
-            import base64
-
             value = base64.b64encode(bytes(value)).decode("ascii")
         else:
             value = bytes(value).decode("utf-8", "replace")

@@ -23,6 +23,7 @@ import threading
 from typing import List, Optional, Tuple
 
 from zipkin_tpu.ingest.receiver import ResultCode, ScribeReceiver
+from zipkin_tpu.obs.stages import stage
 from zipkin_tpu.wire.thrift import (
     T_I32,
     T_LIST,
@@ -132,9 +133,9 @@ def handle_call(receiver: ScribeReceiver, frame: bytes) -> Optional[bytes]:
     name, seqid = _read_message_header(r)
     if name != "Log":
         return _exception_reply(name, seqid, f"unknown method {name!r}")
-    entries = _parse_log_args(r)
-    code = receiver.log(entries)
-    return _reply(name, seqid, code)
+    with stage("ingest.decode"):
+        payloads = receiver.decode(_parse_log_args(r))
+    return _reply(name, seqid, receiver.deliver(payloads))
 
 
 class _Handler(socketserver.BaseRequestHandler):
@@ -142,6 +143,8 @@ class _Handler(socketserver.BaseRequestHandler):
         sock: socket.socket = self.request
         sock.settimeout(self.server.io_timeout_s)  # type: ignore[attr-defined]
         receiver = self.server.receiver  # type: ignore[attr-defined]
+        conn = self.client_address[1]
+        call = 0
         try:
             while True:
                 header = self._read_exact(sock, 4)
@@ -150,16 +153,22 @@ class _Handler(socketserver.BaseRequestHandler):
                 (n,) = struct.unpack(">i", header)
                 if n <= 0 or n > MAX_FRAME:
                     return
-                frame = self._read_exact(sock, n)
-                if frame is None:
-                    return
-                try:
-                    out = handle_call(receiver, frame)
-                except ThriftError:
-                    return
-                if out is None:
-                    return
-                sock.sendall(struct.pack(">i", len(out)) + out)
+                # The call as the daemon sees it: from the header that
+                # announces it (the wait for a header is the client's
+                # time, not the daemon's) to the reply written.
+                call += 1
+                with stage("ingest.call", call=call, conn=conn):
+                    with stage("ingest.read_frame", bytes=n):
+                        frame = self._read_exact(sock, n)
+                    if frame is None:
+                        return
+                    try:
+                        out = handle_call(receiver, frame)
+                    except ThriftError:
+                        return
+                    if out is None:
+                        return
+                    sock.sendall(struct.pack(">i", len(out)) + out)
         except (socket.timeout, ConnectionError, OSError):
             return
 

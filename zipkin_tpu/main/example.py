@@ -634,6 +634,33 @@ def _device_summary(store) -> str:
             f" state_bytes={sum(leaf.nbytes for leaf in leaves)}")
 
 
+def start_scribe(args, store, collector, api):
+    """The scribe TCP door on ``--scribe-port``, serving in a thread;
+    its receiver's entry accounting joins /metrics beside the HTTP
+    route's (``zipkin_scribe_entries{transport="tcp"}``:
+    ``pushed_back`` is the program's own count of TRY_LATER)."""
+    from zipkin_tpu.ingest.receiver import ScribeReceiver
+    from zipkin_tpu.ingest.scribe_server import ScribeServer
+
+    # Ack contract: with a WAL, scribe's OK means "durably appended" —
+    # the receiver processes synchronously through the durable entries
+    # instead of acking from the async queue.
+    if getattr(store, "wal", None) is not None:
+        receiver = ScribeReceiver(
+            collector.ingest_durable,
+            process_thrift=collector.ingest_thrift_durable,
+        )
+    else:
+        receiver = ScribeReceiver(
+            collector.accept,
+            process_thrift=collector.accept_thrift,
+        )
+    receiver.export_stats(api.registry, "tcp")
+    scribe_srv = ScribeServer(receiver, args.host, args.scribe_port)
+    scribe_srv.serve_in_thread()
+    return scribe_srv
+
+
 def main(argv=None) -> None:
     args = build_parser().parse_args(argv)
     if args.platform:
@@ -660,26 +687,8 @@ def main(argv=None) -> None:
 
         ship_srv = ShipServer(shipper, args.host, args.ship_port)
         ship_srv.serve_in_thread()
-    scribe_srv = None
-    if args.scribe_port:
-        from zipkin_tpu.ingest.receiver import ScribeReceiver
-        from zipkin_tpu.ingest.scribe_server import ScribeServer
-
-        # Ack contract: with a WAL, scribe's OK means "durably
-        # appended" — the receiver processes synchronously through the
-        # durable entries instead of acking from the async queue.
-        if getattr(store, "wal", None) is not None:
-            receiver = ScribeReceiver(
-                collector.ingest_durable,
-                process_thrift=collector.ingest_thrift_durable,
-            )
-        else:
-            receiver = ScribeReceiver(
-                collector.accept,
-                process_thrift=collector.accept_thrift,
-            )
-        scribe_srv = ScribeServer(receiver, args.host, args.scribe_port)
-        scribe_srv.serve_in_thread()
+    scribe_srv = (start_scribe(args, store, collector, api)
+                  if args.scribe_port else None)
     print(f"zipkin-tpu example serving on {args.host}:{args.port}"
           + (f" (scribe tcp :{args.scribe_port})" if scribe_srv else "")
           + (f" (wal-ship tcp :{args.ship_port})" if ship_srv else "")

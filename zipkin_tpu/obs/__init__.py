@@ -28,6 +28,10 @@ across process boundaries: causal self-tracing over the ship
 protocol, pushed-snapshot metrics federation (``/metrics?fleet=1``),
 and the stall watchdog + flight recorder behind ``/api/health`` /
 ``/debug/events``.
+
+``obs.stage`` (``obs/stages.py``) is the write path's one timing
+helper: a span in the profiler's own trace plus a sketch observation
+(``zipkin_ingest_stage_seconds{stage}`` where the site names none).
 """
 
 from zipkin_tpu.obs.fleet import (
@@ -48,6 +52,7 @@ from zipkin_tpu.obs.registry import (
     Registry,
     default_registry,
 )
+from zipkin_tpu.obs.stages import stage, stage_family
 
 __all__ = [
     "CallbackFamily",
@@ -64,4 +69,6 @@ __all__ = [
     "merge_sketches",
     "registry_snapshot",
     "render_federated",
+    "stage",
+    "stage_family",
 ]

@@ -76,6 +76,7 @@ from zipkin_tpu.obs.registry import (
     _label_str,
     escape_help,
 )
+from zipkin_tpu.obs.stages import stage
 
 # ---------------------------------------------------------------------------
 # request-context propagation (API handler → dispatcher / downstream)
@@ -171,21 +172,28 @@ class LineageTracker:
     (spans land in the system's own store, ride the WAL, and therefore
     replicate to standbys bitwise like any other span).
 
-    Threading: ``stamp``/``note_append`` run on the encoding thread
-    UNDER the store's encode lock, so they only ever buffer;
-    ``on_durable`` runs on the WAL's group-commit thread (no locks
-    held) — or, under ``fsync=off``/``batch``, synchronously inside
-    ``wal.append`` while the encode lock is still held, which is why
-    the store wraps the append in ``suppressed()`` (flushing there
-    would re-enter the encode lock). Flushes happen from ``on_durable``
-    (sync thread), ``note_shipped`` (ship handler thread), and
-    ``flush()`` — all outside the store's write path. The sink call
-    itself sets a thread-local ``emitting`` flag so the spans' own
-    journaling is never sampled (no feedback trace)."""
+    Threading: every hook only BUFFERS. ``stamp``/``note_append`` run
+    on the encoding thread under the store's encode lock;
+    ``on_durable`` runs on the WAL's group-commit thread (or, under
+    ``fsync=off``/``batch``, inside ``wal.append``); ``note_shipped``
+    on a ship handler. The sink (``store.apply``: the encode lock, a
+    launch, at worst a 52 s compile of a new launch shape) is called
+    on a thread of the tracker's own, woken when ``FLUSH_AT`` spans
+    wait — a slow sink must never hold the group commit, or no fsync
+    happens, acks time out after their call was stored, and the resent
+    calls are stored twice (PERF.md 6, PR 24). The buffer is bounded
+    at ``MAX_PENDING`` spans; what a stuck sink cannot take is dropped
+    and counted. ``flush()`` is synchronous on the caller (tests, the
+    ordered shutdown) and waits for a sink call in flight; under
+    ``suppressed()`` (the store holds its encode lock round the
+    append) it is a no-op. The sink call sets a thread-local
+    ``emitting`` flag so the spans' own journaling is never sampled
+    (no feedback trace)."""
 
     SAMPLE_EVERY = 64   # first unit always sampled
     FLUSH_AT = 32       # buffered spans per sink call (launch amortization)
-    MAX_PENDING = 4096  # sampled units awaiting fsync/ship
+    MAX_PENDING = 4096  # sampled units awaiting fsync/ship; buffered spans
+    FLUSH_IDLE_S = 5.0  # the flush thread ends after this long without work
 
     def __init__(self, sink: Callable[[List[Span]], None],
                  registry: Optional[Registry] = None,
@@ -196,12 +204,14 @@ class LineageTracker:
         self.service_name = service_name
         self.sample_every = max(int(sample_every or self.SAMPLE_EVERY), 1)
         self._clock = clock
-        self._lock = threading.Lock()  # lock-order: 82 fleet-trace
+        self._lock = threading.Condition()  # lock-order: 82 fleet-trace
         self._tl = threading.local()
         self._rng = random.Random()          # guarded-by: _lock
         self._units = 0                      # guarded-by: _lock
         self._pending = collections.OrderedDict()  # guarded-by: _lock
         self._buf: List[Span] = []           # guarded-by: _lock
+        self._flusher: Optional[threading.Thread] = None  # guarded-by: _lock
+        self._sinking = False                # guarded-by: _lock
         reg = registry
         self._h_stage = None
         self._c_units = None
@@ -275,9 +285,9 @@ class LineageTracker:
 
     @contextlib.contextmanager
     def suppressed(self):
-        """No-flush guard for callbacks fired synchronously inside the
-        store's write path (``fsync=off``/``batch`` appends invoke
-        ``on_durable`` on the appending thread)."""
+        """No-flush guard for a caller that holds the store's encode
+        lock: ``flush()`` calls the sink (``store.apply``), which
+        would re-enter that lock."""
         prev = getattr(self._tl, "suppress", False)
         self._tl.suppress = True
         try:
@@ -290,8 +300,8 @@ class LineageTracker:
     def on_durable(self, durable_seq: int) -> None:
         """WAL durable-frontier callback: emit ``wal fsync`` children
         for every pending unit now covered. Runs on the group-commit
-        thread (flushes) or inside an append under ``suppressed()``
-        (buffers only)."""
+        thread or inside an append; buffers only, and wakes the flush
+        thread when a batch waits."""
         now_us = int(self._clock() * 1e6)
         spans: List[Span] = []
         with self._lock:
@@ -364,37 +374,72 @@ class LineageTracker:
 
     # -- buffering / emission -------------------------------------------
 
-    def _observe(self, stage: str, dur_us: float) -> None:
+    def _observe(self, stage_name: str, dur_us: float) -> None:
         if self._h_stage is not None:
-            self._h_stage.labels(stage=stage).observe(
+            self._h_stage.labels(stage=stage_name).observe(
                 max(dur_us, 1) / 1e6)
 
     def _push(self, spans: List[Span], flush: bool = True) -> None:
         with self._lock:
-            self._buf.extend(spans)
+            room = max(self.MAX_PENDING - len(self._buf), 0)
+            self._buf.extend(spans[:room])
+        if len(spans) > room and self._c_drops is not None:
+            self._c_drops.inc(len(spans) - room)
         if flush:
             self._maybe_flush()
 
-    def _maybe_flush(self, force: bool = False) -> None:
-        if getattr(self._tl, "suppress", False):
-            return
+    def _maybe_flush(self) -> None:
+        """Wake the flush thread once a batch waits. Never calls the
+        sink: this runs on the group-commit and ship threads."""
         with self._lock:
-            if not self._buf or (not force
-                                 and len(self._buf) < self.FLUSH_AT):
+            if len(self._buf) < self.FLUSH_AT:
+                return
+            if self._flusher is None:
+                self._flusher = threading.Thread(
+                    target=self._flush_loop,
+                    name="zipkin-lineage-flush", daemon=True)
+                self._flusher.start()
+            self._lock.notify_all()
+
+    def _flush_loop(self) -> None:
+        """The tracker's own thread: sink calls, one batch at a time.
+        It ends after FLUSH_IDLE_S without work (a tracker has no
+        close(); the next batch starts another)."""
+        while True:
+            with self._lock:
+                while self._sinking or len(self._buf) < self.FLUSH_AT:
+                    if (not self._lock.wait(self.FLUSH_IDLE_S)
+                            and len(self._buf) < self.FLUSH_AT):
+                        self._flusher = None
+                        return
+            self._sink_buffered()
+
+    def _sink_buffered(self) -> None:
+        """One sink call with everything buffered; one at a time."""
+        with self._lock:
+            while self._sinking:
+                self._lock.wait()
+            if not self._buf:
                 return
             batch, self._buf = self._buf, []
+            self._sinking = True
         self._tl.emitting = True
         try:
-            self.sink(batch)
+            with stage("lineage.flush", spans=len(batch)):
+                self.sink(batch)
         except Exception:  # graftlint: disable=swallowed-exception
             # Self-tracing must never fail the pipeline it observes.
             if self._c_drops is not None:
                 self._c_drops.inc(len(batch))
         finally:
             self._tl.emitting = False
+            with self._lock:
+                self._sinking = False
+                self._lock.notify_all()
 
     def flush(self) -> None:
-        self._maybe_flush(force=True)
+        if not getattr(self._tl, "suppress", False):
+            self._sink_buffered()
 
     def pending(self) -> int:
         with self._lock:

@@ -5,6 +5,13 @@ singleton, so a second concurrent start would abort the first trace.
 The API exposes this as ``POST /debug/profile?seconds=N`` — the caller
 blocks for the window (ThreadingHTTPServer gives it its own thread) and
 gets back the trace directory, viewable with TensorBoard / Perfetto.
+
+The Python tracer is OFF (``python_tracer_level = 0``): hooking every
+Python call stalled the daemon for seconds at the capture's start and
+stop (one ack waited 6.1 s in a traced benchmark run), which bent the
+very window the trace was taken to measure. The write path's stages
+are in the trace by name instead (``obs.stage``), on host lines of the
+same ``.xplane.pb`` as the device plane.
 """
 
 from __future__ import annotations
@@ -37,7 +44,9 @@ def capture(seconds: float, out_dir: Optional[str] = None
         import jax
 
         out_dir = out_dir or tempfile.mkdtemp(prefix="zipkin-tpu-profile-")
-        jax.profiler.start_trace(out_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(out_dir, profiler_options=options)
         try:
             time.sleep(seconds)
         finally:

@@ -2281,243 +2281,249 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
     # still holds, so every liveness check downstream is layout-blind.
     # Either way the column writes ride the fast unique plane scatter
     # (_uset).
-    if c.paged_enabled:
-        R = c.page_rows
-        RC = b.reclaim_page.shape[0]
-        # Invalidate every row of the pages this unit reclaims BEFORE
-        # the batch writes land (the functional update chain fixes the
-        # order): the planner spliced these pages out of their owners'
-        # chains, and a stale row_gid would keep the old spans visible
-        # to the ring-scan kernels but not the page gather. The
-        # reclaimed rows were captured host-side before this launch
-        # (TpuSpanStore._capture_pages), so the captured-before-
-        # overwrite invariant holds per page.
-        r_slots = (
-            b.reclaim_page[:, None] * R
-            + jnp.arange(R, dtype=jnp.int32)[None, :]
-        ).reshape(-1)
-        r_ok = jnp.repeat(b.reclaim_page >= 0, R)
-        row_gid0 = _uset(
-            state.row_gid, r_slots, jnp.full(RC * R, -1, jnp.int64),
-            r_ok,
-        )
-        gids = b.span_gid
-        slots = b.span_slot
-    else:
-        row_gid0 = state.row_gid
-        gids = state.write_pos + jnp.arange(P, dtype=jnp.int64)
-        slots = (gids % c.capacity).astype(jnp.int32)
-    upd = {}
-    for col in (
-        "trace_id", "span_id", "parent_id", "name_id", "name_lc_id",
-        "service_id", "ts_cs", "ts_cr", "ts_sr", "ts_ss", "ts_first",
-        "ts_last", "duration", "flags", "indexable",
-    ):
-        upd[col] = _uset(getattr(state, col), slots, getattr(b, col),
-                         mask)
-    upd["row_gid"] = _uset(row_gid0, slots, gids, mask)
-    upd["write_pos"] = state.write_pos + b.n_spans.astype(jnp.int64)
+    with jax.named_scope("ingest.ring_write"):
+        if c.paged_enabled:
+            R = c.page_rows
+            RC = b.reclaim_page.shape[0]
+            # Invalidate every row of the pages this unit reclaims BEFORE
+            # the batch writes land (the functional update chain fixes the
+            # order): the planner spliced these pages out of their owners'
+            # chains, and a stale row_gid would keep the old spans visible
+            # to the ring-scan kernels but not the page gather. The
+            # reclaimed rows were captured host-side before this launch
+            # (TpuSpanStore._capture_pages), so the captured-before-
+            # overwrite invariant holds per page.
+            with jax.named_scope("ingest.evict_pages"):
+                r_slots = (
+                    b.reclaim_page[:, None] * R
+                    + jnp.arange(R, dtype=jnp.int32)[None, :]
+                ).reshape(-1)
+                r_ok = jnp.repeat(b.reclaim_page >= 0, R)
+                row_gid0 = _uset(
+                    state.row_gid, r_slots, jnp.full(RC * R, -1, jnp.int64),
+                    r_ok,
+                )
+            gids = b.span_gid
+            slots = b.span_slot
+        else:
+            row_gid0 = state.row_gid
+            gids = state.write_pos + jnp.arange(P, dtype=jnp.int64)
+            slots = (gids % c.capacity).astype(jnp.int32)
+        upd = {}
+        for col in (
+            "trace_id", "span_id", "parent_id", "name_id", "name_lc_id",
+            "service_id", "ts_cs", "ts_cr", "ts_sr", "ts_ss", "ts_first",
+            "ts_last", "duration", "flags", "indexable",
+        ):
+            upd[col] = _uset(getattr(state, col), slots, getattr(b, col),
+                             mask)
+        upd["row_gid"] = _uset(row_gid0, slots, gids, mask)
+        upd["write_pos"] = state.write_pos + b.n_spans.astype(jnp.int64)
 
     # -- annotation ring writes ----------------------------------------
     # Annotation/binary rings stay FIFO under BOTH layouts (ann rows
     # have no pages; their liveness rides the owning span's gid via
     # _span_slot), so ring-age ordering and the _iq freshness gates
     # keep working unchanged in paged mode.
-    a_gids = state.ann_write_pos + jnp.arange(PA, dtype=jnp.int64)
-    a_slots = (a_gids % c.ann_capacity).astype(jnp.int32)
-    if c.paged_enabled:
-        span_gid_of_ann = gids[b.ann_span_idx]
-    else:
-        span_gid_of_ann = state.write_pos + b.ann_span_idx.astype(jnp.int64)
-    upd["ann_gid"] = _uset(
-        state.ann_gid, a_slots, jnp.where(mask_a, span_gid_of_ann, -1),
-        mask_a,
-    )
-    for col in ("ann_ts", "ann_value_id", "ann_service_id", "ann_endpoint_id"):
-        upd[col] = _uset(getattr(state, col), a_slots, getattr(b, col),
-                         mask_a)
-    upd["ann_write_pos"] = state.ann_write_pos + b.n_anns.astype(jnp.int64)
+    with jax.named_scope("ingest.annotation_ring_write"):
+        a_gids = state.ann_write_pos + jnp.arange(PA, dtype=jnp.int64)
+        a_slots = (a_gids % c.ann_capacity).astype(jnp.int32)
+        if c.paged_enabled:
+            span_gid_of_ann = gids[b.ann_span_idx]
+        else:
+            span_gid_of_ann = state.write_pos + b.ann_span_idx.astype(jnp.int64)
+        upd["ann_gid"] = _uset(
+            state.ann_gid, a_slots, jnp.where(mask_a, span_gid_of_ann, -1),
+            mask_a,
+        )
+        for col in ("ann_ts", "ann_value_id", "ann_service_id", "ann_endpoint_id"):
+            upd[col] = _uset(getattr(state, col), a_slots, getattr(b, col),
+                             mask_a)
+        upd["ann_write_pos"] = state.ann_write_pos + b.n_anns.astype(jnp.int64)
 
-    bb_gids = state.bann_write_pos + jnp.arange(PB, dtype=jnp.int64)
-    bb_slots = (bb_gids % c.bann_capacity).astype(jnp.int32)
-    if c.paged_enabled:
-        span_gid_of_bann = gids[b.bann_span_idx]
-    else:
-        span_gid_of_bann = state.write_pos + b.bann_span_idx.astype(jnp.int64)
-    upd["bann_gid"] = _uset(
-        state.bann_gid, bb_slots,
-        jnp.where(mask_b, span_gid_of_bann, -1), mask_b,
-    )
-    for col in (
-        "bann_key_id", "bann_value_id", "bann_type", "bann_service_id",
-        "bann_endpoint_id",
-    ):
-        upd[col] = _uset(getattr(state, col), bb_slots, getattr(b, col),
-                         mask_b)
-    upd["bann_write_pos"] = state.bann_write_pos + b.n_banns.astype(jnp.int64)
+        bb_gids = state.bann_write_pos + jnp.arange(PB, dtype=jnp.int64)
+        bb_slots = (bb_gids % c.bann_capacity).astype(jnp.int32)
+        if c.paged_enabled:
+            span_gid_of_bann = gids[b.bann_span_idx]
+        else:
+            span_gid_of_bann = state.write_pos + b.bann_span_idx.astype(jnp.int64)
+        upd["bann_gid"] = _uset(
+            state.bann_gid, bb_slots,
+            jnp.where(mask_b, span_gid_of_bann, -1), mask_b,
+        )
+        for col in (
+            "bann_key_id", "bann_value_id", "bann_type", "bann_service_id",
+            "bann_endpoint_id",
+        ):
+            upd[col] = _uset(getattr(state, col), bb_slots, getattr(b, col),
+                             mask_b)
+        upd["bann_write_pos"] = state.bann_write_pos + b.n_banns.astype(jnp.int64)
 
     # -- streaming dependency join -------------------------------------
     # Insert this batch's spans into the hash table FIRST so same-batch
     # parents resolve immediately, then probe each child for its parent
     # (ZipkinAggregateJob.scala:26-38 as a streaming hash join; r2's
     # O(ring) sort-join cost seconds per pass at scale).
-    skey = _mix48(b.trace_id, b.span_id)
-    tab = _tab_insert(state.span_tab, skey, b.service_id, mask)
-    upd["span_tab"] = tab
-    resolved, link_id, pending, ckey = _resolve_links(
-        tab, b.trace_id, b.span_id, b.parent_id, b.service_id,
-        b.service_id, b.duration, mask, mask & b.has_parent, S,
-    )
-    upd["dep_window"], upd["dep_window_ts"] = _window_fold(
-        state.dep_window, state.dep_window_ts, b.duration, link_id,
-        resolved, b.ts_first, b.ts_last, S,
-    )
-    # Children whose parent hasn't arrived yet wait in the pending ring
-    # (re-probed by dep_sweep); the ring overwrites oldest-first, the
-    # bounded-wait analogue of the reference's index TTL.
-    Qp = state.pend_key.shape[0]
-    rank = jnp.cumsum(pending.astype(jnp.int64)) - 1
-    pslot = ((state.pend_pos + rank) % Qp).astype(jnp.int32)
-    upd["pend_key"] = _uset(state.pend_key, pslot,
-                            _tab_pack(ckey, b.service_id), pending)
-    upd["pend_dur"] = _uset(state.pend_dur, pslot, b.duration, pending)
-    upd["pend_tsf"] = _uset(state.pend_tsf, pslot, b.ts_first, pending)
-    upd["pend_tsl"] = _uset(state.pend_tsl, pslot, b.ts_last, pending)
-    upd["pend_pos"] = state.pend_pos + pending.sum(dtype=jnp.int64)
+    with jax.named_scope("ingest.span_table_insert"):
+        skey = _mix48(b.trace_id, b.span_id)
+        tab = _tab_insert(state.span_tab, skey, b.service_id, mask)
+        upd["span_tab"] = tab
+    with jax.named_scope("ingest.dependency_join"):
+        resolved, link_id, pending, ckey = _resolve_links(
+            tab, b.trace_id, b.span_id, b.parent_id, b.service_id,
+            b.service_id, b.duration, mask, mask & b.has_parent, S,
+        )
+        upd["dep_window"], upd["dep_window_ts"] = _window_fold(
+            state.dep_window, state.dep_window_ts, b.duration, link_id,
+            resolved, b.ts_first, b.ts_last, S,
+        )
+        # Children whose parent hasn't arrived yet wait in the pending ring
+        # (re-probed by dep_sweep); the ring overwrites oldest-first, the
+        # bounded-wait analogue of the reference's index TTL.
+        Qp = state.pend_key.shape[0]
+        rank = jnp.cumsum(pending.astype(jnp.int64)) - 1
+        pslot = ((state.pend_pos + rank) % Qp).astype(jnp.int32)
+        upd["pend_key"] = _uset(state.pend_key, pslot,
+                                _tab_pack(ckey, b.service_id), pending)
+        upd["pend_dur"] = _uset(state.pend_dur, pslot, b.duration, pending)
+        upd["pend_tsf"] = _uset(state.pend_tsf, pslot, b.ts_first, pending)
+        upd["pend_tsl"] = _uset(state.pend_tsl, pslot, b.ts_last, pending)
+        upd["pend_pos"] = state.pend_pos + pending.sum(dtype=jnp.int64)
 
     # -- index column families -----------------------------------------
     # (written before the counter block; the ann-derived columns below
     # are shared with the presence/top-annotation updates further down)
     n_key_drops = jnp.int64(0)
     if c.use_index:
-        lay, _, _ = c.idx_layout
-        # Coarse-war granularity for ALL the gid watermarks in this
-        # step (ann_poison, key_wm, the trace-segment wm): overstate by at most
-        # capacity / 2^_WM_COARSE_FRAC_BITS — a sub-percent slice of
-        # each gate's >= 1-ring trust margin (gates trust iff
-        # wm < write_pos - capacity, and displaced entries are
-        # ring-laps old whenever a gate is consulted in steady state).
-        wm_shift = max(0, c.capacity.bit_length() - 1
-                       - _WM_COARSE_FRAC_BITS)
-        a_host = b.ann_service_id
-        a_idx_ok = mask_a & (a_host >= 0) & (a_host < S)
-        gid_a = jnp.where(a_idx_ok, span_gid_of_ann, -1)
-        ts_a = b.ts_last[b.ann_span_idx]
+        with jax.named_scope("ingest.index_segments"):
+            lay, _, _ = c.idx_layout
+            # Coarse-war granularity for ALL the gid watermarks in this
+            # step (ann_poison, key_wm, the trace-segment wm): overstate by at most
+            # capacity / 2^_WM_COARSE_FRAC_BITS — a sub-percent slice of
+            # each gate's >= 1-ring trust margin (gates trust iff
+            # wm < write_pos - capacity, and displaced entries are
+            # ring-laps old whenever a gate is consulted in steady state).
+            wm_shift = max(0, c.capacity.bit_length() - 1
+                           - _WM_COARSE_FRAC_BITS)
+            a_host = b.ann_service_id
+            a_idx_ok = mask_a & (a_host >= 0) & (a_host < S)
+            gid_a = jnp.where(a_idx_ok, span_gid_of_ann, -1)
+            ts_a = b.ts_last[b.ann_span_idx]
 
-        def seg(fam, local_bucket, gid, verify, ts, ok):
-            """One concatenation segment of the combined write: global
-            bucket, first-slot row, depth vectors + the entry payload.
-            The service family is not per-key-tracked (its bucket IS the
-            key — no aliasing — and its verify words are raw service ids
-            whose key48 would all collide); it MUST stay the first
-            segment — _index_write takes the keyed families as the
-            suffix from ``keyed_from``."""
-            b_base, s_base, n_b, depth = lay[fam]
-            lb = jnp.clip(local_bucket, 0, n_b - 1)
-            n = lb.shape[0]
-            return fam, (
-                lb.astype(jnp.int32) + jnp.int32(b_base),
-                lb.astype(jnp.int64) * depth + jnp.int64(s_base),
-                jnp.full(n, depth, jnp.int32),
-                jnp.asarray(gid, jnp.int64),
-                jnp.asarray(verify, jnp.int64),
-                jnp.asarray(ts, jnp.int64),
-                ok,
+            def seg(fam, local_bucket, gid, verify, ts, ok):
+                """One concatenation segment of the combined write: global
+                bucket, first-slot row, depth vectors + the entry payload.
+                The service family is not per-key-tracked (its bucket IS the
+                key — no aliasing — and its verify words are raw service ids
+                whose key48 would all collide); it MUST stay the first
+                segment — _index_write takes the keyed families as the
+                suffix from ``keyed_from``."""
+                b_base, s_base, n_b, depth = lay[fam]
+                lb = jnp.clip(local_bucket, 0, n_b - 1)
+                n = lb.shape[0]
+                return fam, (
+                    lb.astype(jnp.int32) + jnp.int32(b_base),
+                    lb.astype(jnp.int64) * depth + jnp.int64(s_base),
+                    jnp.full(n, depth, jnp.int32),
+                    jnp.asarray(gid, jnp.int64),
+                    jnp.asarray(verify, jnp.int64),
+                    jnp.asarray(ts, jnp.int64),
+                    ok,
+                )
+
+            segments = []
+            # Service family: bucket = the annotation's own host service —
+            # exactly the rows the scan kernel matches for a service query.
+            segments.append(seg(
+                StoreConfig.CAND_SVC, a_host, gid_a, a_host, ts_a, a_idx_ok
+            ))
+            # (service, span name) family.
+            ann_name_lc_i = b.name_lc_id[b.ann_span_idx]
+            nm_ok = a_idx_ok & (ann_name_lc_i >= 0)
+            nm_mix = _mixb([a_host, ann_name_lc_i])
+            segments.append(seg(
+                StoreConfig.CAND_NAME, _bucket_of(nm_mix, c.name_buckets),
+                gid_a, _verify_of(nm_mix), ts_a, nm_ok,
+            ))
+            # (service, annotation value) family: a span's value can match a
+            # query under ANY of its hosts (per-slot semantics of the scan /
+            # the in-memory oracle), so entries are written under the span's
+            # host-set (min, max) pair. Core annotations are never queryable
+            # (SpanStore.scala:199) and are skipped.
+            hmin, hmax = _span_host_range(a_host, b.ann_span_idx, a_idx_ok, P)
+            h1 = hmin[b.ann_span_idx]
+            h2 = hmax[b.ann_span_idx]
+            # A 3+-distinct-host span is indexed under (min, max) only: its
+            # MIDDLE hosts' annotation-family buckets could claim complete
+            # answers that silently omit it. Record the span's gid against
+            # each middle host; queries for that service distrust the
+            # annotation fast paths until the span is evicted (see
+            # StoreState.ann_poison).
+            mid = a_idx_ok & (a_host != h1) & (a_host != h2)
+            v_ok = (
+                mask_a & (b.ann_value_id >= FIRST_USER_ANNOTATION_ID)
+                & (b.ann_value_id < jnp.int32(1 << 30))
             )
-
-        segments = []
-        # Service family: bucket = the annotation's own host service —
-        # exactly the rows the scan kernel matches for a service query.
-        segments.append(seg(
-            StoreConfig.CAND_SVC, a_host, gid_a, a_host, ts_a, a_idx_ok
-        ))
-        # (service, span name) family.
-        ann_name_lc_i = b.name_lc_id[b.ann_span_idx]
-        nm_ok = a_idx_ok & (ann_name_lc_i >= 0)
-        nm_mix = _mixb([a_host, ann_name_lc_i])
-        segments.append(seg(
-            StoreConfig.CAND_NAME, _bucket_of(nm_mix, c.name_buckets),
-            gid_a, _verify_of(nm_mix), ts_a, nm_ok,
-        ))
-        # (service, annotation value) family: a span's value can match a
-        # query under ANY of its hosts (per-slot semantics of the scan /
-        # the in-memory oracle), so entries are written under the span's
-        # host-set (min, max) pair. Core annotations are never queryable
-        # (SpanStore.scala:199) and are skipped.
-        hmin, hmax = _span_host_range(a_host, b.ann_span_idx, a_idx_ok, P)
-        h1 = hmin[b.ann_span_idx]
-        h2 = hmax[b.ann_span_idx]
-        # A 3+-distinct-host span is indexed under (min, max) only: its
-        # MIDDLE hosts' annotation-family buckets could claim complete
-        # answers that silently omit it. Record the span's gid against
-        # each middle host; queries for that service distrust the
-        # annotation fast paths until the span is evicted (see
-        # StoreState.ann_poison).
-        mid = a_idx_ok & (a_host != h1) & (a_host != h2)
-        v_ok = (
-            mask_a & (b.ann_value_id >= FIRST_USER_ANNOTATION_ID)
-            & (b.ann_value_id < jnp.int32(1 << 30))
-        )
-        for h, extra in ((h1, None), (h2, h2 != h1)):
-            ok = v_ok & (h >= 0) & (h < S)
-            if extra is not None:
-                ok &= extra
-            mix = _mixb([h, b.ann_value_id])
+            for h, extra in ((h1, None), (h2, h2 != h1)):
+                ok = v_ok & (h >= 0) & (h < S)
+                if extra is not None:
+                    ok &= extra
+                mix = _mixb([h, b.ann_value_id])
+                segments.append(seg(
+                    StoreConfig.CAND_ANN, _bucket_of(mix, c.ann_buckets),
+                    jnp.where(ok, span_gid_of_ann, -1), _verify_of(mix),
+                    ts_a, ok,
+                ))
+            # (service, binary key[, value]) family: two bucket keyings per
+            # host — with the value (valued queries) and with a -1 sentinel
+            # (key-only queries) — under the span's host-set pair.
+            bh1 = hmin[b.bann_span_idx]
+            bh2 = hmax[b.bann_span_idx]
+            bk_idx_ok = mask_b & (b.bann_key_id >= 0)
+            ts_b = b.ts_last[b.bann_span_idx]
+            no_val = jnp.full(PB, -1, jnp.int32)
+            for h, val, extra in (
+                (bh1, b.bann_value_id, None), (bh2, b.bann_value_id, bh2 != bh1),
+                (bh1, no_val, None), (bh2, no_val, bh2 != bh1),
+            ):
+                ok = bk_idx_ok & (h >= 0) & (h < S)
+                if extra is not None:
+                    ok &= extra
+                mix = _mixb([h, b.bann_key_id, val])
+                segments.append(seg(
+                    StoreConfig.CAND_BANN, _bucket_of(mix, c.bann_buckets),
+                    jnp.where(ok, span_gid_of_bann, -1), _verify_of(mix),
+                    ts_b, ok,
+                ))
+            # keyed_from depends on the un-keyed SVC family being the SINGLE
+            # leading segment; a reorder would silently poison the key table
+            # (service verify words all collide in key48 space) — assert the
+            # invariant structurally, at trace time.
+            fams = [f for f, _ in segments]
+            assert (fams[0] == StoreConfig.CAND_SVC
+                    and StoreConfig.CAND_SVC not in fams[1:]), fams
+            n_cand_rows = sum(p[0].shape[0] for _, p in segments)
+            # Trace-membership families trail the candidate segments in the
+            # SAME unified concatenation: row gids bucketed by trace-id
+            # hash, one sub-family per ring (whole-trace fetch + durations).
+            # Verify carries the trace mix, ts the row's last_ts — the
+            # arena rows are uniform (gid, verify, ts) triples.
+            tb = _bucket_of(_mixb([b.trace_id]), c.trace_buckets)
+            tmix = _verify_of(_mixb([b.trace_id]))
+            NC = StoreConfig.N_CAND_FAMILIES
             segments.append(seg(
-                StoreConfig.CAND_ANN, _bucket_of(mix, c.ann_buckets),
-                jnp.where(ok, span_gid_of_ann, -1), _verify_of(mix),
-                ts_a, ok,
+                NC + StoreConfig.TR_SPAN, tb, gids, tmix, b.ts_last, mask
             ))
-        # (service, binary key[, value]) family: two bucket keyings per
-        # host — with the value (valued queries) and with a -1 sentinel
-        # (key-only queries) — under the span's host-set pair.
-        bh1 = hmin[b.bann_span_idx]
-        bh2 = hmax[b.bann_span_idx]
-        bk_idx_ok = mask_b & (b.bann_key_id >= 0)
-        ts_b = b.ts_last[b.bann_span_idx]
-        no_val = jnp.full(PB, -1, jnp.int32)
-        for h, val, extra in (
-            (bh1, b.bann_value_id, None), (bh2, b.bann_value_id, bh2 != bh1),
-            (bh1, no_val, None), (bh2, no_val, bh2 != bh1),
-        ):
-            ok = bk_idx_ok & (h >= 0) & (h < S)
-            if extra is not None:
-                ok &= extra
-            mix = _mixb([h, b.bann_key_id, val])
             segments.append(seg(
-                StoreConfig.CAND_BANN, _bucket_of(mix, c.bann_buckets),
-                jnp.where(ok, span_gid_of_bann, -1), _verify_of(mix),
-                ts_b, ok,
+                NC + StoreConfig.TR_ANN, tb[b.ann_span_idx], a_gids,
+                tmix[b.ann_span_idx], ts_a, mask_a,
             ))
-        # keyed_from depends on the un-keyed SVC family being the SINGLE
-        # leading segment; a reorder would silently poison the key table
-        # (service verify words all collide in key48 space) — assert the
-        # invariant structurally, at trace time.
-        fams = [f for f, _ in segments]
-        assert (fams[0] == StoreConfig.CAND_SVC
-                and StoreConfig.CAND_SVC not in fams[1:]), fams
-        n_cand_rows = sum(p[0].shape[0] for _, p in segments)
-        # Trace-membership families trail the candidate segments in the
-        # SAME unified concatenation: row gids bucketed by trace-id
-        # hash, one sub-family per ring (whole-trace fetch + durations).
-        # Verify carries the trace mix, ts the row's last_ts — the
-        # arena rows are uniform (gid, verify, ts) triples.
-        tb = _bucket_of(_mixb([b.trace_id]), c.trace_buckets)
-        tmix = _verify_of(_mixb([b.trace_id]))
-        NC = StoreConfig.N_CAND_FAMILIES
-        segments.append(seg(
-            NC + StoreConfig.TR_SPAN, tb, gids, tmix, b.ts_last, mask
-        ))
-        segments.append(seg(
-            NC + StoreConfig.TR_ANN, tb[b.ann_span_idx], a_gids,
-            tmix[b.ann_span_idx], ts_a, mask_a,
-        ))
-        segments.append(seg(
-            NC + StoreConfig.TR_BANN, tb[b.bann_span_idx], bb_gids,
-            tmix[b.bann_span_idx], b.ts_last[b.bann_span_idx], mask_b,
-        ))
-        cat = [jnp.concatenate(parts)
-               for parts in zip(*(p for _, p in segments))]
+            segments.append(seg(
+                NC + StoreConfig.TR_BANN, tb[b.bann_span_idx], bb_gids,
+                tmix[b.bann_span_idx], b.ts_last[b.bann_span_idx], mask_b,
+            ))
+            cat = [jnp.concatenate(parts)
+                   for parts in zip(*(p for _, p in segments))]
         # Static per-shape path decisions (r12), recorded at trace time
         # so counters()/bench can report which kernels a config's
         # compiled steps actually used. Both rank paths are bitwise-
@@ -2535,99 +2541,101 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
         )
         _note_path(c, "rank", rank_sel[0])
         _note_path(c, "scatter", scatter_mode)
-        (upd["cand_idx"], upd["cand_pos"], upd["cand_wm"],
-         upd["key_tab"], upd["key_wm"], upd["ann_poison"],
-         n_key_drops) = _index_write(
-            state.cand_idx, state.cand_pos, state.cand_wm,
-            state.key_tab, state.key_wm, state.ann_poison, *cat,
-            keyed_from=segments[0][1][0].shape[0],
-            n_cand_rows=n_cand_rows,
-            n_cand_buckets=c.cand_layout[1],
-            poison_bucket=a_host, poison_gid=span_gid_of_ann,
-            poison_ok=mid,
-            wm_shift=wm_shift,
-            rank_sel=rank_sel, scatter_mode=scatter_mode,
-        )
+        with jax.named_scope("ingest.index_write"):
+            (upd["cand_idx"], upd["cand_pos"], upd["cand_wm"],
+             upd["key_tab"], upd["key_wm"], upd["ann_poison"],
+             n_key_drops) = _index_write(
+                state.cand_idx, state.cand_pos, state.cand_wm,
+                state.key_tab, state.key_wm, state.ann_poison, *cat,
+                keyed_from=segments[0][1][0].shape[0],
+                n_cand_rows=n_cand_rows,
+                n_cand_buckets=c.cand_layout[1],
+                poison_bucket=a_host, poison_gid=span_gid_of_ann,
+                poison_ok=mid,
+                wm_shift=wm_shift,
+                rank_sel=rank_sel, scatter_mode=scatter_mode,
+            )
 
     # -- per-service latency histogram ---------------------------------
-    hist = svc_histogram(state)
-    svc_ok = mask & (b.service_id >= 0) & (b.service_id < S) & (b.duration >= 0)
-    bidx = Q.bucket_index(hist, b.duration.astype(jnp.float32))
-    g = jnp.clip(b.service_id, 0, S - 1)
-    ones_p = jnp.ones(P, jnp.int32)
-    ones_a = jnp.ones(PA, jnp.int32)
-    upd["svc_hist"] = _scatter_add(
-        state.svc_hist,
-        jnp.where(svc_ok, g * c.quantile_buckets + bidx, -1),
-        ones_p, c.use_pallas,
-    )
+    with jax.named_scope("ingest.sketch_update"):
+        hist = svc_histogram(state)
+        svc_ok = mask & (b.service_id >= 0) & (b.service_id < S) & (b.duration >= 0)
+        bidx = Q.bucket_index(hist, b.duration.astype(jnp.float32))
+        g = jnp.clip(b.service_id, 0, S - 1)
+        ones_p = jnp.ones(P, jnp.int32)
+        ones_a = jnp.ones(PA, jnp.int32)
+        upd["svc_hist"] = _scatter_add(
+            state.svc_hist,
+            jnp.where(svc_ok, g * c.quantile_buckets + bidx, -1),
+            ones_p, c.use_pallas,
+        )
 
-    # -- counters / presence matrices ----------------------------------
-    svc_cnt_ok = mask & (b.service_id >= 0) & (b.service_id < S)
-    upd["svc_span_counts"] = _scatter_add(
-        state.svc_span_counts, jnp.where(svc_cnt_ok, b.service_id, -1),
-        ones_p, c.use_pallas,
-    )
-    a_svc = b.ann_service_id
-    a_svc_ok = mask_a & (a_svc >= 0) & (a_svc < S)
-    upd["ann_svc_counts"] = _scatter_add(
-        state.ann_svc_counts, jnp.where(a_svc_ok, a_svc, -1),
-        ones_a, c.use_pallas,
-    )
+        # -- counters / presence matrices ----------------------------------
+        svc_cnt_ok = mask & (b.service_id >= 0) & (b.service_id < S)
+        upd["svc_span_counts"] = _scatter_add(
+            state.svc_span_counts, jnp.where(svc_cnt_ok, b.service_id, -1),
+            ones_p, c.use_pallas,
+        )
+        a_svc = b.ann_service_id
+        a_svc_ok = mask_a & (a_svc >= 0) & (a_svc < S)
+        upd["ann_svc_counts"] = _scatter_add(
+            state.ann_svc_counts, jnp.where(a_svc_ok, a_svc, -1),
+            ones_a, c.use_pallas,
+        )
 
-    # span-name presence keyed by annotation-host service (the semantics
-    # of getSpanNames: names of indexed spans for a service).
-    ann_name = b.name_id[b.ann_span_idx]  # batch-local gather
-    ann_name_lc = b.name_lc_id[b.ann_span_idx]
-    ann_indexable = b.indexable[b.ann_span_idx]
-    np_ok = (
-        a_svc_ok & ann_indexable
-        & (ann_name_lc >= 0) & (ann_name >= 0) & (ann_name < c.max_span_names)
-    )
-    upd["name_presence"] = _scatter_add(
-        state.name_presence,
-        jnp.where(np_ok, a_svc * c.max_span_names + ann_name, -1),
-        ones_a, c.use_pallas,
-    )
+        # span-name presence keyed by annotation-host service (the semantics
+        # of getSpanNames: names of indexed spans for a service).
+        ann_name = b.name_id[b.ann_span_idx]  # batch-local gather
+        ann_name_lc = b.name_lc_id[b.ann_span_idx]
+        ann_indexable = b.indexable[b.ann_span_idx]
+        np_ok = (
+            a_svc_ok & ann_indexable
+            & (ann_name_lc >= 0) & (ann_name >= 0) & (ann_name < c.max_span_names)
+        )
+        upd["name_presence"] = _scatter_add(
+            state.name_presence,
+            jnp.where(np_ok, a_svc * c.max_span_names + ann_name, -1),
+            ones_a, c.use_pallas,
+        )
 
-    # top annotations per service (user annotations only).
-    av_ok = (
-        a_svc_ok
-        & (b.ann_value_id >= FIRST_USER_ANNOTATION_ID)
-        & (b.ann_value_id < c.max_annotation_values)
-    )
-    upd["ann_value_counts"] = _scatter_add(
-        state.ann_value_counts,
-        jnp.where(av_ok, a_svc * c.max_annotation_values + b.ann_value_id, -1),
-        ones_a, c.use_pallas,
-    )
+        # top annotations per service (user annotations only).
+        av_ok = (
+            a_svc_ok
+            & (b.ann_value_id >= FIRST_USER_ANNOTATION_ID)
+            & (b.ann_value_id < c.max_annotation_values)
+        )
+        upd["ann_value_counts"] = _scatter_add(
+            state.ann_value_counts,
+            jnp.where(av_ok, a_svc * c.max_annotation_values + b.ann_value_id, -1),
+            ones_a, c.use_pallas,
+        )
 
-    bk_svc = b.bann_service_id
-    bk_ok = (
-        mask_b & (bk_svc >= 0) & (bk_svc < S)
-        & (b.bann_key_id >= 0) & (b.bann_key_id < c.max_binary_keys)
-    )
-    upd["bann_key_counts"] = _scatter_add(
-        state.bann_key_counts,
-        jnp.where(bk_ok, bk_svc * c.max_binary_keys + b.bann_key_id, -1),
-        jnp.ones(PB, jnp.int32), c.use_pallas,
-    )
+        bk_svc = b.bann_service_id
+        bk_ok = (
+            mask_b & (bk_svc >= 0) & (bk_svc < S)
+            & (b.bann_key_id >= 0) & (b.bann_key_id < c.max_binary_keys)
+        )
+        upd["bann_key_counts"] = _scatter_add(
+            state.bann_key_counts,
+            jnp.where(bk_ok, bk_svc * c.max_binary_keys + b.bann_key_id, -1),
+            jnp.ones(PB, jnp.int32), c.use_pallas,
+        )
 
-    # -- probabilistic state -------------------------------------------
-    t_hi, t_lo = dev_split64(b.trace_id)
-    upd["hll_traces"] = hll.update(
-        hll.HyperLogLog(state.hll_traces), t_hi, t_lo, valid=mask
-    ).registers
-    cms_sketch = cms.CountMin(state.cms_trace_spans)
-    cms_idx = cms._indices(cms_sketch, t_hi, t_lo)  # [depth, P]
-    cms_flat = cms_idx + (
-        jnp.arange(c.cms_depth, dtype=jnp.int32) * c.cms_width
-    )[:, None]
-    cms_flat = jnp.where(mask[None, :], cms_flat, -1).reshape(-1)
-    upd["cms_trace_spans"] = _scatter_add(
-        state.cms_trace_spans, cms_flat,
-        jnp.ones(c.cms_depth * P, jnp.int32), c.use_pallas,
-    )
+        # -- probabilistic state -------------------------------------------
+        t_hi, t_lo = dev_split64(b.trace_id)
+        upd["hll_traces"] = hll.update(
+            hll.HyperLogLog(state.hll_traces), t_hi, t_lo, valid=mask
+        ).registers
+        cms_sketch = cms.CountMin(state.cms_trace_spans)
+        cms_idx = cms._indices(cms_sketch, t_hi, t_lo)  # [depth, P]
+        cms_flat = cms_idx + (
+            jnp.arange(c.cms_depth, dtype=jnp.int32) * c.cms_width
+        )[:, None]
+        cms_flat = jnp.where(mask[None, :], cms_flat, -1).reshape(-1)
+        upd["cms_trace_spans"] = _scatter_add(
+            state.cms_trace_spans, cms_flat,
+            jnp.ones(c.cms_depth * P, jnp.int32), c.use_pallas,
+        )
 
     # -- windowed Moments-sketch arena ---------------------------------
     # (service × ring-indexed time bucket) integer cells; the host
@@ -2637,68 +2645,70 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
     # scatters (+1 of them the serialized i64 class, 4P rows), +2
     # gathers, 0 sorts — the store/census.py r13 bump.
     if c.window_enabled:
-        Wn = c.win_slots
-        w_ok = mask & (b.service_id >= 0) & (b.service_id < S) \
-            & (b.ts_first >= 0)
-        a_bkt = jnp.where(w_ok, b.ts_first, 0) // jnp.int64(c.window_us)
-        slot = (a_bkt % Wn).astype(jnp.int32)
-        slot = jnp.where(w_ok, slot, 0)
-        # Epoch war: each touched slot advances to the max absolute
-        # bucket offered this step; rows older than the winner (stale
-        # lates, or the losers of an in-batch ring wrap) are dropped.
-        new_epoch = _war_max64(state.win_epoch, slot, a_bkt, w_ok)
-        upd["win_epoch"] = new_epoch
-        stale = (new_epoch != state.win_epoch)[None, :, None]
-        counts_w = jnp.where(stale, jnp.int32(0), state.win_counts)
-        sums_w = jnp.where(stale, jnp.int64(0), state.win_sums)
-        mm_w = jnp.where(stale, I32_MIN, state.win_mm)
-        live = w_ok & (a_bkt == new_epoch[slot])
-        cid = g * Wn + slot  # g = clip(service_id) — valid where live
-        d_ok = live & (b.duration >= 0)
-        x = (bidx >> c.win_x_shift).astype(jnp.int32)
-        base3 = cid * 3
-        idx_c = jnp.concatenate([
-            jnp.where(live, base3, -1),
-            jnp.where(live & b.error_flag, base3 + 1, -1),
-            jnp.where(d_ok, base3 + 2, -1),
-        ])
-        upd["win_counts"] = _scatter_add(
-            counts_w, idx_c, jnp.ones(3 * P, jnp.int32), c.use_pallas
-        )
-        flat_s = sums_w.reshape(-1)
-        xi = x.astype(jnp.int64)
-        base4 = cid * 4
-        idx_s = jnp.concatenate([base4, base4 + 1, base4 + 2,
-                                 base4 + 3])
-        safe_s = jnp.where(jnp.tile(d_ok, 4), idx_s, flat_s.shape[0])
-        vals_s = jnp.concatenate([xi, xi * xi, xi * xi * xi,
-                                  xi * xi * xi * xi])
-        upd["win_sums"] = flat_s.at[safe_s].add(
-            vals_s, mode="drop").reshape(sums_w.shape)
-        flat_m = mm_w.reshape(-1)
-        base2 = cid * 2
-        idx_m = jnp.concatenate([base2, base2 + 1])
-        safe_m = jnp.where(jnp.tile(d_ok, 2), idx_m, flat_m.shape[0])
-        vals_m = jnp.concatenate([-x, x])
-        upd["win_mm"] = flat_m.at[safe_m].max(
-            vals_m, mode="drop").reshape(mm_w.shape)
+        with jax.named_scope("ingest.window_arena"):
+            Wn = c.win_slots
+            w_ok = mask & (b.service_id >= 0) & (b.service_id < S) \
+                & (b.ts_first >= 0)
+            a_bkt = jnp.where(w_ok, b.ts_first, 0) // jnp.int64(c.window_us)
+            slot = (a_bkt % Wn).astype(jnp.int32)
+            slot = jnp.where(w_ok, slot, 0)
+            # Epoch war: each touched slot advances to the max absolute
+            # bucket offered this step; rows older than the winner (stale
+            # lates, or the losers of an in-batch ring wrap) are dropped.
+            new_epoch = _war_max64(state.win_epoch, slot, a_bkt, w_ok)
+            upd["win_epoch"] = new_epoch
+            stale = (new_epoch != state.win_epoch)[None, :, None]
+            counts_w = jnp.where(stale, jnp.int32(0), state.win_counts)
+            sums_w = jnp.where(stale, jnp.int64(0), state.win_sums)
+            mm_w = jnp.where(stale, I32_MIN, state.win_mm)
+            live = w_ok & (a_bkt == new_epoch[slot])
+            cid = g * Wn + slot  # g = clip(service_id) — valid where live
+            d_ok = live & (b.duration >= 0)
+            x = (bidx >> c.win_x_shift).astype(jnp.int32)
+            base3 = cid * 3
+            idx_c = jnp.concatenate([
+                jnp.where(live, base3, -1),
+                jnp.where(live & b.error_flag, base3 + 1, -1),
+                jnp.where(d_ok, base3 + 2, -1),
+            ])
+            upd["win_counts"] = _scatter_add(
+                counts_w, idx_c, jnp.ones(3 * P, jnp.int32), c.use_pallas
+            )
+            flat_s = sums_w.reshape(-1)
+            xi = x.astype(jnp.int64)
+            base4 = cid * 4
+            idx_s = jnp.concatenate([base4, base4 + 1, base4 + 2,
+                                     base4 + 3])
+            safe_s = jnp.where(jnp.tile(d_ok, 4), idx_s, flat_s.shape[0])
+            vals_s = jnp.concatenate([xi, xi * xi, xi * xi * xi,
+                                      xi * xi * xi * xi])
+            upd["win_sums"] = flat_s.at[safe_s].add(
+                vals_s, mode="drop").reshape(sums_w.shape)
+            flat_m = mm_w.reshape(-1)
+            base2 = cid * 2
+            idx_m = jnp.concatenate([base2, base2 + 1])
+            safe_m = jnp.where(jnp.tile(d_ok, 2), idx_m, flat_m.shape[0])
+            vals_m = jnp.concatenate([-x, x])
+            upd["win_mm"] = flat_m.at[safe_m].max(
+                vals_m, mode="drop").reshape(mm_w.shape)
 
     # -- time range + counters -----------------------------------------
-    firsts = jnp.where(mask & (b.ts_first >= 0), b.ts_first, I64_MAX)
-    lasts = jnp.where(mask & (b.ts_last >= 0), b.ts_last, I64_MIN)
-    upd["ts_min"] = jnp.minimum(state.ts_min, firsts.min())
-    upd["ts_max"] = jnp.maximum(state.ts_max, lasts.max())
-    # Spread-then-update: counters the step doesn't touch (sweeps)
-    # must carry through, not silently reset to absent.
-    upd["counters"] = {
-        **state.counters,
-        "spans_seen": state.counters["spans_seen"] + b.n_spans,
-        "anns_seen": state.counters["anns_seen"] + b.n_anns,
-        "banns_seen": state.counters["banns_seen"] + b.n_banns,
-        "batches": state.counters["batches"] + 1,
-        "key_claim_drops": state.counters["key_claim_drops"]
-        + n_key_drops,
-    }
+    with jax.named_scope("ingest.counters"):
+        firsts = jnp.where(mask & (b.ts_first >= 0), b.ts_first, I64_MAX)
+        lasts = jnp.where(mask & (b.ts_last >= 0), b.ts_last, I64_MIN)
+        upd["ts_min"] = jnp.minimum(state.ts_min, firsts.min())
+        upd["ts_max"] = jnp.maximum(state.ts_max, lasts.max())
+        # Spread-then-update: counters the step doesn't touch (sweeps)
+        # must carry through, not silently reset to absent.
+        upd["counters"] = {
+            **state.counters,
+            "spans_seen": state.counters["spans_seen"] + b.n_spans,
+            "anns_seen": state.counters["anns_seen"] + b.n_anns,
+            "banns_seen": state.counters["banns_seen"] + b.n_banns,
+            "batches": state.counters["batches"] + 1,
+            "key_claim_drops": state.counters["key_claim_drops"]
+            + n_key_drops,
+        }
 
     return state.replace(**upd)
 

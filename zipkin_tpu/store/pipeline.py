@@ -62,6 +62,7 @@ from typing import NamedTuple, Optional
 
 import jax
 
+from zipkin_tpu.obs.stages import stage
 from zipkin_tpu.store import device as dev
 
 _STOP = object()
@@ -261,10 +262,13 @@ class IngestPipeline(_StageBase):
         # Only a put against an already-full queue is backpressure;
         # elapsed time on a non-full put is just lock contention and
         # must not read as a stall on a loaded machine.
-        full = self._prefetch.full()
-        t0 = time.perf_counter()
-        self._prefetch.put(unit)
-        stall = (time.perf_counter() - t0) if full else 0.0
+        stall = 0.0
+        if self._prefetch.full():
+            with stage("pipeline.feed_stall", unit=unit.wal_seq) as wait:
+                self._prefetch.put(unit)
+            stall = wait.seconds
+        else:
+            self._prefetch.put(unit)
         if stall > 1e-4:
             self.c_stall.inc(stall)
         self.c_units.inc()
@@ -279,9 +283,9 @@ class IngestPipeline(_StageBase):
                 self._staged.put(_STOP)
                 return
             try:
-                t0 = time.perf_counter()
-                item = item._replace(db=self._stage(item.db))
-                self.h_stage.observe(time.perf_counter() - t0)
+                with stage("pipeline.h2d", self.h_stage,
+                           unit=item.wal_seq):
+                    item = item._replace(db=self._stage(item.db))
             except BaseException as e:  # noqa: BLE001 — parked, re-raised
                 self._park_error(e)
                 self._mark_done()  # drop this unit; keep flowing
@@ -297,9 +301,9 @@ class IngestPipeline(_StageBase):
             if item is _STOP:
                 return
             try:
-                t0 = time.perf_counter()
-                store._commit_unit(item)
-                self.h_commit.observe(time.perf_counter() - t0)
+                with stage("pipeline.commit", self.h_commit,
+                           unit=item.wal_seq):
+                    store._commit_unit(item)
             except BaseException as e:  # noqa: BLE001 — parked, re-raised
                 # This unit's spans are dropped (mirrors untouched, so
                 # ring invariants hold and a failed capture pull is

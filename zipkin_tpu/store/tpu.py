@@ -40,6 +40,7 @@ from zipkin_tpu.columnar.schema import SpanBatch
 from zipkin_tpu.models.constants import CORE_ANNOTATIONS
 from zipkin_tpu.models.dependencies import Dependencies, DependencyLink, Moments
 from zipkin_tpu.models.span import Span
+from zipkin_tpu.obs.stages import stage
 from zipkin_tpu.ops import hll
 from zipkin_tpu.ops import quantile as Q
 from zipkin_tpu.store import device as dev
@@ -229,6 +230,15 @@ def gate_multi_probes(probes, limits, per_probe):
                 limits[qi], win_total, cands, complete, wm
             )
     return out
+
+
+def device_memory(device) -> Dict[str, float]:
+    """{kind: bytes} of one device's ``memory_stats()``."""
+    stats = device.memory_stats() or {}
+    return {kind: float(stats[key])
+            for kind, key in (("in_use", "bytes_in_use"),
+                              ("peak", "peak_bytes_in_use"))
+            if key in stats}
 
 
 def _next_pow2(n: int) -> int:
@@ -444,15 +454,17 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         reg = registry or obs.default_registry()
         self._registry = reg
         # Launch dispatch is ASYNC under JAX, so a per-step wall clock
-        # only measures host dispatch. The true-latency sketch blocks
-        # on a tiny scalar every INGEST_SYNC_EVERY-th launch (sampled
-        # sync: negligible throughput tax, honest p50/p99); the
-        # dispatch sketch keeps the old always-on host-side number.
+        # only measures host dispatch. Every INGEST_SYNC_EVERY-th
+        # launch blocks on a tiny scalar, which waits for every step
+        # queued before it (see _observe_ingest); the dispatch sketch
+        # keeps the always-on host-side number.
         self._h_ingest = reg.register(obs.LatencySketch(
             "zipkin_store_ingest_step_seconds",
-            "TRUE fused-step latency, dispatch through device "
-            "completion (sampled: observed every "
-            f"{self.INGEST_SYNC_EVERY}th launch via a scalar sync)"))
+            "Dispatch of a launch through the drain of every step "
+            "queued on the device before it (sampled: every "
+            f"{self.INGEST_SYNC_EVERY}th launch blocks on a scalar; "
+            "that block is the write path's only back-pressure on the "
+            "device queue). Not one step's latency"))
         self._h_dispatch = reg.register(obs.LatencySketch(
             "zipkin_store_ingest_dispatch_seconds",
             "Host dispatch time per fused step/chain (async: excludes "
@@ -466,6 +478,15 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
             "Compiled variants across the ingest/staging/capture jits "
             "(dev.compile_count; steady-state pipelined ingest adds 0)",
             fn=lambda: float(dev.compile_count())))
+        # The allocator's own numbers for the device the state lives
+        # on, read at scrape and never on the write path.
+        device = next(iter(self.state.write_pos.devices()))
+        reg.register(obs.CallbackFamily(
+            "zipkin_device_memory_bytes",
+            "Memory of the store's device as its allocator reports it "
+            "(memory_stats(); no samples where the backend reports "
+            "none, as the CPU's does)",
+            "kind", lambda: device_memory(device)))
         # Windowed Moments-sketch arena families (zipkin_window_*,
         # docs/OBSERVABILITY.md): fold counters are process-monotonic
         # mirror totals (never regress on ring self-clears or resync);
@@ -547,7 +568,8 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
     def apply(self, spans: Sequence[Span]) -> None:
         if not spans:
             return
-        with self._lock:
+        with stage("store.lock_wait") as wait, self._lock:
+            wait.done()
             for span in spans:
                 self.ttls.setdefault(to_signed64(span.trace_id), 1.0)
             if self.pins:
@@ -572,19 +594,23 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
                 return
             parts = []
             for part in self._chunk_by_trace(spans):
-                batch = self.codec.encode(part)
-                indexable = np.fromiter(
-                    (should_index(s) for s in part), bool, len(part)
-                )
-                name_lc = self._name_lc_ids(batch)
-                parts.extend(self._chunk_columnar(
-                    batch, name_lc, indexable
-                ))
+                with stage("store.encode"):
+                    parts.extend(self._encode_part(part))
                 if self.CHAIN_SIZES and len(parts) >= self.CHAIN_SIZES[0]:
                     self._write_parts(parts)
                     parts = []
             if parts:
                 self._write_parts(parts)
+
+    def _encode_part(self, part: Sequence[Span]) -> list:
+        """One trace chunk → columnar parts with index bits (the body
+        of stage 1 on the span-object path, serial or pipelined)."""
+        batch = self.codec.encode(part)
+        indexable = np.fromiter(
+            (should_index(s) for s in part), bool, len(part)
+        )
+        return list(self._chunk_columnar(
+            batch, self._name_lc_ids(batch), indexable))
 
     def _apply_pipelined(self, spans: Sequence[Span]) -> None:
         """Stage 1 of the ingest pipeline (caller thread, under the
@@ -596,23 +622,16 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         (tests/test_pipeline.py)."""
         pipe = self._pipeline
         self.ensure_writable()  # fail fast; the commit thread re-checks
-        t0 = _time.perf_counter()
-        stalled = 0.0
-        parts = []
-        for part in self._chunk_by_trace(spans):
-            batch = self.codec.encode(part)
-            indexable = np.fromiter(
-                (should_index(s) for s in part), bool, len(part)
-            )
-            name_lc = self._name_lc_ids(batch)
-            parts.extend(self._chunk_columnar(batch, name_lc, indexable))
-            if self.CHAIN_SIZES and len(parts) >= self.CHAIN_SIZES[0]:
-                stalled += self._feed_units(pipe, parts)
-                parts = []
-        if parts:
-            stalled += self._feed_units(pipe, parts)
-        pipe.h_encode.observe(
-            max(_time.perf_counter() - t0 - stalled, 0.0))
+        with stage("store.encode") as encode:
+            parts = []
+            for part in self._chunk_by_trace(spans):
+                parts.extend(self._encode_part(part))
+                if self.CHAIN_SIZES and len(parts) >= self.CHAIN_SIZES[0]:
+                    encode.less += self._feed_units(pipe, parts)
+                    parts = []
+            if parts:
+                encode.less += self._feed_units(pipe, parts)
+        pipe.h_encode.observe(encode.seconds)
 
     def _feed_units(self, pipe: IngestPipeline, parts) -> float:
         """Pad + enqueue one flushed part list as launch units; returns
@@ -700,44 +719,47 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         propagates for callers to chunk."""
         from zipkin_tpu import native
 
-        with self._lock:
-            t0 = _time.perf_counter()  # stage-1 clock (pipelined mode)
-            batch, name_lc, dropped, kept_debug = (
-                native.parse_spans_columnar_sampled(
-                    payload, self.dicts, sample_threshold,
-                    max_spans=self.MAX_CHUNK,
-                )
-            )
-            if batch.n_spans == 0:
-                return 0, dropped, 0
-            for tid in np.unique(batch.trace_id):
-                self.ttls.setdefault(int(tid), 1.0)
-            if self.pins:
-                # Fast-path arrivals for pinned traces must reach the
-                # eviction-exempt bank too: decode just those rows.
-                keep = np.isin(
-                    batch.trace_id,
-                    np.fromiter(self.pins.tids(), np.int64,
-                                len(self.pins.tids())),
-                )
-                if keep.any():
-                    pinned_part = self._select_batch(batch, keep)
-                    self._bump_read_epoch()
-                    self.pins.note_write(
-                        to_signed64, self.codec.decode(pinned_part)
+        with stage("store.lock_wait") as wait, self._lock:
+            wait.done()
+            # Stage 1: the whole body (parse + index bits + chunking,
+            # and on the pipelined path padding and the journal too),
+            # less the feed stall, which is a span of its own. On the
+            # serial path the pad is timed in _commit_group.
+            with stage("store.encode") as encode:
+                batch, name_lc, dropped, kept_debug = (
+                    native.parse_spans_columnar_sampled(
+                        payload, self.dicts, sample_threshold,
+                        max_spans=self.MAX_CHUNK,
                     )
-            self._prune_ttls()
-            indexable = native.indexable_from_batch(batch, self.dicts)
-            parts = list(self._chunk_columnar(batch, name_lc, indexable))
-            pipe = self._pipeline
+                )
+                if batch.n_spans == 0:
+                    return 0, dropped, 0
+                for tid in np.unique(batch.trace_id):
+                    self.ttls.setdefault(int(tid), 1.0)
+                if self.pins:
+                    # Fast-path arrivals for pinned traces must reach
+                    # the eviction-exempt bank too: decode just those
+                    # rows.
+                    keep = np.isin(
+                        batch.trace_id,
+                        np.fromiter(self.pins.tids(), np.int64,
+                                    len(self.pins.tids())),
+                    )
+                    if keep.any():
+                        pinned_part = self._select_batch(batch, keep)
+                        self._bump_read_epoch()
+                        self.pins.note_write(
+                            to_signed64, self.codec.decode(pinned_part)
+                        )
+                self._prune_ttls()
+                indexable = native.indexable_from_batch(batch, self.dicts)
+                parts = list(self._chunk_columnar(batch, name_lc, indexable))
+                pipe = self._pipeline
+                if pipe is not None:
+                    self.ensure_writable()
+                    encode.less = self._feed_units(pipe, parts)
             if pipe is not None:
-                # t0 opened before the native parse: the encode sketch
-                # must cover the whole stage-1 body (parse + index
-                # bits + chunking + padding), not just the pad tail.
-                self.ensure_writable()
-                stalled = self._feed_units(pipe, parts)
-                pipe.h_encode.observe(
-                    max(_time.perf_counter() - t0 - stalled, 0.0))
+                pipe.h_encode.observe(encode.seconds)
             else:
                 self._write_parts(parts)
             return batch.n_spans, dropped, kept_debug
@@ -889,8 +911,11 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
             kill_point("before-append")
             seq = self._journal_group(group)
         # Journal-before-pad: see _feed_units — the paged planner's
-        # claim plan is keyed to ``seq`` inside _pad_unit.
-        unit = self._pad_unit(group, wal_seq=seq)
+        # claim plan is keyed to ``seq`` inside _pad_unit. The pad is
+        # stage-1 work, so it is the serial path's second observation
+        # of store.encode (the journal lies between the two).
+        with stage("store.encode"):
+            unit = self._pad_unit(group, wal_seq=seq)
         if seq is not None:
             unit = unit._replace(wal_seq=seq)
             kill_point("after-append")
@@ -1024,48 +1049,54 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         self._lock; the pipeline's commit thread runs it alone (it is
         the only device writer while a pipeline is active)."""
         self.ensure_writable()
-        t0 = _time.perf_counter()
-        if self._planner is not None:
-            # Paged capture is at page granularity: the unit's plan
-            # names exactly the pages it reclaims, and their rows are
-            # pulled BEFORE the launch whose invalidation scatter
-            # erases them (the per-page captured-before-overwrite
-            # invariant). The ring-window trigger stays dormant — its
-            # [cap_upto, wp) arithmetic is FIFO-gid arithmetic.
-            if unit.reclaims:
-                self._capture_pages(unit.reclaims)
-        else:
-            self._maybe_capture(unit.n_spans, unit.n_anns, unit.n_banns)
-        self._maybe_archive(unit.n_spans)
-        step = dev.ingest_steps if unit.chained else dev.ingest_step
-        # The host mirrors, the WAL applied frontier, and the cadence
-        # sweep all advance INSIDE the write-lock hold: a checkpoint's
-        # state gather (under the read lock) then always pairs the
-        # device cut with exactly-matching clocks — the invariant
-        # deterministic replay (wal/recovery) rebuilds launches from.
-        with self._rw.write():
-            self.state = step(self.state, unit.db)
-            # Mirror BEFORE the frontier bump: a sketch-tier read at
-            # frontier F must already include commit F's delta.
-            if unit.sketch is not None:
-                self.sketch_mirror.apply(unit.sketch)
-            self._wp += unit.n_spans
-            self._awp += unit.n_anns
-            self._bwp += unit.n_banns
-            self._step_seq += 1
-            if unit.wal_seq is not None:
-                self._wal_applied = unit.wal_seq
-            # Dispatch accounting stops HERE: the cadence sweep below
-            # is its own launch, and folding it into the per-batch
-            # dispatch sketch would plant a 1-in-64 outlier that reads
-            # as an ingest regression.
-            dispatch_s = _time.perf_counter() - t0
-            self._batches_since_sweep += unit.n_parts
-            if self._batches_since_sweep >= self.SWEEP_EVERY:
-                self.state = dev.dep_sweep(self.state)
+        unit_id = unit.wal_seq
+        with stage("store.commit", unit=unit_id) as commit, \
+                stage("store.dispatch", self._h_dispatch,
+                      unit=unit_id) as dispatch:
+            if self._planner is not None:
+                # Paged capture is at page granularity: the unit's plan
+                # names exactly the pages it reclaims, and their rows
+                # are pulled BEFORE the launch whose invalidation
+                # scatter erases them (the per-page captured-before-
+                # overwrite invariant). The ring-window trigger stays
+                # dormant — its [cap_upto, wp) arithmetic is FIFO-gid
+                # arithmetic.
+                if unit.reclaims:
+                    self._capture_pages(unit.reclaims)
+            else:
+                self._maybe_capture(unit.n_spans, unit.n_anns,
+                                    unit.n_banns)
+            self._maybe_archive(unit.n_spans)
+            step = dev.ingest_steps if unit.chained else dev.ingest_step
+            # The host mirrors, the WAL applied frontier, and the
+            # cadence sweep all advance INSIDE the write-lock hold: a
+            # checkpoint's state gather (under the read lock) then
+            # always pairs the device cut with exactly-matching clocks
+            # — the invariant deterministic replay (wal/recovery)
+            # rebuilds launches from.
+            with self._rw.write():
+                self.state = step(self.state, unit.db)
+                # Mirror BEFORE the frontier bump: a sketch-tier read
+                # at frontier F must already include commit F's delta.
+                if unit.sketch is not None:
+                    self.sketch_mirror.apply(unit.sketch)
+                self._wp += unit.n_spans
+                self._awp += unit.n_anns
+                self._bwp += unit.n_banns
                 self._step_seq += 1
-                self._batches_since_sweep = 0
-        self._observe_ingest(t0, dispatch_s)
+                if unit.wal_seq is not None:
+                    self._wal_applied = unit.wal_seq
+                # Dispatch accounting stops HERE: the cadence sweep
+                # below is its own launch, and folding it into the
+                # per-batch dispatch sketch would plant a 1-in-64
+                # outlier that reads as an ingest regression.
+                dispatch.done()
+                self._batches_since_sweep += unit.n_parts
+                if self._batches_since_sweep >= self.SWEEP_EVERY:
+                    self.state = dev.dep_sweep(self.state)
+                    self._step_seq += 1
+                    self._batches_since_sweep = 0
+            self._observe_ingest(commit)
 
     def _write_device_many(self, group) -> None:
         """One chained launch over ≥2 chunks: pad every chunk to the
@@ -1081,18 +1112,17 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         already fits the ring capacities."""
         self._commit_group([(batch, name_lc, indexable)])
 
-    def _observe_ingest(self, t0: float,
-                        dispatch_s: Optional[float] = None) -> None:
-        """Launch accounting: always-on dispatch time (``dispatch_s``
-        when the caller clocked it before extra launches joined the
-        window), plus the TRUE step latency every INGEST_SYNC_EVERY-th
-        launch (block on the write_pos scalar — one tiny D2H, no ring
-        traffic). The old single-sketch scheme timed only the async
-        dispatch, so /metrics showed host dispatch cost as if it were
-        device compute (the r9 underreporting fix)."""
-        self._h_dispatch.observe(
-            dispatch_s if dispatch_s is not None
-            else _time.perf_counter() - t0)
+    def _observe_ingest(self, commit: stage) -> None:
+        """Launch accounting past the always-on dispatch sketch (the
+        ``store.dispatch`` span of _commit_unit): every
+        INGEST_SYNC_EVERY-th launch blocks on the write_pos scalar
+        (one tiny D2H, no ring traffic) and observes the seconds since
+        the commit began. Dispatch is ASYNC and the device runs its
+        queue in order, so that block returns when EVERY step queued
+        so far has run: the observation is dispatch through the drain
+        of the whole device queue, not one step's latency, and the
+        block is the write path's only back-pressure on that queue.
+        ``store.device_sync_wait`` times the block alone."""
         self._c_launches.inc()
         self._launch_seq += 1
         if self._launch_seq % self.INGEST_SYNC_EVERY == 1 \
@@ -1100,9 +1130,9 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
             # Under the read lock: a reader-triggered pending sweep
             # (get_dependencies) is a DONATING step — blocking on a
             # state the sweep just consumed would hit deleted buffers.
-            with self._rw.read():
+            with self._rw.read(), stage("store.device_sync_wait"):
                 jax.block_until_ready(self.state.write_pos)
-            self._h_ingest.observe(_time.perf_counter() - t0)
+            self._h_ingest.observe(commit.elapsed())
 
     # Write-path sweep cadence (batches). Each sweep is one small launch
     # over the pending ring; 64 bounds a cross-batch child's link
@@ -1431,21 +1461,18 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
 
         With a lineage tracker attached the record meta gains the
         commit timestamp (+ sampled B3 context) and the append is
-        reported. The append runs inside ``tracker.suppressed()``:
-        with fsync=off/batch the WAL's on_durable callback fires
-        synchronously in ``wal.append`` while THIS thread holds the
-        store's encode lock — a tracker flush there would re-enter
-        ``store.apply`` and deadlock; suppression defers it to the
-        next out-of-lock flush site."""
+        reported. With fsync=off/batch the WAL's on_durable callback
+        fires synchronously in ``wal.append`` while THIS thread holds
+        the store's encode lock; the tracker only buffers there (its
+        sink, ``store.apply``, runs on a thread of its own)."""
         from zipkin_tpu.wal.record import dump_dict_deltas, encode_unit
 
         sizes, deltas = dump_dict_deltas(self.dicts, self._wal_marks)
         lin = self.lineage
         if lin is not None:
             extra = lin.stamp()
-            with lin.suppressed():
-                seq = self.wal.append(encode_unit(
-                    group, self._wal_marks, deltas, extra=extra))
+            seq = self.wal.append(encode_unit(
+                group, self._wal_marks, deltas, extra=extra))
             lin.note_append(seq, extra)
         else:
             seq = self.wal.append(encode_unit(group, self._wal_marks,

@@ -70,6 +70,8 @@ import time
 import zlib
 from typing import Iterator, List, Optional, Tuple
 
+from zipkin_tpu.obs.stages import stage
+
 _MAGIC = b"ZWAL1"
 _HDR = struct.Struct(">I")
 _REC = struct.Struct(">IBI")  # payload_len, flags, crc32
@@ -267,8 +269,8 @@ class WriteAheadLog:
         # Durable-frontier observer (obs.fleet lineage): called with
         # the new durable seq AFTER _cond is released at every site
         # that advances the frontier. Must never be invoked under
-        # _cond — the callback flushes self-trace spans through
-        # store.apply, whose lock ranks BELOW the WAL's (10 < 60).
+        # _cond — the callback takes the tracker's lock, and a
+        # callback that blocked would stall every append behind it.
         self._on_durable = None  # guarded-by: _cond (the slot, not the call)
         self.torn_records_cut = 0  # records dropped by the open() scan
         self._next_seq = 1  # guarded-by: _cond
@@ -292,6 +294,14 @@ class WriteAheadLog:
             "zipkin_wal_truncation_backlog_segments",
             "Segment files not yet covered by a checkpoint truncation",
             fn=self._live_segments))
+        self.g_last = reg.register(obs.Gauge(
+            "zipkin_wal_last_seq",
+            "Sequence of the newest appended record (the append "
+            "frontier)", fn=lambda: float(self.last_seq)))
+        self.g_durable = reg.register(obs.Gauge(
+            "zipkin_wal_durable_seq",
+            "Highest sequence known fsynced (the durable frontier "
+            "an ack waits for)", fn=lambda: float(self.durable_seq)))
         self.c_records = reg.register(obs.Counter(
             "zipkin_wal_records_total", "Records appended to the WAL"))
         self.c_replayed = reg.register(obs.Counter(
@@ -424,9 +434,9 @@ class WriteAheadLog:
         """Register ``fn(durable_seq)`` to run after every durable-
         frontier advance, OUTSIDE ``_cond``. With ``fsync='off'`` or
         ``'batch'`` the call happens synchronously inside ``append``
-        (the caller may hold its own locks — obs.fleet's tracker
-        defers its flush via ``suppressed()`` for exactly this case);
-        under ``'interval'`` it runs on the group-commit thread."""
+        (the caller may hold its own locks); under ``'interval'`` it
+        runs on the group-commit thread, where no fsync happens while
+        it runs: ``fn`` must only buffer (obs.fleet's tracker does)."""
         with self._cond:
             self._on_durable = fn
 
@@ -485,7 +495,13 @@ class WriteAheadLog:
             if len(packed) < len(payload):
                 data, flags = packed, FLAG_DEFLATE
         frame = _REC.pack(len(data), flags, zlib.crc32(data)) + data
-        t0 = time.perf_counter()
+        with stage("wal.append", self.h_append) as span:
+            seq = self._append_frame(frame)
+            span.tag(unit=seq)
+        self.c_records.inc()
+        return seq
+
+    def _append_frame(self, frame: bytes) -> int:
         with self._cond:
             if self._closed:
                 raise RuntimeError("write-ahead log is closed")
@@ -527,15 +543,13 @@ class WriteAheadLog:
                 self._cond.notify_all()
             # INTERVAL: the group-commit thread advances the frontier.
         self._notify_durable(prev_durable)
-        self.h_append.observe(time.perf_counter() - t0)
-        self.c_records.inc()
         return seq
 
     def _fsync_locked(self) -> None:  # called-under: _cond
         if self._file is not None:
-            t0 = time.perf_counter()
-            os.fsync(self._file.fileno())
-            self.h_fsync.observe(time.perf_counter() - t0)
+            with stage("wal.fsync", self.h_fsync,
+                       durable_seq=self._next_seq - 1):
+                os.fsync(self._file.fileno())
         self._sync_error = None
         self._durable = self._next_seq - 1
         self._cond.notify_all()
@@ -626,8 +640,9 @@ class WriteAheadLog:
                         self._cond.notify_all()
             if fd is not None:
                 try:
-                    t0 = time.perf_counter()
-                    os.fsync(fd)
+                    with stage("wal.fsync", self.h_fsync,
+                               durable_seq=target):
+                        os.fsync(fd)
                 except Exception as e:  # noqa: BLE001
                     # The thread must SURVIVE a transient EIO/ENOSPC:
                     # park the error for wait_durable to surface
@@ -638,7 +653,6 @@ class WriteAheadLog:
                         self._sync_fails += 1
                         self._cond.notify_all()
                 else:
-                    self.h_fsync.observe(time.perf_counter() - t0)
                     with self._cond:
                         prev = self._durable
                         self._sync_error = None
@@ -816,7 +830,8 @@ class WriteAheadLog:
         if self._syncer is not None:
             self._syncer.join(timeout=5.0)
         for m in (self.h_append, self.h_fsync, self.g_bytes,
-                  self.g_backlog, self.c_records, self.c_replayed,
+                  self.g_backlog, self.g_last, self.g_durable,
+                  self.c_records, self.c_replayed,
                   self.c_corrupt, self.c_truncated):
             if self._registry.get(m.name) is m:
                 self._registry.unregister(m.name)
