@@ -37,7 +37,8 @@ def _count_ops(stablehlo_text: str) -> dict:
     down: how many scatter/sort ops the ingest step ISSUES per batch.
     r5 split-design baseline at the smoke shapes: 101 scatters /
     6 sorts / 80 gathers; the r6 unified arena shipped 95 / 5 / 79;
-    the r12 counting-sort rank path ships 95 / 4 / 79 (ceilings
+    the r12 counting-sort rank path shipped 95 / 4 / 79; the PR 26
+    arena planes ship 95 / 4 / 84 (ceilings
     centralized in zipkin_tpu.store.census — the one place the tier-1
     gate reads them from). One shared counter (dev.
     stablehlo_op_census) backs this gate AND the runtime

@@ -236,7 +236,7 @@ def main():
             poison_bucket=a_host, poison_gid=span_gid_of_ann,
             poison_ok=a_idx_ok & (a_host != h1) & (a_host != h2),
         )
-        return out[0].sum()
+        return sum(p.sum() for p in out[0])
 
     timeit("unified index write (cand+trace, concat+sort+scatter)",
            jax.jit(cand_only), state, b)
@@ -366,7 +366,7 @@ def main():
     small_S = small_nb * small_depth
     if PK.arena_scatter_supported(small_S, small_nb):
         NS = min(NR, 1 << 17)
-        ent = jnp.zeros((small_S, 3), jnp.int64)
+        ent = dev._arena_init(small_S)
         sb = ((jnp.arange(NS, dtype=jnp.int64) * 2654435761)
               % small_nb).astype(jnp.int32)
         svals = jnp.stack([jnp.arange(NS, dtype=jnp.int64)] * 3, -1)
@@ -379,12 +379,13 @@ def main():
             rank = dev._fifo_ranks(sb, sval, small_nb)
             slot = sslot0.astype(jnp.int32) + (rank % small_depth)
             keep = sval & (rank >= 0)
-            return dev._uset_cols64(e, slot, svals, keep).sum()
+            return sum(p.sum() for p in
+                       dev._arena_set(e, slot, svals, keep))
 
         def pallas_scatter(e):
-            return PK.arena_claim_scatter(
+            return sum(p.sum() for p in PK.arena_claim_scatter(
                 e, sb, sbase, sslot0, sdep, svals, sval,
-                n_buckets=small_nb).sum()
+                n_buckets=small_nb))
 
         timeit(f"arena scatter: XLA rank+6-plane ({NS} rows)",
                jax.jit(xla_scatter), ent)

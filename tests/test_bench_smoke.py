@@ -8,7 +8,8 @@ sort. The ceilings live in ONE place — ``zipkin_tpu.store.census`` —
 consumed here and by the smoke script, so a path change updates
 exactly one number (raise one only with a NOTES entry explaining what
 bought the extra launches). r5 split-design baseline: 101 scatters /
-6 sorts / 80 gathers; r6: 95/5/79; r12: 95/4/79.
+6 sorts / 80 gathers; r6: 95/5/79; r12: 95/4/79; PR 26 (arena
+planes): 95/4/84.
 """
 
 import json

@@ -196,7 +196,8 @@ def test_arena_claim_scatter_compiles(one_chip, mosaic):
     s, nb, n = 1 << 15, 1 << 10, 4096
     assert PK.arena_scatter_supported(s, nb)
     i32 = _spec((n,), jnp.int32, one_chip)
+    planes = (_spec((s,), jnp.int32, one_chip),) * dev.ARENA_PLANES
     _compiled_on_tpu(PK.arena_claim_scatter.lower(
-        _spec((s, 3), jnp.int64, one_chip), i32, i32, i32, i32,
+        planes, i32, i32, i32, i32,
         _spec((n, 3), jnp.int64, one_chip),
         _spec((n,), jnp.bool_, one_chip), n_buckets=nb))

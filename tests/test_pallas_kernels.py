@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from zipkin_tpu.ops import pallas_kernels as pk
+from zipkin_tpu.store import device as dev
 
 
 class TestFlatHistogram:
@@ -71,8 +72,6 @@ class TestArenaClaimScatter:
                        n_b):
         import jax
 
-        from zipkin_tpu.store import device as dev
-
         rank = dev._fifo_ranks(bucket, valid, n_b)
         pos_lo = jax.lax.bitcast_convert_type(pos, jnp.int32)[:, 0]
         b_c = jnp.clip(bucket, 0, n_b - 1)
@@ -83,33 +82,43 @@ class TestArenaClaimScatter:
         keep = valid & (rank >= cnt[b_c] - depth)
         slot = (b_c * depth).astype(jnp.int32) + (
             (pos_b + rank) % depth)
-        return dev._uset_cols64(entries, slot, vals, keep)
+        return dev._arena_set(entries, slot, vals, keep)
 
-    def test_matches_xla_path(self):
-        rng = np.random.default_rng(11)
+    @pytest.mark.parametrize("n", [7, 300, 1024])
+    def test_matches_xla_path(self, n):
+        rng = np.random.default_rng(11 + n)
         n_b, depth = 53, 8
         S = n_b * depth
-        for n in (7, 300, 1024):
-            entries = jnp.asarray(
-                rng.integers(-2**62, 2**62, (S, 3)), jnp.int64)
-            bucket = jnp.asarray(rng.integers(0, n_b, n), jnp.int32)
-            pos = jnp.asarray(rng.integers(0, 500, n_b), jnp.int64)
-            valid = jnp.asarray(rng.random(n) < 0.8)
-            vals = jnp.asarray(
-                rng.integers(-2**62, 2**62, (n, 3)), jnp.int64)
-            dvec = jnp.full(n, depth, jnp.int32)
-            want = self._xla_reference(entries, bucket, pos, depth,
-                                       vals, valid, n_b)
-            pos_lo = np.asarray(pos).astype(np.uint64) & 0xFFFFFFFF
-            base = jnp.asarray(
-                pos_lo[np.clip(np.asarray(bucket), 0, n_b - 1)],
-                jnp.int32)
-            got = pk.arena_claim_scatter(
-                entries, bucket, base,
-                bucket.astype(jnp.int64) * depth, dvec, vals, valid,
-                n_buckets=n_b, tile=256)
-            np.testing.assert_array_equal(np.asarray(want),
-                                          np.asarray(got), err_msg=n)
+        rows = rng.integers(-2**62, 2**62, (S, 3))
+        entries = tuple(jnp.asarray(p) for p in dev.arena_planes(rows))
+        bucket = jnp.asarray(rng.integers(0, n_b, n), jnp.int32)
+        pos = jnp.asarray(rng.integers(0, 500, n_b), jnp.int64)
+        valid = jnp.asarray(rng.random(n) < 0.8)
+        vals = jnp.asarray(
+            rng.integers(-2**62, 2**62, (n, 3)), jnp.int64)
+        dvec = jnp.full(n, depth, jnp.int32)
+        want = self._xla_reference(entries, bucket, pos, depth,
+                                   vals, valid, n_b)
+        pos_lo = np.asarray(pos).astype(np.uint64) & 0xFFFFFFFF
+        base = jnp.asarray(
+            pos_lo[np.clip(np.asarray(bucket), 0, n_b - 1)],
+            jnp.int32)
+        got = pk.arena_claim_scatter(
+            entries, bucket, base,
+            bucket.astype(jnp.int64) * depth, dvec, vals, valid,
+            n_buckets=n_b, tile=256)
+        np.testing.assert_array_equal(
+            dev.arena_rows64(want), dev.arena_rows64(got))
+        # Both writers against a plain numpy FIFO walk over i64 rows:
+        # each valid row, in arrival order, lands whole at its
+        # bucket's next slot; nothing else moves.
+        model = rows.copy()
+        cur = np.asarray(pos).copy()
+        for i in np.flatnonzero(np.asarray(valid)):
+            b = int(bucket[i])
+            model[b * depth + cur[b] % depth] = np.asarray(vals)[i]
+            cur[b] += 1
+        np.testing.assert_array_equal(dev.arena_rows64(got), model)
 
     def test_overflow_single_bucket(self):
         # 100 rows into one depth-4 bucket: the kernel writes all 100
@@ -117,7 +126,7 @@ class TestArenaClaimScatter:
         # rows at the cursor-aligned positions.
         n_b, depth, n = 4, 4, 100
         S = n_b * depth
-        entries = jnp.full((S, 3), -1, jnp.int64)
+        entries = dev._arena_init(S)
         bucket = jnp.zeros(n, jnp.int32)
         vals = jnp.stack(
             [jnp.arange(n, dtype=jnp.int64)] * 3, axis=-1)
@@ -125,7 +134,7 @@ class TestArenaClaimScatter:
             entries, bucket, jnp.zeros(n, jnp.int32),
             jnp.zeros(n, jnp.int64), jnp.full(n, depth, jnp.int32),
             vals, jnp.ones(n, bool), n_buckets=n_b)
-        got = np.asarray(got)
+        got = dev.arena_rows64(got)
         # slots (0+r) % 4 for r=96..99 -> slot r%4 holds row r.
         np.testing.assert_array_equal(got[:4, 0], [96, 97, 98, 99])
         np.testing.assert_array_equal(got[4:, 0], -np.ones(S - 4))
@@ -143,7 +152,6 @@ class TestArenaClaimScatter:
         # fused kernel actually engages — counters prove it). Slow
         # lane: the kernel-level fuzz above is the bitwise proof in
         # tier-1; this is the whole-store integration twin.
-        from zipkin_tpu.store import device as dev
         from zipkin_tpu.store.tpu import TpuSpanStore
         from zipkin_tpu.testing.crash import states_bitwise_equal
         from zipkin_tpu.tracegen import generate_traces

@@ -96,7 +96,17 @@ _PINS_FILE = "pins.pkl"
 #    closed). Revisions 15-17 were consumed by the replication /
 #    sharded-serving line (sharded clocks, fleet WAL shipping); their
 #    snapshots restore through the same revision-tolerant key checks.
-_REVISION = 18
+# 19: the index arena is saved as the store now holds it: six i32
+#    bit-plane leaves ``cand_idx.0`` .. ``cand_idx.5`` ([slots] each,
+#    [n_shards, slots] sharded; plane 2c the low word of column c,
+#    2c + 1 its high word — device._arena_set) instead of one
+#    ``cand_idx`` [slots, 3] i64 leaf. Same bits, other shape: a
+#    revision 11-18 snapshot's i64 leaf restores BIT FOR BIT through a
+#    host-side numpy view (device.arena_planes), gated on the stored
+#    key, not the revision. A pre-19 loader drops the unknown keys
+#    but restores cand_pos over an empty arena: do not downgrade
+#    across this revision (docs/MIGRATION.md).
+_REVISION = 19
 _SEGMENTS_DIR = "segments"
 
 
@@ -180,6 +190,17 @@ def _dict_load(dictionary, values: list) -> None:
 
     for item in values:
         dictionary.encode(load_value(item))
+
+
+def _leaf_items(name: str, value) -> list:
+    """(npz key, leaf) pairs of one StoreState field: a plain array
+    under its own name; the ``counters`` dict and the ``cand_idx``
+    plane tuple as ``name.<key>`` / ``name.<plane>``."""
+    if isinstance(value, dict):
+        return [(f"{name}.{k}", v) for k, v in value.items()]
+    if isinstance(value, tuple):
+        return [(f"{name}.{j}", v) for j, v in enumerate(value)]
+    return [(name, value)]
 
 
 def _savez_fast(path: str, leaves: dict) -> None:
@@ -398,12 +419,8 @@ def save(store, path: str, chunk_deadline_s: Optional[float] = None,
             state = store.states if n_shards else store.state
             host_state = jax.device_get(state)
         for name in dev.StoreState._FIELDS:
-            value = getattr(host_state, name)
-            if name == "counters":
-                for k, v in value.items():
-                    leaves[f"counters.{k}"] = np.asarray(v)
-            else:
-                leaves[name] = np.asarray(value)
+            for key, leaf in _leaf_items(name, getattr(host_state, name)):
+                leaves[key] = np.asarray(leaf)
     else:
         # Chunked+resumable path. The read lock covers the whole
         # gather (consistent cut; writers block). On timeout the
@@ -432,11 +449,8 @@ def save(store, path: str, chunk_deadline_s: Optional[float] = None,
                     json.dump(gen, f)
                 state = store.states if n_shards else store.state
                 for name in dev.StoreState._FIELDS:
-                    value = getattr(state, name)
-                    items = ([(f"counters.{k}", v)
-                              for k, v in value.items()]
-                             if name == "counters" else [(name, value)])
-                    for key, leaf in items:
+                    for key, leaf in _leaf_items(name,
+                                                 getattr(state, name)):
                         dest = os.path.join(staging, key + ".npy")
                         if os.path.exists(dest):
                             stats["resumed_leaves"] += 1
@@ -766,12 +780,24 @@ def load(path: str, mesh=None, config_defaults=None):
     # addresses them by name.
     base_state = store.inner.states if n_shards else store.state
     counters = dict(base_state.counters)
+    planes = {}
     for key in data.files:
         if key.startswith("counters."):
             counters[key.split(".", 1)[1]] = jax.numpy.asarray(
                 _leaf(key))
+        elif key.startswith("cand_idx."):
+            planes[int(key.split(".", 1)[1])] = _leaf(key)
+        elif key == "cand_idx":
+            # Revisions 11-18 saved the arena as one [slots, 3] i64
+            # leaf: the same bits as today's six i32 planes, split by
+            # a host-side view (pre-11 arenas are dropped below).
+            planes = dict(enumerate(dev.arena_planes(_leaf(key))))
         else:
             upd[key] = jax.numpy.asarray(_leaf(key))
+    if planes:
+        upd["cand_idx"] = tuple(
+            jax.numpy.asarray(planes[j])
+            for j in range(dev.ARENA_PLANES))
     # Drop snapshot counters the current schema no longer carries.
     counters = {
         k: v for k, v in counters.items() if k in base_state.counters
@@ -904,11 +930,8 @@ def load(path: str, mesh=None, config_defaults=None):
         def place(x):
             return jax.device_put(jax.numpy.asarray(x), sharding)
 
-        upd = {
-            k: ({ck: place(cv) for ck, cv in v.items()}
-                if k == "counters" else place(v))
-            for k, v in upd.items()
-        }
+        upd = {k: jax.tree_util.tree_map(place, v)
+               for k, v in upd.items()}
         with store._rw.write():
             store.inner.states = store.inner.states.replace(**upd)
             if pre_index:

@@ -252,10 +252,13 @@ def _arena_kernel(bucket_ref, base_ref, slot0_ref, dmask_ref, valid_ref,
 @functools.partial(jax.jit, static_argnames=("n_buckets", "tile"))
 def arena_claim_scatter(entries, bucket, base, slot0, depth, vals,
                         valid, n_buckets: int, tile: int = ARENA_TILE):
-    """Fused FIFO claim + entry-row scatter over the unified [slots, 3]
-    i64 index arena. Per valid row: claim the bucket's next FIFO slot
-    (``slot0 + ((base + cursor++) & (depth - 1))``) and store the row's
-    three i64 columns as six i32 planes. Grid steps run sequentially on
+    """Fused FIFO claim + entry-row scatter over the unified index
+    arena, handed over as the store keeps it: ``entries`` is the tuple
+    of six [slots] i32 planes (store/device: plane 2c the low word of
+    column c, 2c + 1 its high word) and so is the result. Per valid
+    row: claim the bucket's next FIFO slot (``slot0 + ((base +
+    cursor++) & (depth - 1))``) and store the row's three i64 columns
+    (``vals`` [N, 3] i64) into the planes. Grid steps run sequentially on
     a TPU core, so the cursor walk needs no atomics and write order is
     arrival order — the final arena is bitwise-identical to the XLA
     path's rank-gated unique scatter (fuzz-gated by
@@ -266,7 +269,7 @@ def arena_claim_scatter(entries, bucket, base, slot0, depth, vals,
     the caller); ``depth`` per-row powers of two; callers check
     ``arena_scatter_supported`` first (whole-arena VMEM residency).
     """
-    S = entries.shape[0]
+    S = entries[0].shape[0]
     n = bucket.shape[0]
     if n == 0:
         return entries
@@ -274,13 +277,10 @@ def arena_claim_scatter(entries, bucket, base, slot0, depth, vals,
     bp = -(-n_buckets // LANES) * LANES
     n_tiles = -(-n // tile)
     pad = n_tiles * tile - n
-    # Arena -> six plane-major i32 buffers ([S] each, lane-padded): a
-    # row's (gid, verify, ts) i64 columns become planes 2c (lo) and
-    # 2c+1 (hi) — the same bitcast _p32 uses, kept plane-major so each
+    # Each plane lane-padded to a [rows, LANES] VMEM block, so each
     # kernel write is one contiguous VMEM row RMW.
-    p = jax.lax.bitcast_convert_type(entries, jnp.int32).reshape(S, 6)
-    planes = jnp.pad(jnp.moveaxis(p, 0, 1), ((0, 0), (0, sp - S)))
-    planes = planes.reshape(6, sp // LANES, LANES)
+    planes = [jnp.pad(p, (0, sp - S)).reshape(sp // LANES, LANES)
+              for p in entries]
     v = jax.lax.bitcast_convert_type(
         jnp.asarray(vals, jnp.int64), jnp.int32).reshape(n, 6)
 
@@ -306,10 +306,8 @@ def arena_claim_scatter(entries, bucket, base, slot0, depth, vals,
         ] * 6,
         scratch_shapes=[pltpu.VMEM((bp // LANES, LANES), jnp.int32)],
         interpret=_interpret(),
-    )(*row_ins, *(planes[j] for j in range(6)))
-    flat = jnp.stack(outs).reshape(6, sp)[:, :S]
-    return jax.lax.bitcast_convert_type(
-        jnp.moveaxis(flat, 0, 1).reshape(S, 3, 2), jnp.int64)
+    )(*row_ins, *planes)
+    return tuple(o.reshape(sp)[:S] for o in outs)
 
 
 # ---------------------------------------------------------------------------

@@ -50,9 +50,22 @@ History of the measured counts at the smoke shapes:
   on allocation. Additive with the window bump (measured: paged+win
   == BASE + WINDOW + PAGED exactly).
 
+- PR 26 arena planes:     +0 scatters / +0 sorts / +5 gathers — the
+  index arena became six [slots] i32 bit-plane leaves (device "the
+  index arena's plane form"), so the displaced-entry read is no longer
+  ONE [N, 3] i64 row gather but six word gathers of the rows each
+  section needs (ts lo/hi on the candidate prefix, gid lo/hi from the
+  keyed slice on, verify lo/hi on the keyed slice): 79 -> 84. What it
+  bought: the entry write's six 1-D scatters now land in place in
+  donated leaves; the 29 whole-arena ops beside them (ARENA_SWEEP_OPS
+  below) are gone, and with them 3.3 GB of step temporaries at the
+  2^22 ring (chipless compile, PR 26: 60 MB).
+
 Raise a ceiling only with a note here explaining what bought the
 extra launches.
 """
+
+import re
 
 # The per-layout lowering table: (scatters, sorts, gathers) — "BASE"
 # is the default ring/window-off lowering; every "+NAME" row is the
@@ -61,7 +74,7 @@ extra launches.
 # composed rows at exact equality, so an ungated path shows up as a
 # census mismatch, not a silent regression).
 LOWERING_TABLE = {
-    "BASE": (95, 4, 79),
+    "BASE": (95, 4, 84),
     "+WINDOW": (5, 0, 2),   # r13 windowed Moments-sketch arena
     "+PAGED": (2, 0, 2),    # r19 paged span layout
 }
@@ -102,6 +115,48 @@ MAX_STEP_SCATTERS, MAX_STEP_SORTS, MAX_STEP_GATHERS = expected_census(
 # expected lowering when rank_path="argsort" (or the wm_shift == 0 /
 # scratch-infeasible fallbacks) is active.
 ARGSORT_STEP_SORTS = 5
+
+# Ops of the fused step that pass over a whole index-arena plane, other
+# than the gathers and scatters that touch the batch's rows
+# (stablehlo_arena_sweeps below; tests/test_arena_planes.py gates every
+# layout, window on and off). PR 26: the arena is six 1-D i32 plane
+# leaves precisely so that this is 0 — held as one [slots, 3] i64 leaf
+# the entry write cost 29 such ops at the smoke shapes (bitcasts, strided
+# slices, stacks), 60 % of the step on the chip at a 1.99 GB arena.
+# Nothing buys a raise: a new reader gathers words at the slots it
+# probes; a new writer scatters into the donated planes.
+ARENA_SWEEP_OPS = 0
+
+
+def stablehlo_arena_sweeps(stablehlo_text: str, slots: int) -> list:
+    """Ops of a StableHLO lowering that pass over a whole arena plane:
+    every op other than ``gather``/``scatter`` (and the entry
+    function's own arguments and results) with an operand or result
+    that has a dimension of ``slots`` (= ``idx_layout[2]``, chosen by
+    the caller to coincide with no other dimension). A step that costs
+    the batch and not the arena has none (ARENA_SWEEP_OPS). Returns the
+    offending op names, in order."""
+    dims = re.compile(r"tensor<((?:\d+x)+)[a-z]")
+    name = re.compile(r'"?\b(?:stablehlo|chlo|func)\.([a-z_]+)"?|\b(call) @')
+    out, open_ops = [], []
+    for line in stablehlo_text.splitlines():
+        ln = line.strip()
+        m = name.search(ln)
+        op = (m.group(1) or m.group(2)) if m else None
+        if ln.startswith("return "):
+            op = "return"
+        if ln.startswith("})"):
+            op = open_ops.pop() if open_ops else None
+        elif ln.endswith("({"):
+            open_ops.append(op)
+            continue  # a region op's types come on its closing line
+        if op in ("gather", "scatter", "return") or (
+                op == "func" and "public @main" in ln):
+            continue
+        if any(str(slots) in d.split("x") for d in dims.findall(ln)):
+            out.append(op or ln[:60])
+    return out
+
 
 # Stage-1 sketch-mirror budget: the host COO delta (store/mirror,
 # riding the hot encode path since r11) may add at most this fraction

@@ -370,6 +370,9 @@ def _pack_layout(fams):
 # Callers must guarantee uniqueness among the surviving (ok) indices;
 # dropped rows are remapped to DISTINCT out-of-bounds slots so the
 # promise holds for the whole index vector.
+#
+# Rule: a state leaf that a step scatters into lives in PLANE form
+# (see "the index arena's plane form" below).
 
 
 def _p32(x):
@@ -425,29 +428,69 @@ def _uset_p(arr2, idx, vals, ok):
     return jnp.stack([lo, hi], axis=-1)
 
 
-def _uset_cols64(arr, idx, vals, ok):
-    """Row scatter ``arr.at[idx[ok]].set(vals[ok])`` for an [M, C] i64
-    array via 2C strided 1-D i32 plane scatters (2-D scatters are slow
+# -- the index arena's plane form ---------------------------------------------
+#
+# The index arena's logical [total_slots, 3] i64 rows of (gid, verify,
+# ts) are held as six [total_slots] i32 leaves — plane 2c is the low
+# word of column c, plane 2c + 1 its high word, the bits ``_p32`` would
+# give — so the step's entry write is six 1-D unique i32 scatters into
+# donated leaves, in place, and costs the batch. Slicing a plane out of
+# an i64 or stacked leaf and stacking it back is a pass over the whole
+# leaf every step: 60 % of the step at a 1.99 GB arena (PERF.md 6,
+# PR 26). Readers gather 4-byte words at the slots they probe and
+# combine lo and hi for the gathered rows only.
+ARENA_COLS = 3
+ARENA_PLANES = 2 * ARENA_COLS
+
+
+def _arena_init(total_slots: int):
+    """Every logical entry (-1, -1, -1): both words of i64 -1 are -1."""
+    return tuple(jnp.full(total_slots, -1, jnp.int32)
+                 for _ in range(ARENA_PLANES))
+
+
+def _arena_col(planes, idx, col: int):
+    """Logical i64 column ``col`` (0 gid, 1 verify, 2 ts) of the arena
+    rows ``idx`` (any shape): two word gathers."""
+    return _p64(jnp.stack(
+        [planes[2 * col][idx], planes[2 * col + 1][idx]], axis=-1))
+
+
+def _arena_window(planes, start, depth: int):
+    """``depth`` consecutive logical rows from ``start`` -> [depth, 3]
+    i64: one contiguous slice per plane (a bucket's FIFO window)."""
+    w = [jax.lax.dynamic_slice(p, (start,), (depth,)) for p in planes]
+    return jnp.stack(
+        [_p64(jnp.stack(w[2 * c:2 * c + 2], axis=-1))
+         for c in range(ARENA_COLS)], axis=-1)
+
+
+def _arena_set(planes, idx, vals, ok):
+    """Row scatter ``arena[idx[ok]] = vals[ok]`` of logical [N, 3] i64
+    rows: one 1-D unique i32 scatter per plane (2-D scatters are slow
     in every dtype on this backend; 1-D unique i32 is ~4.5 ns/row)."""
-    m, ncols = arr.shape
-    p = _p32(arr)                          # [M, C, 2]
-    v = _p32(jnp.asarray(vals, jnp.int64))  # [N, C, 2]
-    safe = _oob_unique(idx, ok, m)
-    # Recombine per COLUMN (the _uset idiom), then stack the i64
-    # columns. Bitcasting one interleaved [M, C, 2] stack of all 2C
-    # planes is the same bits, but on the TPU its de-interleaving
-    # reshape compiled into a program unrolled over the whole arena:
-    # minutes of compile per launch shape, growing with capacity
-    # (PERF.md, PR 22).
-    cols = []
-    for cdx in range(ncols):
-        lo, hi = (
-            p[:, cdx, pl].at[safe].set(
-                v[:, cdx, pl], mode="drop", unique_indices=True)
-            for pl in range(2)
-        )
-        cols.append(_p64(jnp.stack([lo, hi], axis=-1)))
-    return jnp.stack(cols, axis=-1)
+    v = _p32(jnp.asarray(vals, jnp.int64))  # [N, 3, 2]
+    safe = _oob_unique(idx, ok, planes[0].shape[0])
+    return tuple(
+        p.at[safe].set(v[:, j // 2, j % 2], mode="drop",
+                       unique_indices=True)
+        for j, p in enumerate(planes))
+
+
+def arena_rows64(planes):
+    """Host view: numpy planes ([..., M] each) -> the logical
+    [..., M, 3] i64 arena (tests, checkpoint migration checks)."""
+    p = np.stack([np.asarray(x) for x in planes], axis=-1)
+    return np.ascontiguousarray(p).view(np.int64)
+
+
+def arena_planes(rows64):
+    """Host inverse of ``arena_rows64``: a logical [..., M, 3] i64
+    arena (the pre-revision-19 checkpoint leaf) -> six contiguous
+    [..., M] i32 planes."""
+    p = np.ascontiguousarray(np.asarray(rows64, np.int64)).view(np.int32)
+    return tuple(np.ascontiguousarray(p[..., j])
+                 for j in range(ARENA_PLANES))
 
 
 # Per-key record table: i32 fingerprints (claims ride the vectorized
@@ -736,9 +779,11 @@ class StoreState:
     # -- index column families -------------------------------------------
     # ALL seven index families — the four candidate families (service /
     # service+name / service+ann-value / service+binary) AND the three
-    # trace-membership sub-families — share ONE flat [total_slots, 3]
-    # i64 entry arena of (gid, verify, ts) rows, one [total_buckets]
-    # i64 cursor array, and one watermark array, laid out per
+    # trace-membership sub-families — share ONE flat arena of logical
+    # [total_slots, 3] i64 (gid, verify, ts) rows, held as six
+    # [total_slots] i32 bit-plane leaves (see "the index arena's plane
+    # form"), one [total_buckets] i64 cursor array, and one watermark
+    # array, laid out per
     # StoreConfig.idx_layout (candidate families are the prefix; the
     # probe-side ``cand_layout`` view is unchanged). One combined
     # rank-sort + scatter pass serves every family (_index_write). A
@@ -751,7 +796,7 @@ class StoreState:
     # whole-trace fetch and durations. The watermark array carries ts
     # values on the candidate prefix and gids on the trace suffix;
     # every query slices by family, never across the boundary.
-    cand_idx: jnp.ndarray
+    cand_idx: Tuple[jnp.ndarray, ...]  # 6 x [total_slots] i32 planes
     cand_pos: jnp.ndarray
     cand_wm: jnp.ndarray
     # Middle-host trust: annotation/binary index entries are written
@@ -897,7 +942,7 @@ def init_state(config: StoreConfig = StoreConfig()) -> StoreState:
         # max-war and verify = -1 hashes to a fingerprint that matches
         # no claimed key. Changing these fills requires re-deriving that
         # argument (or adding an explicit old-entry validity check).
-        cand_idx=jnp.full((c.idx_layout[2], 3), -1, jnp.int64),
+        cand_idx=_arena_init(c.idx_layout[2]),
         cand_pos=jnp.zeros(c.idx_layout[1], jnp.int64),
         cand_wm=jnp.full(c.idx_layout[1], I64_MIN, jnp.int64),
         ann_poison=jnp.full(S, I64_MIN, jnp.int64),
@@ -1638,18 +1683,20 @@ def _index_write(entries, pos, wm, key_tab, key_wm, ann_poison,
     # watermark war and match no key fingerprint; see init_state).
     occupied = keep & (pos_b + rank >= depth)
     gidx = jnp.where(keep, slot, 0)
-    # ONE row gather of the displaced entries for ALL families:
-    # profiled ~3x cheaper than per-column i64 gathers on this backend
-    # (the [N, 3] rows are contiguous 24-byte reads;
-    # scripts/profile_ingest.py arm 8b).
-    old_rows = entries[gidx]
+    # The displaced entries, for ALL families, as word gathers of the
+    # planes each section needs (lo and hi combined for the gathered
+    # rows only): ts for the candidate prefix, gid from the keyed
+    # slice on (keyed + trace rows are contiguous), verify for the
+    # keyed slice.
     cand = slice(0, n_cand_rows)
     trc = slice(n_cand_rows, None)
-    old_ts_c = jnp.where(occupied[cand], old_rows[cand, 2], I64_MIN)
-    # Old entry identity is consumed by the keyed-slice machinery below.
     sfx = slice(keyed_from, n_cand_rows)
-    old_gid_s = old_rows[sfx, 0]
-    old_verify_s = old_rows[sfx, 1]
+    old_ts_c = jnp.where(
+        occupied[cand], _arena_col(entries, gidx[cand], 2), I64_MIN)
+    old_gid = _arena_col(entries, gidx[keyed_from:], 0)
+    # Old entry identity is consumed by the keyed-slice machinery below.
+    old_gid_s = old_gid[:n_cand_rows - keyed_from]
+    old_verify_s = _arena_col(entries, gidx[sfx], 1)
     dropped_ts = jnp.where(
         valid[cand] & ~keep[cand],
         jnp.asarray(ts, jnp.int64)[cand], I64_MIN,
@@ -1663,7 +1710,8 @@ def _index_write(entries, pos, wm, key_tab, key_wm, ann_poison,
     # every busy bucket's gate closed forever. In-batch dropped rows
     # carry their own gid.
     gid = jnp.asarray(gid, jnp.int64)
-    tr_wmv = jnp.where(occupied[trc], old_rows[trc, 0], gid[trc])
+    tr_wmv = jnp.where(
+        occupied[trc], old_gid[n_cand_rows - keyed_from:], gid[trc])
     tr_ok = occupied[trc] | (valid[trc] & ~keep[trc])
     verify = jnp.asarray(verify, jnp.int64)
     vals = jnp.stack([gid, verify, jnp.asarray(ts, jnp.int64)], axis=-1)
@@ -1683,7 +1731,7 @@ def _index_write(entries, pos, wm, key_tab, key_wm, ann_poison,
             entries, b_c, pos_b, slot0, depth, vals, valid,
             n_buckets=n_b)
     else:
-        entries = _uset_cols64(entries, slot, vals, keep)
+        entries = _arena_set(entries, slot, vals, keep)
     pos = pos + cnt.astype(pos.dtype)
 
     # -- per-key fingerprint records (suffix rows only) ----------------
@@ -2913,10 +2961,8 @@ def _iq_service_impl(entries, pos, wm, row_gid, indexable, trace_id,
     # family (_iq_verify_impl), never through this bucket.
     b_base, s_base, n_b, depth = layout
     svc_i = jnp.clip(jnp.asarray(svc, jnp.int32), 0, n_b - 1)
-    row = jax.lax.dynamic_slice(
-        entries, (jnp.int32(s_base) + svc_i * depth, jnp.int32(0)),
-        (depth, 3),
-    )
+    row = _arena_window(
+        entries, jnp.int32(s_base) + svc_i * depth, depth)
     gb = jnp.int32(b_base) + svc_i
     ok = jnp.ones(depth, bool)
     return _iq_finish(row, pos[gb], wm[gb], row_gid, indexable, ts_last,
@@ -2952,10 +2998,7 @@ def _iq_verify_impl(entries, pos, wm, row_gid, indexable, trace_id,
     b_base, s_base, n_b, depth = layout
     mixed = _mixb(list(key_parts))
     lb = _bucket_of(mixed, n_b)
-    row = jax.lax.dynamic_slice(
-        entries, (jnp.int32(s_base) + lb * depth, jnp.int32(0)),
-        (depth, 3),
-    )
+    row = _arena_window(entries, jnp.int32(s_base) + lb * depth, depth)
     gb = jnp.int32(b_base) + lb
     ver_ok = row[:, 1] == _verify_of(mixed)
     cnt, bwm = pos[gb], wm[gb]
@@ -2999,14 +3042,8 @@ def _iq_verify2_impl(entries, pos, wm, row_gid, indexable, trace_id,
     m2 = _mixb(list(key_parts2))
     lb1 = _bucket_of(m1, n_b)
     lb2 = _bucket_of(m2, n_b)
-    r1 = jax.lax.dynamic_slice(
-        entries, (jnp.int32(s_base) + lb1 * depth, jnp.int32(0)),
-        (depth, 3),
-    )
-    r2 = jax.lax.dynamic_slice(
-        entries, (jnp.int32(s_base) + lb2 * depth, jnp.int32(0)),
-        (depth, 3),
-    )
+    r1 = _arena_window(entries, jnp.int32(s_base) + lb1 * depth, depth)
+    r2 = _arena_window(entries, jnp.int32(s_base) + lb2 * depth, depth)
     row = jnp.concatenate([r1, r2])
     gb1 = jnp.int32(b_base) + lb1
     gb2 = jnp.int32(b_base) + lb2
@@ -3073,11 +3110,11 @@ def _iq_multi_impl(entries, pos, wm, row_gid, indexable, trace_id,
     slot0 = s_base + lb * depth.astype(jnp.int64)
     rows = jnp.arange(k_max, dtype=jnp.int64)[None, :]
     valid_row = rows < depth[:, None]
-    idx = jnp.where(valid_row, slot0[:, None] + rows, entries.shape[0])
-    eg = entries[jnp.clip(idx, 0, entries.shape[0] - 1)]  # [N, Kmax, 3]
+    idx = jnp.clip(slot0[:, None] + rows, 0, entries[0].shape[0] - 1)
     exp_ver = jnp.where(is_svc, key1.astype(jnp.int64), _verify_of(mixed))
-    ver_ok = valid_row & (eg[:, :, 1] == exp_ver[:, None])
-    gid = eg[:, :, 0]
+    ver_ok = valid_row & (
+        _arena_col(entries, idx, 1) == exp_ver[:, None])   # [N, Kmax]
+    gid = _arena_col(entries, idx, 0)
     slot = jnp.clip((gid % capacity).astype(jnp.int32), 0, capacity - 1)
     live = (gid >= 0) & (row_gid[slot] == gid)
     ok = live & indexable[slot] & ver_ok
@@ -3223,9 +3260,9 @@ def _iq_durations_impl(entries, pos, wm, trace_id, row_gid, ts_first,
     qb = jnp.int32(b_base) + lb
     rows = (jnp.int32(s_base) + lb[:, None] * depth
             + jnp.arange(depth, dtype=jnp.int32)[None, :])
-    # Unified arena rows are (gid, verify, ts) triples; the gid column
-    # rides the contiguous [n, 3] row gather (the cheap shape class).
-    gid = entries[rows.reshape(-1), 0].reshape(nq, depth)
+    # Unified arena rows are (gid, verify, ts) triples; only the gid
+    # column's two word planes are read.
+    gid = _arena_col(entries, rows, 0)
     slot = jnp.clip((gid % capacity).astype(jnp.int32), 0, capacity - 1)
     live = (gid >= 0) & (row_gid[slot] == gid)
     match = live & (trace_id[slot] == sorted_qids[:, None])
@@ -3281,7 +3318,7 @@ def _iq_gather_impl(
         qb = jnp.int32(b_base) + lb
         rows = (jnp.int32(s_base) + lb[:, None] * depth
                 + jnp.arange(depth, dtype=jnp.int32)[None, :])
-        gid = tr_entries[rows.reshape(-1), 0].reshape(nq, depth)
+        gid = _arena_col(tr_entries, rows, 0)
         gate = (tr_pos[qb] <= depth) | (tr_wm[qb] < ring_wp - ring_cap)
         return gid, gate.all()
 
