@@ -37,12 +37,15 @@ def journal_fsyncs(path: str) -> None:
     import time
 
     real = os.fsync
-    out = open(path, "a", buffering=1)
+    # one write a line to a file opened for appending: lines stay whole
+    # however many group-commit threads fsync at once
+    out = os.open(path, os.O_WRONLY | os.O_APPEND | os.O_CREAT, 0o644)
 
     def fsync(fd):
         st = os.fstat(fd)
         real(fd)
-        out.write(f"{time.monotonic():.6f} {st.st_ino} {st.st_size}\n")
+        os.write(out, f"{time.monotonic():.6f} {st.st_ino} "
+                      f"{st.st_size}\n".encode())
 
     os.fsync = fsync
 

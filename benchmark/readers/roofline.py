@@ -23,7 +23,10 @@ def index_rows(spans: int, anns: int, banns: int, services_per_span: int,
 def ingest_step(traffic: dict) -> float:
     """Least bytes of one fused ingest step of one ``Log`` call: the
     batch's columns read, the same rows written to the rings, and each
-    index row it touches read and written once."""
+    index row it touches read and written once. They are the bytes of
+    the whole call wherever its rows land: a launch that spreads the
+    call's spans over n chips moves these bytes once among them, not
+    once a chip, and has n chips' bandwidth to do it with."""
     s = traffic["call_spans"]
     a = s * traffic["annotations_per_span"]
     b = s * traffic["binary_per_span"]

@@ -6,6 +6,10 @@ Modules`` holds one event per executed program, named
 ``<module>(<fingerprint>)``, and ``XLA Ops`` one per operation. Busy
 time is the union of the operation intervals (the module intervals
 where a trace has no operation line), averaged over the device planes.
+Every number is reckoned per chip: a program that runs on n chips at
+once (one launch, one event on every plane) counts as one run of the
+mean plane's length, and seconds are the mean plane's. One plane in,
+the numbers are that plane's.
 A metric of ``{"reader": "trace"}`` picks a ``stat`` below.
 """
 
@@ -70,20 +74,26 @@ class Trace:
                 if any(r.search(e[0]) for r in rx)]
 
     def module_s(self, patterns) -> float:
+        """Seconds these programs ran, on the mean plane."""
         n = max(1, len(self.planes))
         return sum(d for _, _, d in self.module_events(patterns)) / 1e9 / n
 
     def breakdown(self) -> dict:
+        """Seconds per plane (the mean over the device planes, as
+        ``busy_s`` is), by operation and by the program an idle gap
+        came after: on four chips an operation's seconds are what one
+        chip spent in it, not four chips' sum."""
         ops, gaps = {}, {}
+        n = len(self.planes)
         for p in self.planes.values():
             for name, _, d in (p["ops"] or p["modules"]):
                 name = name[:160]
-                ops[name] = ops.get(name, 0.0) + d / 1e9
+                ops[name] = ops.get(name, 0.0) + d / 1e9 / n
             mods = sorted(p["modules"], key=lambda e: e[1])
             for (n0, s0, d0), (_, s1, _) in zip(mods, mods[1:]):
                 if s1 > s0 + d0:
                     key = f"after {n0}"
-                    gaps[key] = gaps.get(key, 0.0) + (s1 - s0 - d0) / 1e9
+                    gaps[key] = gaps.get(key, 0.0) + (s1 - s0 - d0) / 1e9 / n
         return {"device_ops": _top10(ops), "idle_gaps": _top10(gaps)}
 
 
@@ -168,7 +178,7 @@ def read(spec: dict, ctx: dict):
     events = trace.module_events(spec["patterns"])
     if not events:
         return None
-    seconds = trace.module_s(spec["patterns"])
+    seconds = trace.module_s(spec["patterns"])  # on the mean plane
     if stat == "module_ms_per_unit":
         # device ms of these programs per unit of work (a read, a
         # thousand spans): the units the traced window held are the whole
@@ -180,13 +190,17 @@ def read(spec: dict, ctx: dict):
             return None
         units = count * trace.window_s / whole / spec.get("per_unit", 1.0)
         return 1e3 * seconds / units
+    n_planes = len(trace.planes)
     if stat == "module_ms_per_event_unit":
-        # device ms per run of these programs, times runs per unit over
-        # the whole window (from the program's own launch counter)
+        # device ms of one run of these programs on one chip (every
+        # plane's events over every plane's seconds), times runs per
+        # unit over the whole window (from the program's own launch
+        # counter: a launch over n chips is one run)
         runs_per_unit = prom_delta.read(spec["events_per_unit"], ctx)
         if runs_per_unit is None:
             return None
-        return 1e3 * seconds / len(events) * runs_per_unit
+        total_s = sum(d for _, _, d in events) / 1e9
+        return 1e3 * total_s / len(events) * runs_per_unit
     if stat == "hbm_roofline_pct":
         with open(os.path.join(os.path.dirname(
                 os.path.abspath(__file__)), "peaks.json")) as f:
@@ -194,7 +208,10 @@ def read(spec: dict, ctx: dict):
         kind = ctx["device_kind"]
         if kind not in peaks:
             raise KeyError(f"device kind {kind!r} is not in peaks.json")
-        need = getattr(roofline, spec["bytes"])(ctx["traffic"]) * len(events)
-        least_s = need / peaks[kind]["hbm_bytes_per_s"]
-        return 100.0 * least_s / (seconds * max(1, len(trace.planes)))
+        # the bytes once per launch (a launch is one event on every
+        # plane), over all the planes' peak and the launch's seconds
+        need = (getattr(roofline, spec["bytes"])(ctx["traffic"])
+                * len(events) / n_planes)
+        least_s = need / (peaks[kind]["hbm_bytes_per_s"] * n_planes)
+        return 100.0 * least_s / seconds
     raise ValueError(f"unknown trace stat {stat!r}")

@@ -12,7 +12,10 @@ whole, while it is among the newest ``retained`` acked spans (the
 deployment's rings keep the newest rows and overwrite the oldest: a
 span older than that may be gone, in part or whole, and nothing is
 asked of it); what the daemon keeps for all time (service and span
-names, dependency links) counts every acked span.
+names, dependency links) counts every acked span. A deployment of n
+shards keeps n sets of rings that evict apart: each shard holds whole
+the newest ``retained`` / n acked spans of the traces it owns
+(``shard_of``).
 Answers are compared in a canonical order (spans by id, annotations by
 (timestamp, value), binary annotations by key): order inside a trace is
 not part of the guarantee.
@@ -29,11 +32,22 @@ def hex_id(x: int) -> str:
     return f"{int(x) & (2**64 - 1):x}"
 
 
+def shard_of(trace_id, n_shards: int):
+    """The shard that owns each trace, ids as signed 64-bit numbers:
+    (id x 0x9E3779B97F4A7C15) mod n in whole numbers, never negative,
+    as ``zipkin_tpu/parallel/multihost.py:shard_of`` routes. A product
+    mod n is the product of the factors mod n, so nothing overflows."""
+    t = np.asarray(trace_id, np.int64)
+    return (t % n_shards) * (0x9E3779B97F4A7C15 % n_shards) % n_shards
+
+
 class Reference:
-    def __init__(self, stream, acked_frames, retained: int = None):
+    def __init__(self, stream, acked_frames, retained: int = None,
+                 shards: int = 1):
         """``acked_frames``: numbers of the frames acked OK, any order.
         ``retained``: how many of the newest acked spans the deployment
-        holds whole (None: all of them)."""
+        holds whole (None: all of them), ``shards`` sets of rings
+        together, each an equal part of it."""
         self.stream, self.pool = stream, stream.pool
         c = stream.call_spans
         frames = np.unique(np.asarray(list(acked_frames), np.int64))
@@ -45,9 +59,24 @@ class Reference:
                              else 0, bool)
         self.mask[self.pos] = True
         n = len(self.pos)
-        # whole frames: a trace is cut only where a call's edge cuts it
-        self.first_retained = 0 if retained is None else max(
-            0, n - (retained // c) * c)
+        # Each shard holds the newest spans of its own traces, from a
+        # call's edge on: a trace is cut only where a call's edge cuts
+        # it. ``held_from[s]`` is shard s's first acked span held, as an
+        # index into the acked spans, ``held`` says of each acked span
+        # whether its shard holds it, and from ``first_retained`` on
+        # every shard holds all of its spans.
+        self.shards = shards
+        self.held_from = np.zeros(shards, np.int64)
+        salts = np.asarray(stream.salts, np.int64)[self.k]
+        owner = shard_of(self.pool.trace_id[self.i] ^ salts, shards)
+        if retained is not None:
+            for s in range(shards):
+                mine = np.flatnonzero(owner == s)
+                older = len(mine) - retained // shards
+                if older > 0:
+                    self.held_from[s] = -(-int(mine[older]) // c) * c
+        self.held = np.arange(n) >= self.held_from[owner]
+        self.first_retained = int(self.held_from.max())
 
     def n_spans(self) -> int:
         return len(self.pos)
@@ -112,9 +141,13 @@ class Reference:
 
     def _before_retained(self, trace_id: int) -> bool:
         """Whether any acked span of the trace is older than the spans
-        held whole."""
+        that its shard holds whole."""
         p = self.pool
-        cut = int(self.pos[self.first_retained])
+        first = int(self.held_from[shard_of(trace_id, self.shards)])
+        # a shard whose first held span lay in the last acked call holds
+        # nothing from a call's edge on: every acked span is older
+        cut = int(self.pos[first]) if first < len(self.pos) \
+            else int(self.pos[-1]) + 1
         for k, salt in enumerate(self.stream.salts):
             idx = np.flatnonzero(p.trace_id == (trace_id ^ salt))
             if len(idx) and k * p.n + int(idx[0]) < cut:
@@ -179,7 +212,7 @@ class Reference:
 
     def longest_trace(self) -> int:
         """The longest among the traces still held whole."""
-        r = slice(self.first_retained, None)
+        r = self.held
         sizes = np.bincount(self.pool.trace_idx[self.i[r]]
                             + self.k[r] * self.pool.n_traces)
         key = int(np.argmax(sizes))

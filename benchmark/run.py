@@ -12,6 +12,9 @@ metrics are files found by the names in BENCHMARK.json.
 
 ``--platform cpu --capacity N`` is the rehearsal (README.md): the child
 on the CPU at a small ring; the last line then says ``cpu``.
+``--benchmark-file PATH`` lays another file's keys over the root file's
+(the fixture of tests/sharded/ brings its own ``configs`` and
+``workloads``); the driver passes none of these.
 """
 
 from __future__ import annotations
@@ -146,6 +149,12 @@ def end_to_end(ingest, reads, w0: float, t_end: float, setup_s: float,
         lat = [(r[3] - (r[1] if r[1] is not None else r[2])) * 1e3
                for r in done]
         out["ack_p95_ms"] = (percentile(lat, 0.95), "ms")
+        # The connections queue on one device step, so the latencies
+        # stand on rungs a step apart and the 95th percentile can fall
+        # in the thin part between two (PERF.md 2): its neighbours are
+        # printed beside it.
+        say("ack latency percentiles 90..99 ms: " + " ".join(
+            f"{percentile(lat, q / 100):.1f}" for q in range(90, 100)))
         work_s = max(r[3] for r in done) - w0
         out["acked_spans_per_s"] = (len(done) * call_spans / work_s,
                                     "spans/s")
@@ -211,6 +220,21 @@ def ring_fill(scrape: dict) -> str:
         and ("ring_occupancy" in k or "ring_laps" in k))
 
 
+def seconds_split(before: dict, after: dict) -> str:
+    """Every timing sketch of /metrics that moved over the window
+    (``obs.stage``'s and the layers' own), most seconds first, as
+    ``name seconds/runs``: where a call's time went, for the run's log."""
+    rows = []
+    for k, v in after.items():
+        if "_seconds_sum" in k and v > before.get(k, 0.0):
+            n = k.replace("_seconds_sum", "_seconds_count")
+            rows.append((v - before.get(k, 0.0), k.replace(
+                "_seconds_sum", "").replace("zipkin_", ""),
+                after.get(n, 0.0) - before.get(n, 0.0)))
+    return ", ".join(f"{k} {d:.3f}s/{int(n)}"
+                     for d, k, n in sorted(rows, reverse=True))
+
+
 def reports(metric: dict, bench: dict, workload: str) -> bool:
     """Whether this cell reports the metric: the cells its entry lists
     or, where it lists none, every cell that reports the end-to-end
@@ -224,40 +248,59 @@ def reports(metric: dict, bench: dict, workload: str) -> bool:
     return reports(moved, bench, workload)
 
 
-def lap_spans(config: dict, traffic: dict, capacity: int) -> int:
-    """Spans of this traffic that fill the ring that fills first."""
+def shards_of(flags: list) -> int:
+    """How many sets of rings the daemon keeps: its ``--shards``, else 1."""
+    return int(flags[flags.index("--shards") + 1]) if "--shards" in flags \
+        else 1
+
+
+def lap_spans(config: dict, traffic: dict, capacity: int,
+              shards: int = 1) -> int:
+    """Spans of this traffic that fill the ring that fills first, in
+    every shard: ``--capacity`` sizes each shard's rings, and the
+    traces spread evenly over the shards."""
     per_span = {"span": 1, "annotation": traffic["annotations_per_span"],
                 "binary": traffic["binary_per_span"]}
-    return min(capacity * rows // per_span[ring]
-               for ring, rows in config["ring_rows_per_capacity_row"].items())
+    return shards * min(
+        capacity * rows // per_span[ring]
+        for ring, rows in config["ring_rows_per_capacity_row"].items())
+
+
+def retained_spans(config: dict, traffic: dict, lap: int) -> int:
+    """Held to be whole: the configuration's share of a lap (the rest is
+    room for the daemon's own self-trace rows), less the calls that
+    can be in flight at once, which may be committed out of send order."""
+    return (int(config["retained_whole_share"] * lap)
+            - traffic["ingest"]["connections"] * traffic["call_spans"])
 
 
 def run_cell(args) -> dict:
     bench = load_json("BENCHMARK.json")
+    if args.benchmark_file:
+        bench.update(load_json(args.benchmark_file))
     cell = next((w for w in bench["workloads"]
                  if w["name"] == args.workload), None)
     if cell is None:
-        raise SystemExit(f"no workload {args.workload!r} in BENCHMARK.json")
-    config = load_json(os.path.join(
-        bench["paths"][0], "configs", cell["config"] + ".json"))
+        raise SystemExit(f"no workload {args.workload!r} in "
+                         + (args.benchmark_file or "BENCHMARK.json"))
+    config = load_json(next(c["file"] for c in bench["configs"]
+                            if c["name"] == cell["config"]))
     traffic = load_json(os.path.join(
         bench["paths"][0], "traffic", cell["traffic"] + ".json"))
     flags = list(config["daemon_flags"])
     if args.capacity:
         flags[flags.index("--capacity") + 1] = str(args.capacity)
+    shards = shards_of(flags)
     platform = args.platform or config["platform"]
     seconds = float(args.seconds)
     c = traffic["call_spans"]
     # The window runs on full rings: the pre-fill is as many laps of the
     # ring that fills first as the traffic file says, in whole calls.
-    lap = lap_spans(config, traffic, int(flags[flags.index("--capacity") + 1]))
+    lap = lap_spans(config, traffic,
+                    int(flags[flags.index("--capacity") + 1]), shards)
     n_prefill = math.ceil(traffic["prefill_laps"] * lap / c)
     ing_spec, rd_spec = traffic["ingest"], traffic.get("reads")
-    # Held to be whole: the configuration's share of a lap (the rest is
-    # room for the daemon's own self-trace rows), less the calls that
-    # can be in flight at once, which may be committed out of send order.
-    retained = (int(config["retained_whole_share"] * lap)
-                - ing_spec["connections"] * c)
+    retained = retained_spans(config, traffic, lap)
     # where a control drops a call, it drops one of the window's
     os.environ.setdefault("BENCH_FAULT_AT", str(n_prefill + 6))
 
@@ -311,7 +354,7 @@ def run_cell(args) -> dict:
             if any(not r[5] for r in ingest.records):
                 raise RuntimeError("a pre-fill Log call was never acked")
             ack_time = {r[0]: r[3] for r in ingest.records}
-            ref = Reference(stream, ack_time, retained)
+            ref = Reference(stream, ack_time, retained, shards)
             _, never = wait_visible(daemon, ref, sorted(ack_time)[-ing_spec[
                 "connections"]:], deadline_s=600.0)
             if never:
@@ -381,6 +424,8 @@ def run_cell(args) -> dict:
         say(f"window closed: {len(ingest.records)} calls, try_later "
             f"{ingest.try_later}; rings " + ring_fill(after)
             + (f", {len(reads.records)} reads" if reads else ""))
+        say("seconds by sketch over the window: "
+            + seconds_split(before, after))
         acks = sorted(r[3] - w0 for r in ingest.records if r[5])
         say("acks by second of the window: " + " ".join(
             str(sum(1 for a in acks if k <= a < k + 1))
@@ -407,7 +452,7 @@ def run_cell(args) -> dict:
 
         # -- the comparison that decides `correct` ---------------------------
         ack_time.update({r[0]: r[3] for r in ingest.records if r[5]})
-        ref = Reference(stream, ack_time, retained)
+        ref = Reference(stream, ack_time, retained, shards)
         newest = sorted(r[0] for r in ingest.records if r[5])[
             -ing_spec["connections"]:]
         lag, never = wait_visible(daemon, ref, newest or sorted(ack_time)[-1:])
@@ -443,6 +488,7 @@ def run_cell(args) -> dict:
             stream.close()
 
     mem = daemon.memory_report()
+    say(f"memory peak by device: {mem.get('per_device')}")
     dev = {"platform": device["platform"], "kind": device["kind"],
            "count": device["count"],
            "memory_peak_bytes": mem.get("memory_peak_bytes", 0),
@@ -501,6 +547,9 @@ def main(argv=None) -> int:
     # the rehearsal; the driver passes neither
     p.add_argument("--platform", choices=("cpu", "tpu"), default=None)
     p.add_argument("--capacity", type=int, default=0)
+    p.add_argument("--benchmark-file", default="",
+                   help="a file whose keys stand in for BENCHMARK.json's, "
+                        "relative to the checkout's root (fixtures only)")
     p.add_argument("--fault", default="",
                    help="plant a fault of tests/faults.py in the daemon "
                         "(controls and tests only)")

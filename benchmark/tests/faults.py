@@ -9,6 +9,10 @@ something cheaper than the whole truth.
   (the durability / read-your-acks guarantee).
 - ``ack_before_fsync``: a Log call is acked once appended, without
   waiting for the group commit's fsync (durability).
+- ``ack_on_epoch_only``: on a sharded daemon the ack waits for the
+  fsync of the epoch log, the group commit's record, and not for the
+  shard logs' that hold the spans: the tempting shortcut of the sharded
+  barrier (durability; no effect on a single log).
 - ``stored_then_pushed_back``: one Log call of the window is stored and
   then answered TRY_LATER, as the program itself does where an ack's
   wait for the fsync times out; the client resends it, so it is stored
@@ -30,7 +34,8 @@ def plant(name: str) -> None:
     {"lost_write": _lost_write, "ack_before_fsync": _ack_before_fsync,
      "stored_then_pushed_back": _stored_then_pushed_back,
      "prefill_pushed_back": _prefill_pushed_back,
-     "not_whole": _not_whole, "stale_query": _stale_query}[name]()
+     "not_whole": _not_whole, "stale_query": _stale_query,
+     "ack_on_epoch_only": _ack_on_epoch_only}[name]()
 
 
 def _lost_write() -> None:
@@ -85,6 +90,13 @@ def _ack_before_fsync() -> None:
     from zipkin_tpu.ingest.collector import Collector
 
     Collector._wal_barrier = lambda self: None
+
+
+def _ack_on_epoch_only() -> None:
+    from zipkin_tpu.wal.sharded import ShardedWal
+
+    ShardedWal.wait_durable = (
+        lambda self, seq, timeout=30.0: self.epoch.wait_durable(seq, timeout))
 
 
 def _not_whole() -> None:

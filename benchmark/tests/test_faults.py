@@ -4,7 +4,9 @@
 Drives run.py end to end with the child on the CPU at a small ring (the
 harness's look for a chip is skipped by ``--platform cpu``); the daemon
 is the program itself with one of faults.py's broken guarantees planted.
-About a minute a case, on rings that lap (2^18 rows). Run by hand:
+About a minute a case, on rings that lap (2^18 rows). The sharded cases
+drive the fixture cell of ``sharded/`` on four forced CPU devices, four
+shards of 2^17 rows, about two minutes a case. Run by hand:
 
     python -m pytest benchmark/tests/test_faults.py -q -p no:cacheprovider
 """
@@ -25,12 +27,13 @@ def last_line(r) -> dict:
 
 
 def run_cell(workload: str, fault: str, env: dict = None,
-             want_rc: int = None):
+             want_rc: int = None, capacity: int = 262144, more=()):
     """The result line of a run that exits 0 or, where ``want_rc`` is
     given, the finished process itself."""
     cmd = [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
            "--workload", workload, "--seed", "2147483659", "--seconds", "2",
-           "--trace", "0", "--platform", "cpu", "--capacity", "262144"]
+           "--trace", "0", "--platform", "cpu", "--capacity", str(capacity),
+           *more]
     if fault:
         cmd += ["--fault", fault]
     r = subprocess.run(cmd, cwd=ROOT, capture_output=True, text=True,
@@ -92,3 +95,29 @@ def test_prefills_pushed_back_in_a_row_fail_the_run():
     r = run_cell(first_cell("closed"), "prefill_pushed_back", want_rc=1)
     assert "pre-fills in a row were pushed back" in r.stderr
     assert not r.stdout.strip()
+
+
+SHARDED = os.path.join("benchmark", "tests", "sharded", "BENCHMARK.json")
+
+
+@pytest.mark.parametrize("fault,numbers", [
+    ("", ()),
+    ("ack_on_epoch_only", ("acks_before_durable",)),
+    ("lost_write", ("acked_spans_not_in_wal", "dependency_calls_off")),
+])
+def test_a_fault_planted_in_the_sharded_daemon_reads_not_correct(
+        fault, numbers):
+    """The fixture cell: four shards over four forced CPU devices (the
+    daemon child inherits XLA_FLAGS), rings that lap, the log a tree."""
+    with open(os.path.join(ROOT, SHARDED)) as f:
+        cell = json.load(f)["workloads"][0]
+    assert cell["chips"] == 4
+    line = run_cell(
+        cell["name"], fault,
+        {"XLA_FLAGS": "--xla_force_host_platform_device_count=4"},
+        capacity=131072, more=("--benchmark-file", SHARDED))
+    assert line["device"]["platform"] == "cpu"
+    assert line["device"]["count"] == 4
+    over = {k for k, v in line["compared"].items() if v["value"] > v["limit"]}
+    assert line["correct"] is (not fault)
+    assert over >= set(numbers) and bool(over) == bool(fault)

@@ -137,3 +137,69 @@ def test_reduce_planes_keeps_device_planes_and_strips_fingerprints():
     assert abs(t.window_s - 240e-9) < 1e-15     # the device plane's span
     D.planes = D.planes[:1]
     assert trace_reduce.reduce_planes(D) is None  # a CPU rehearsal
+
+
+def test_four_planes_are_reckoned_per_chip():
+    """A launch over four chips is one event on every plane. The time of
+    a run is one chip's; the bytes of a ``Log`` call are moved once a
+    launch with four chips' bandwidth, so four planes that each take as
+    long as the one read a quarter of its roofline share. One plane in,
+    the floats are those of the single-device arithmetic, to the bit."""
+    steps = [("jit_ingest_step", 1_000 + 50_000 * i, 30_000 + 1_000 * i)
+             for i in range(5)]
+    ops = [("fusion.1", s, d // 2) for _, s, d in steps]
+    plane = {"modules": steps + [("jit_other", 400_000, 7_000)], "ops": ops}
+    # the other chips start a little later and take a little longer
+    skew = [{k: [(n, s + 100 * c, d + 10 * c) for n, s, d in v]
+             for k, v in plane.items()} for c in range(4)]
+    one = trace_reduce.Trace({"/device:TPU:0": skew[0]})
+    four = trace_reduce.Trace(
+        {f"/device:TPU:{c}": skew[c] for c in range(4)})
+    traffic = {"call_spans": 2048, "annotations_per_span": 6,
+               "binary_per_span": 2, "services_per_span": 2,
+               "indexed_annotations_per_span": 2}
+    spec_ms = {"stat": "module_ms_per_event_unit",
+               "patterns": ["^jit_ingest_step"],
+               "events_per_unit": {"num": [{"prom": "launches"}],
+                                   "den": [{"client": "acked_spans"}],
+                                   "scale": 1000.0}}
+    spec_roof = {"stat": "hbm_roofline_pct", "bytes": "ingest_step",
+                 "patterns": ["^jit_ingest_step"]}
+
+    def read(trace, spec):
+        return trace_reduce.read(spec, {
+            "trace": trace, "before": {"launches": 0.0},
+            "after": {"launches": 5.0}, "client": {"acked_spans": 10240},
+            "device_kind": "TPU v5 lite", "traffic": traffic})
+
+    durs = [d for _, _, d in steps]
+    seconds = sum(durs) / 1e9 / 1
+    launches_per_kspan = 1000.0 * 5.0 / 10240
+    # one plane: the single-device formulas as they stood, bit for bit
+    assert read(one, spec_ms) == 1e3 * seconds / 5 * launches_per_kspan
+    need = roofline.ingest_step(traffic) * 5
+    assert read(one, spec_roof) == 100.0 * (need / 819e9) / (seconds * 1)
+    # four planes: a run is the mean chip's 30 + 2 + 0.015 us
+    mean_run_s = (sum(durs) / 5 + 15) / 1e9
+    assert abs(read(four, spec_ms)
+               - 1e3 * mean_run_s * launches_per_kspan) < 1e-12
+    assert abs(read(four, spec_ms) / read(one, spec_ms)
+               - mean_run_s / (sum(durs) / 5e9)) < 1e-12
+    want = 100.0 * roofline.ingest_step(traffic) / (4 * 819e9) / mean_run_s
+    assert abs(read(four, spec_roof) - want) < 1e-12
+    # were all four planes the one plane, the time of a run would be the
+    # same and the share a quarter
+    same = trace_reduce.Trace(
+        {f"/device:TPU:{c}": skew[0] for c in range(4)})
+    assert abs(read(same, spec_ms) - read(one, spec_ms)) < 1e-12
+    assert abs(read(same, spec_roof) - read(one, spec_roof) / 4) < 1e-15
+    assert abs(same.busy_s - one.busy_s) < 1e-15
+    # the breakdown is per plane too: one chip's seconds in an operation
+    b1, b4 = one.breakdown(), same.breakdown()
+    assert b1["device_ops"][0][0] == b4["device_ops"][0][0] == "fusion.1"
+    assert abs(b4["device_ops"][0][1] - b1["device_ops"][0][1]) < 1e-15
+    assert abs(b4["idle_gaps"][0][1] - b1["idle_gaps"][0][1]) < 1e-15
+    acc = 0.0  # one plane: added up as it always was, to the bit
+    for d in durs:
+        acc = acc + d // 2 / 1e9
+    assert b1["device_ops"][0][1] == acc
