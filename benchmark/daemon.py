@@ -4,6 +4,10 @@ Copied from ``chip_smoke.py``'s ``Daemon`` (spawn, boot line, HTTP GET,
 /metrics scrape, SIGTERM) and made to take its flags from a
 configuration file. stdout/stderr go to files so a chatty child never
 blocks on a full pipe.
+
+The child cannot outlive the process that made it: ``daemon_entry.py``
+asks the kernel to kill it when this process dies, and it leads a
+process group of its own, which ``kill`` takes down whole.
 """
 
 from __future__ import annotations
@@ -49,6 +53,7 @@ class Daemon:
         self.mem_path = os.path.join(workdir, "device_memory.json")
         self.fsync_path = os.path.join(workdir, "fsyncs.txt")
         cmd = [sys.executable, os.path.join(HERE, "daemon_entry.py"),
+               "--parent-pid", str(os.getpid()),
                "--memory-report", self.mem_path,
                "--fsync-journal", self.fsync_path]
         if fault:
@@ -62,22 +67,27 @@ class Daemon:
         env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
         env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
         self.t_spawn = time.monotonic()
+        # Made on the caller's MAIN thread: the child's tie to this
+        # process (daemon_entry.die_with_parent) follows the thread that
+        # forked it. A group of its own, so that kill() reaches whatever
+        # the child started (its native.py can have a compiler running).
         with open(self.out_path, "wb") as out, \
                 open(self.err_path, "wb") as err:
             self.proc = subprocess.Popen(
                 cmd, cwd=ROOT, env=env, stdin=subprocess.DEVNULL,
-                stdout=out, stderr=err)
+                stdout=out, stderr=err, process_group=0)
 
-    def check_alive(self) -> None:
+    def check_alive(self, when: str = "early") -> None:
         rc = self.proc.poll()
         if rc is not None:
-            raise RuntimeError(f"daemon exited early with code {rc}")
+            raise RuntimeError(f"daemon exited {when} with code {rc}")
 
     def wait_boot(self, deadline_s: float) -> dict:
         """Block until the boot line; returns the device the store's
-        state lives on, as the daemon reported it."""
+        state lives on, as the daemon reported it. A daemon that died
+        and one that hangs read differently."""
         while time.monotonic() - self.t_spawn < deadline_s:
-            self.check_alive()
+            self.check_alive("before its boot line")
             with open(self.out_path, errors="replace") as f:
                 m = BOOT_RE.search(f.read())
             if m:
@@ -85,7 +95,9 @@ class Daemon:
                         "count": int(m.group(3)),
                         "state_bytes": int(m.group(4))}
             time.sleep(0.2)
-        raise TimeoutError(f"no boot line within {deadline_s:.0f}s")
+        raise TimeoutError(
+            f"no boot line within {deadline_s:.0f}s of the spawn: the "
+            f"daemon (pid {self.proc.pid}) is alive and silent")
 
     def request(self, method: str, path: str, params: dict = None,
                 timeout_s: float = 900.0):
@@ -122,12 +134,21 @@ class Daemon:
         return out
 
     def terminate(self, deadline_s: float) -> int:
+        """SIGTERM to the child alone, which saves and reports on its
+        way out; its exit code. What it may have left in its group goes
+        after it."""
         self.proc.send_signal(signal.SIGTERM)
-        return self.proc.wait(timeout=deadline_s)
+        rc = self.proc.wait(timeout=deadline_s)
+        self.kill()
+        return rc
 
     def kill(self) -> None:
-        if self.proc.poll() is None:
-            self.proc.kill()
+        """SIGKILL to the child's whole group, the child gone or not:
+        what it started may have outlived it."""
+        try:
+            os.killpg(self.proc.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
         self.proc.wait()
 
     def memory_report(self) -> dict:

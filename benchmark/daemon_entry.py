@@ -1,6 +1,8 @@
 """Entry of the daemon child: ``zipkin_tpu.main.example.main`` itself,
-with two things round it that only the process on the chip can do.
+with a few things round it that only the process on the chip can do.
 
+- First of all it ties its life to its parent's (``die_with_parent``):
+  however ``run.py`` ends, this process does not outlive it.
 - At exit it writes the device's memory statistics (the peak on the
   fullest chip) where the parent asked: the parent never touches JAX.
 - It keeps a journal of every ``os.fsync`` that returned (monotonic
@@ -14,8 +16,30 @@ with two things round it that only the process on the chip can do.
 from __future__ import annotations
 
 import argparse
+import ctypes
 import json
 import os
+import signal
+
+PR_SET_PDEATHSIG = 1  # <linux/prctl.h>
+
+
+def die_with_parent(parent_pid: int) -> None:
+    """The kernel kills this process when its parent dies, whatever the
+    parent died of: a SIGKILL runs no handler of the parent's, so the tie
+    is made from the child's side (and not in a ``preexec_fn``, which is
+    unsafe once ``run.py`` has threads). The signal is sent when the
+    THREAD that forked this process exits: ``run.py`` makes its ``Daemon``
+    on its main thread. A parent that died before the ``prctl`` took hold
+    left this process to another parent: ``getppid`` tells."""
+    libc = ctypes.CDLL(None, use_errno=True)
+    libc.prctl.argtypes = [ctypes.c_int, ctypes.c_ulong]
+    libc.prctl.restype = ctypes.c_int
+    if libc.prctl(PR_SET_PDEATHSIG, signal.SIGKILL) != 0:
+        raise OSError(ctypes.get_errno(), "prctl(PR_SET_PDEATHSIG)")
+    if os.getppid() != parent_pid:
+        raise SystemExit(f"the parent {parent_pid} is gone (now "
+                         f"{os.getppid()}): not starting")
 
 
 def write_memory_report(path: str) -> None:
@@ -52,11 +76,13 @@ def journal_fsyncs(path: str) -> None:
 
 def main() -> None:
     p = argparse.ArgumentParser()
+    p.add_argument("--parent-pid", type=int, required=True)
     p.add_argument("--memory-report", required=True)
     p.add_argument("--fsync-journal", required=True)
     p.add_argument("--fault", default="")
     p.add_argument("rest", nargs=argparse.REMAINDER)
     args = p.parse_args()
+    die_with_parent(args.parent_pid)
     rest = args.rest[1:] if args.rest[:1] == ["--"] else args.rest
     if args.fault:
         import importlib.util

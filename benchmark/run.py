@@ -14,7 +14,14 @@ metrics are files found by the names in BENCHMARK.json.
 on the CPU at a small ring; the last line then says ``cpu``.
 ``--benchmark-file PATH`` lays another file's keys over the root file's
 (the fixture of tests/sharded/ brings its own ``configs`` and
-``workloads``); the driver passes none of these.
+``workloads``; a traffic mix is looked for beside such a file first);
+the driver passes none of these.
+
+However a run ends, the daemon ends with it (README.md, "How a run
+ends"): SIGTERM, SIGHUP and SIGINT are raised on the main thread, so the
+one ``except`` below kills the daemon's group; a SIGKILL of this process
+kills the daemon through the kernel (``daemon_entry.die_with_parent``).
+And a set-up that cannot be done within ``SETUP_BUDGET_S`` stops itself.
 """
 
 from __future__ import annotations
@@ -25,6 +32,8 @@ import json
 import math
 import os
 import shutil
+import signal
+import statistics
 import subprocess
 import sys
 import tempfile
@@ -49,7 +58,32 @@ from reference import Reference, hex_id  # noqa: E402
 BOOT_DEADLINE_S = 600.0
 STOP_DEADLINE_S = 300.0
 MAX_FILLS = 3  # pre-fills tried before a run gives up (each a fresh daemon)
+# Process start -> window start, every pre-fill together. Above every
+# set-up that has passed (537 s, ledger PR 24; 569 s, the four-shard
+# fixture compiling, PR 27) and under what a compiling run may take
+# (1200 s) less the window (40 s) and the longest comparison and log
+# check on record (160 s + 3 s). README.md, "How a run ends".
+SETUP_BUDGET_S = 900.0
+PROJECT_ACKS = 32  # acks whose median period projects the pre-fill's end
+ENDING = (signal.SIGTERM, signal.SIGHUP, signal.SIGINT)
 T_START = time.monotonic()
+
+
+class Ended(SystemExit):
+    """This process was told to end: exit code 128 + the signal's number."""
+
+    def __init__(self, signum: int):
+        super().__init__(128 + signum)
+        self.signal = signal.Signals(signum).name
+
+
+def end_on(signum, frame) -> None:
+    """One exception on the main thread for every signal that asks the
+    run to end, so that ``run_cell``'s ``except`` kills the daemon; a
+    second signal does not cut that short."""
+    for s in ENDING:
+        signal.signal(s, signal.SIG_IGN)
+    raise Ended(signum)
 
 
 def say(msg: str) -> None:
@@ -62,20 +96,77 @@ def load_json(rel: str) -> dict:
         return json.load(f)
 
 
-def build_codec() -> None:
+def build_codec(deadline_s: float) -> None:
     """Build the native codec from native/span_codec.cc where the shared
     object is missing or older than the source (the program's own rule;
     a checkout has none, so its first run builds what git committed), in
-    a child, so that this process imports nothing of the program."""
+    a child, so that this process imports nothing of the program and
+    the daemon finds it built and starts no compiler of its own."""
     r = subprocess.run(
         [sys.executable, "-c",
          "from zipkin_tpu import native; native.build(); native.get_lib()"],
-        cwd=ROOT, capture_output=True, text=True,
+        cwd=ROOT, capture_output=True, text=True, timeout=deadline_s,
         env={**os.environ, "JAX_PLATFORMS": "cpu",
              "PYTHONPATH": ROOT + os.pathsep
              + os.environ.get("PYTHONPATH", "")})
     if r.returncode != 0:
         raise RuntimeError("native codec did not build:\n" + r.stderr[-2000:])
+
+
+def budget_left(budget_s: float, before: str) -> float:
+    """Seconds of the set-up's budget not yet spent: the deadline of the
+    set-up's next wait. A run past its budget stops."""
+    left = budget_s - (time.monotonic() - T_START)
+    if left <= 0:
+        raise TimeoutError(
+            f"the set-up budget of {budget_s:.0f}s was passed before "
+            f"{before}")
+    return left
+
+
+def projected_fill(acks: list, calls_left: int, now: float):
+    """(seconds a call, seconds into the run at which the pre-fill would
+    end), from the median period of the last ``PROJECT_ACKS`` acks: one
+    slow call (a compile) does not decide it, and acks that come in
+    bursts read too fast, never too slow. None before that many acks."""
+    if len(acks) < PROJECT_ACKS:
+        return None
+    last = sorted(acks)[-PROJECT_ACKS:]
+    period = statistics.median(b - a for a, b in zip(last, last[1:]))
+    return period, now - T_START + calls_left * period
+
+
+def fill(ingest: Ingest, first: int, n_calls: int, budget_s: float) -> None:
+    """Send the pre-fill and wait for its last ack, looking once a second
+    at the acks so far (the senders are not touched): a fill that would
+    end past the set-up's budget, or a budget already passed, stops the
+    run here and not at somebody else's ``kill``."""
+    c = ingest.stream.call_spans
+    ingest.run(first, first + n_calls)
+    said = False
+    for t in ingest.threads:
+        while t.is_alive():
+            t.join(1.0)
+            with ingest.lock:
+                acks = [r[3] for r in ingest.records]
+            now = time.monotonic()
+            seen = projected_fill(acks, n_calls - len(acks), now)
+            if seen is None:
+                budget_left(budget_s, f"the pre-fill's ack {PROJECT_ACKS}")
+                continue
+            period, end_s = seen
+            line = (f"{len(acks)} of {n_calls} pre-fill calls acked, "
+                    f"{c / max(period, 1e-9):.0f} spans/s by the median "
+                    f"period of the last {PROJECT_ACKS} acks: the fill "
+                    f"would end {end_s:.0f}s into the run; the set-up's "
+                    f"budget is {budget_s:.0f}s")
+            if end_s > budget_s:  # at the latest when the budget is passed
+                say("the set-up stops itself")
+                raise TimeoutError("the set-up stops itself: " + line)
+            if not said:
+                say(line)
+                said = True
+    ingest.join()
 
 
 def wait_visible(daemon, ref: Reference, frames: list,
@@ -285,8 +376,14 @@ def run_cell(args) -> dict:
                          + (args.benchmark_file or "BENCHMARK.json"))
     config = load_json(next(c["file"] for c in bench["configs"]
                             if c["name"] == cell["config"]))
-    traffic = load_json(os.path.join(
-        bench["paths"][0], "traffic", cell["traffic"] + ".json"))
+    traffic_file = os.path.join(
+        bench["paths"][0], "traffic", cell["traffic"] + ".json")
+    if args.benchmark_file:  # a fixture may bring a mix of its own
+        beside = os.path.join(os.path.dirname(args.benchmark_file),
+                              "traffic", cell["traffic"] + ".json")
+        if os.path.exists(os.path.join(ROOT, beside)):
+            traffic_file = beside
+    traffic = load_json(traffic_file)
     flags = list(config["daemon_flags"])
     if args.capacity:
         flags[flags.index("--capacity") + 1] = str(args.capacity)
@@ -303,8 +400,13 @@ def run_cell(args) -> dict:
     retained = retained_spans(config, traffic, lap)
     # where a control drops a call, it drops one of the window's
     os.environ.setdefault("BENCH_FAULT_AT", str(n_prefill + 6))
+    # Only a run with a fault planted (controls and tests) may have
+    # another budget, from the environment the faults are set by.
+    budget_s = float(os.environ.get("BENCH_FAULT_SETUP_BUDGET_S",
+                                    SETUP_BUDGET_S)
+                     if args.fault else SETUP_BUDGET_S)
 
-    build_codec()
+    build_codec(budget_left(budget_s, "the codec's build"))
     daemon = stream = None
     try:
         # -- boot, pre-fill and warm-up: this cell's shapes, through the
@@ -321,7 +423,9 @@ def run_cell(args) -> dict:
             workdir = tempfile.mkdtemp(prefix="bench_run_")
             daemon = Daemon(flags, platform, workdir, fault=args.fault)
             say(f"{args.workload} seed {args.seed} seconds {seconds} trace "
-                f"{args.trace}; daemon spawned; workdir {workdir}")
+                f"{args.trace}; daemon spawned pid {daemon.proc.pid} ports "
+                f"{daemon.http_port} {daemon.scribe_port}; workdir "
+                f"{workdir}")
             if stream is None:  # made while the daemon boots
                 t0 = time.monotonic()
                 # Three passes over the pool fit in what is held whole, so
@@ -335,7 +439,8 @@ def run_cell(args) -> dict:
                 say(f"stream: calls of {c} spans; one lap is {lap} spans, "
                     f"pre-fill {n_prefill} calls, held whole {retained}; "
                     f"pool made in {time.monotonic() - t0:.1f}s")
-            device = daemon.wait_boot(BOOT_DEADLINE_S)
+            device = daemon.wait_boot(min(BOOT_DEADLINE_S, budget_left(
+                budget_s, "the daemon's boot")))
             say(f"boot line after {time.monotonic() - daemon.t_spawn:.1f}s: "
                 f"{device}")
             if device["platform"] != platform:
@@ -349,14 +454,15 @@ def run_cell(args) -> dict:
             ingest = Ingest(daemon.scribe_port, stream, ing_spec,
                             daemon.check_alive)
             t0 = time.monotonic()
-            ingest.run(first, first + n_prefill)
-            ingest.join()
+            fill(ingest, first, n_prefill, budget_s)
             if any(not r[5] for r in ingest.records):
                 raise RuntimeError("a pre-fill Log call was never acked")
             ack_time = {r[0]: r[3] for r in ingest.records}
             ref = Reference(stream, ack_time, retained, shards)
-            _, never = wait_visible(daemon, ref, sorted(ack_time)[-ing_spec[
-                "connections"]:], deadline_s=600.0)
+            _, never = wait_visible(
+                daemon, ref, sorted(ack_time)[-ing_spec["connections"]:],
+                deadline_s=min(600.0, budget_left(
+                    budget_s, "the pre-fill's spans were readable")))
             if never:
                 raise RuntimeError(
                     "the pre-fill's spans never became readable")
@@ -374,7 +480,8 @@ def run_cell(args) -> dict:
                 f"{sorted(r[0] for r in ingest.records if r[4] > 1)} were "
                 f"resent; a fresh daemon is filled from call "
                 f"{first + n_prefill} on")
-            rc = daemon.terminate(STOP_DEADLINE_S)
+            rc = daemon.terminate(min(STOP_DEADLINE_S, budget_left(
+                budget_s, f"pre-fill {attempt + 1}")))
             if rc != 0:
                 raise RuntimeError(f"daemon exited {rc} on SIGTERM")
             shutil.rmtree(workdir, ignore_errors=True)
@@ -396,6 +503,7 @@ def run_cell(args) -> dict:
         # -- the window ----------------------------------------------------
         before = daemon.scrape()
         starved0 = stream.starved_s
+        budget_left(budget_s, "the window")
         setup_s = time.monotonic() - T_START
         say(f"window starts; setup_s {setup_s:.3f}; rings "
             + ring_fill(before))
@@ -474,7 +582,9 @@ def run_cell(args) -> dict:
             traffic["annotations_per_span"], traffic["binary_per_span"], say))
         say(f"the log held against the acks in "
             f"{time.monotonic() - t0:.1f}s")
-    except BaseException:
+    except BaseException as e:
+        if isinstance(e, Ended):
+            say(f"ended by {e.signal}")
         if daemon is not None:
             daemon.kill()
             sys.stderr.write("---- daemon stdout (tail) ----\n"
@@ -556,6 +666,9 @@ def main(argv=None) -> int:
     p.add_argument("--dump-trace", default="",
                    help="write a text summary of the trace's planes here")
     args = p.parse_args(argv)
+    for s in ENDING:  # but one that the caller had ignored stays ignored
+        if signal.getsignal(s) is not signal.SIG_IGN:
+            signal.signal(s, end_on)
     result = run_cell(args)
     if "jax" in sys.modules:
         from jax._src import xla_bridge

@@ -21,6 +21,12 @@ something cheaper than the whole truth.
   in the first daemon of a run only (the file ``BENCH_FAULT_MARK`` names
   is made when it fires; without the variable, in every daemon). This is
   no control: run.py has to see the push-back and fill a fresh daemon.
+- ``slow_store``: every durable Log call first waits
+  ``BENCH_FAULT_DELAY_S`` seconds, one call at a time, as a store whose
+  write path is slow under its lock. No control either: run.py has to
+  project the pre-fill's end and stop a run that cannot make its set-up
+  budget (``BENCH_FAULT_SETUP_BUDGET_S``, read only where a fault is
+  planted), and may not stop one that can.
 - ``not_whole``: a trace read drops the last annotation of one span
   (read back whole).
 - ``stale_query``: an index query leaves out the newest trace (exact
@@ -35,7 +41,8 @@ def plant(name: str) -> None:
      "stored_then_pushed_back": _stored_then_pushed_back,
      "prefill_pushed_back": _prefill_pushed_back,
      "not_whole": _not_whole, "stale_query": _stale_query,
-     "ack_on_epoch_only": _ack_on_epoch_only}[name]()
+     "ack_on_epoch_only": _ack_on_epoch_only,
+     "slow_store": _slow_store}[name]()
 
 
 def _lost_write() -> None:
@@ -84,6 +91,24 @@ def _prefill_pushed_back() -> None:
             return
         open(mark, "w").close()
     _push_back_after_storing(3)
+
+
+def _slow_store() -> None:
+    import threading
+    import time
+
+    from zipkin_tpu.ingest.collector import Collector
+
+    delay = float(os.environ["BENCH_FAULT_DELAY_S"])
+    turn = threading.Lock()
+    real = Collector.ingest_thrift_durable
+
+    def ingest_thrift_durable(self, payload):
+        with turn:
+            time.sleep(delay)
+        return real(self, payload)
+
+    Collector.ingest_thrift_durable = ingest_thrift_durable
 
 
 def _ack_before_fsync() -> None:
