@@ -414,6 +414,7 @@ def bench_tpu_stream(total_spans: int, capacity_log2: int = 22,
         # fit), so the record must say what actually ran.
         "rank_path": dev.active_paths(config).get("rank", ()),
         "scatter_path": dev.active_paths(config).get("scatter", ()),
+        "ring_write_path": dev.active_paths(config).get("ring_write", ()),
         # Per-stage telemetry: the device counter block (one fused
         # fetch — ring occupancy/laps, poison census, ingest counters)
         # rides the BENCH json so remote runs surface the same

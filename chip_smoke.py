@@ -361,6 +361,8 @@ def report_metrics(daemon: Daemon, shards: int) -> None:
         say("jit_compiles_total", int(m["zipkin_store_jit_compiles_total"]))
         say("rank_path_counting", int(counter("rank_path_counting")))
         say("scatter_path_pallas", int(counter("scatter_path_pallas")))
+        say("ring_write_window", int(counter("ring_write_window")))
+        say("ring_write_scatter", int(counter("ring_write_scatter")))
     say("ring_occupancy", int(counter("ring_occupancy")))
     say("wal_records_total", int(m["zipkin_wal_records_total"]))
     for k in range(shards):

@@ -38,7 +38,8 @@ def _count_ops(stablehlo_text: str) -> dict:
     r5 split-design baseline at the smoke shapes: 101 scatters /
     6 sorts / 80 gathers; the r6 unified arena shipped 95 / 5 / 79;
     the r12 counting-sort rank path shipped 95 / 4 / 79; the PR 26
-    arena planes ship 95 / 4 / 84 (ceilings
+    arena planes shipped 95 / 4 / 84; the PR 30 ring windows ship
+    54 / 4 / 84 (ceilings
     centralized in zipkin_tpu.store.census — the one place the tier-1
     gate reads them from). One shared counter (dev.
     stablehlo_op_census) backs this gate AND the runtime
@@ -667,6 +668,8 @@ def run_ingest_structure() -> dict:
             "rank", ()),
         "rank_path_counting": c_meas["rank_path_counting"],
         "scatter_path_pallas": c_meas["scatter_path_pallas"],
+        "ring_write_cfg": dev.active_paths(cfg_cnt).get("ring_write", ()),
+        "ring_write_window": c_meas["ring_write_window"],
         "batch_spans_geometries": [cfg_cnt.batch_spans,
                                    cfg_big.batch_spans],
         "escalated_batch_spans_limit": c_meas["batch_spans_limit"],
@@ -1562,7 +1565,7 @@ def run(total_spans: int = 7000, k_queries: int = 8) -> dict:
         max_binary_keys=128, cms_width=1 << 12, hll_p=8,
         quantile_buckets=512,
         # Pin the counting rank path: the op-count gate below is the
-        # COUNTING path's census (95/4/79 ceilings). "auto" would pick
+        # COUNTING path's census (census.LOWERING_TABLE). "auto" would pick
         # argsort on the CPU CI backend (backend-aware policy,
         # dev.rank_mode) and gate the wrong lowering.
         rank_path="counting",

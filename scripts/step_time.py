@@ -14,17 +14,111 @@ Prints one JSON line per capacity. ``ms_per_step`` is wall time over
 the chained steps; ``enqueue_ms_per_step`` is what the host needed to
 launch them (where the two are close, the host bound the run and
 ``ms_per_step`` is an upper bound of the device's).
+
+The batches have the shape of the benchmark's stream
+(``benchmark/gen.py``: 6 annotations a span on two hosts, 2 of them
+user annotations, and 2 binary annotations), so a launch carries 12,288
+valid annotation rows of 16,384 and 4,096 of 4,096 binary rows, as a
+served 2048-span ``Log`` call does.
+
+``--profile DIR`` captures ``--profile-steps`` more steps at each
+capacity with the JAX profiler and writes ``DIR/ops_<capacity>.json``:
+the device time of ``jit_ingest_step`` a run and of every device op
+summed by name (the name carries the result shape), longest first. Run
+it from another checkout's root to time that checkout's step.
 """
 
 import argparse
 import json
+import os
 import sys
+import tempfile
 import time
+
+import numpy as np
 
 sys.path.insert(0, ".")
 
 PADS = (2048, 16384, 4096)  # spans, annotations, binary annotations
 SPANS_PER_TRACE = 8
+
+
+def benchmark_shape(gen, n_traces):
+    """A ``ColumnarTraceGen`` batch (2 annotations and 1 binary
+    annotation a span) widened to the benchmark's rows a span: cs and cr
+    on the caller's host (the parent's service; a root calls itself),
+    sr, two user annotations and ss on the span's own; two binary
+    annotations on the span's own host."""
+    from zipkin_tpu.columnar.schema import SpanBatch
+
+    base, name_lc, indexable = gen.next_batch(n_traces)
+    n = base.n_spans
+    spt = gen.spans_per_trace
+    j = np.arange(n) % spt
+    caller = np.where(j > 0, np.arange(n) - j + (j - 1) // 2, np.arange(n))
+    ep_of = dict(zip(gen.service_ids.tolist(), gen.endpoint_ids.tolist()))
+    own_ep = base.ann_endpoint_id[0::2]
+    caller_svc = base.service_id[caller]
+    caller_ep = np.array([ep_of[s] for s in caller_svc.tolist()], np.int32)
+    word = gen.dicts.annotations.encode
+    user = np.array([word(f"word-{i:03d}") for i in range(256)], np.int32)
+    mid = base.ts_first + base.duration // 2
+    rows = (  # (ts, value id, service, endpoint) of a span's six rows
+        (base.ts_cs, 0, caller_svc, caller_ep),
+        (base.ts_sr, 2, base.service_id, own_ep),
+        (mid, user[gen.rng.integers(0, len(user), n)], base.service_id,
+         own_ep),
+        (mid + 1, gen.custom_ann_id, base.service_id, own_ep),
+        (base.ts_ss, 3, base.service_id, own_ep),
+        (base.ts_cr, 1, caller_svc, caller_ep),
+    )
+    wide = SpanBatch.empty(n, len(rows) * n, 2 * n)
+    for f in ("trace_id", "span_id", "parent_id", "name_id", "service_id",
+              "flags", "ts_cs", "ts_cr", "ts_sr", "ts_ss", "ts_first",
+              "ts_last", "duration"):
+        getattr(wide, f)[:] = getattr(base, f)
+    k = len(rows)
+    for i, (ts, value, svc, ep) in enumerate(rows):
+        wide.ann_span_idx[i::k] = np.arange(n)
+        wide.ann_ts[i::k] = ts
+        wide.ann_value_id[i::k] = value
+        wide.ann_service_id[i::k] = svc
+        wide.ann_endpoint_id[i::k] = ep
+    bkey = gen.dicts.binary_keys.encode
+    bval = gen.dicts.binary_values.encode
+    keys = np.array([bkey(f"key-{i:03d}") for i in range(256)], np.int32)
+    vals = np.array([bval(b"value-%03d" % i) for i in range(256)], np.int32)
+    for f in ("bann_span_idx", "bann_key_id", "bann_value_id", "bann_type",
+              "bann_service_id", "bann_endpoint_id"):
+        getattr(wide, f)[0::2] = getattr(base, f)
+        getattr(wide, f)[1::2] = getattr(base, f)
+    wide.bann_key_id[0::2] = keys[gen.rng.integers(0, len(keys), n)]
+    wide.bann_value_id[0::2] = vals[gen.rng.integers(0, len(vals), n)]
+    return wide, name_lc, indexable
+
+
+def ops_by_name(profile_dir):
+    """{"step_ms": [...], "ops": [[name, runs, seconds], ...]} of the
+    newest capture under ``profile_dir``: the device plane's
+    ``jit_ingest_step`` runs and every device op summed by name."""
+    from jax.profiler import ProfileData
+
+    from trace_stages import DEVICE_PLANE, newest_xplane  # scripts/
+
+    steps, ops = [], {}
+    for plane in ProfileData.from_file(newest_xplane(profile_dir)).planes:
+        if not DEVICE_PLANE.match(plane.name):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if line.name == "XLA Modules" and "ingest_step" in e.name:
+                    steps.append(e.duration_ns / 1e6)
+                elif line.name == "XLA Ops":
+                    runs, s = ops.get(e.name, (0, 0.0))
+                    ops[e.name] = (runs + 1, s + e.duration_ns / 1e9)
+    return {"step_ms": steps,
+            "ops": sorted(([k, r, s] for k, (r, s) in ops.items()),
+                          key=lambda row: -row[2])}
 
 
 def main():
@@ -34,6 +128,9 @@ def main():
     ap.add_argument("--batches", type=int, default=8,
                     help="distinct batches cycled through (new trace "
                          "ids each, so index buckets differ by step)")
+    ap.add_argument("--profile", default="",
+                    help="directory for ops_<capacity>.json")
+    ap.add_argument("--profile-steps", type=int, default=8)
     args = ap.parse_args()
 
     import jax
@@ -50,9 +147,11 @@ def main():
                            spans_per_trace=SPANS_PER_TRACE, topology=True)
     batches = [
         jax.device_put(dev.make_device_batch(
-            *gen.next_batch(PADS[0] // SPANS_PER_TRACE), *PADS))
+            *benchmark_shape(gen, PADS[0] // SPANS_PER_TRACE), *PADS))
         for _ in range(args.batches)
     ]
+    valid = {f: int(getattr(batches[0], f))
+             for f in ("n_spans", "n_anns", "n_banns")}
     for cap in (int(x) for x in args.capacity.split(",")):
         config = dev.StoreConfig(capacity=cap, **_side_rings(cap),
                                  window_seconds=60, window_buckets=64)
@@ -69,7 +168,8 @@ def main():
         jax.block_until_ready(state.write_pos)
         t2 = time.perf_counter()
         print(json.dumps({
-            "capacity": cap, "pads": PADS, "steps": args.steps,
+            "capacity": cap, "pads": PADS, "valid_rows": valid,
+            "steps": args.steps,
             "arena_slots": config.idx_layout[2],
             "ms_per_step": (t2 - t0) / args.steps * 1e3,
             "enqueue_ms_per_step": (t1 - t0) / args.steps * 1e3,
@@ -77,6 +177,22 @@ def main():
             "device": f"{d.platform} {d.device_kind}",
             "paths": dev.active_paths(config),
         }), flush=True)
+        if args.profile:
+            with tempfile.TemporaryDirectory() as raw:
+                jax.profiler.start_trace(raw)
+                for i in range(args.profile_steps):
+                    state = dev.ingest_step(state, batches[i % len(batches)])
+                jax.block_until_ready(state.write_pos)
+                jax.profiler.stop_trace()
+                table = ops_by_name(raw)
+            os.makedirs(args.profile, exist_ok=True)
+            with open(os.path.join(args.profile, f"ops_{cap}.json"),
+                      "w") as f:
+                json.dump({"capacity": cap, **table}, f)
+            print(json.dumps({
+                "capacity": cap, "profiled_steps": len(table["step_ms"]),
+                "device_ms_per_step": float(np.mean(table["step_ms"])),
+            }), flush=True)
         del state
 
 

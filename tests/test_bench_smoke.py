@@ -9,7 +9,7 @@ consumed here and by the smoke script, so a path change updates
 exactly one number (raise one only with a NOTES entry explaining what
 bought the extra launches). r5 split-design baseline: 101 scatters /
 6 sorts / 80 gathers; r6: 95/5/79; r12: 95/4/79; PR 26 (arena
-planes): 95/4/84.
+planes): 95/4/84; PR 30 (ring windows): 54/4/84.
 """
 
 import json
@@ -151,6 +151,10 @@ def test_bench_smoke_json_and_op_ceilings():
     assert (ing["census_counting"]["gather"]
             <= ing["census_argsort"]["gather"]), ing
     assert ing["rank_path_counting"] == 1.0, ing
+    # Every ring of the ring layout is written as a window (PR 30).
+    assert ing["ring_write_cfg"] == [
+        "ann:window", "bann:window", "pend:window", "span:window"], ing
+    assert ing["ring_write_window"] == 4.0, ing
     assert ing["recompiles_after_batch_escalation"] == 0, ing
     assert ing["escalated_batch_spans_limit"] == 512.0, ing
     assert ing["mirror_delta_ratio"] <= MAX_MIRROR_DELTA_RATIO, ing

@@ -22,6 +22,7 @@ that is handed it runs the fixture). Keep all such cases in THIS file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -116,26 +117,153 @@ def test_ingest_step_compiles(one_chip, rank_path):
     assert dev.active_paths(config)["rank"] == (rank_path,)
 
 
+# -- the rings are written in place (PR 30) ----------------------------------
+#
+# "Before the chip" of ISSUE 30 as a test: at the benchmark's geometry
+# (the daemon at --capacity 4194304, pads of a 2048-span Log call) the
+# optimized step holds no instruction of a ring's size but parameters,
+# views, window reads and in-place window writes. Two things of that
+# size are known and named: ``span_tab`` (hashed slots; an [H, 2] leaf
+# whose planes are sliced, scattered into and stacked back, with the
+# compiler's copies between memory spaces: exempt by its scope's name
+# and, for the copies, which carry no name, by its size) and the three
+# X64 custom calls an i64 leaf costs for any update at all
+# (X64SplitLow/High before, X64Combine after: 97 us a 2^22 column on
+# the chip, PERF.md 6, PR 30; the cure is the leaf in plane form).
+
+
+def _daemon_config(capacity):
+    from zipkin_tpu.main.example import _side_rings
+
+    return dev.StoreConfig(capacity=capacity, **_side_rings(capacity),
+                           window_seconds=60, window_buckets=64)
+
+
+_FREE = ("parameter", "bitcast", "get-tuple-element", "tuple",
+         "dynamic-slice", "dynamic-update-slice")
+_MOVES = ("copy-start", "copy-done", "slice-start", "slice-done",
+          "ConcatBitcast")
+# Scopes whose instructions of such a size are no ring's: span_tab's,
+# and the sketch update's one slice of 2^20 counters, which at the 2^22
+# ring happens to be the pending ring's size.
+_NOT_A_RING = ("ingest.span_table_insert", "ingest.sketch_update")
+_INSTRUCTION = re.compile(
+    r"^\s*(ROOT\s+)?%\S+ = (\(?[a-z0-9]+\[.*?) ([a-z][a-z0-9\-]*)\(")
+
+
+def _ring_sized(hlo: str, ring_dims, span_tab_rows: int):
+    """(offending, x64) instructions of an optimized HLO module with a
+    result dimension in ``ring_dims``: ``x64`` the X64 split/combine
+    custom calls, ``offending`` whatever is neither free (a parameter, a
+    view, a window read, an in-place window write alone or as a fusion's
+    root), nor ``span_tab``'s (its scope's name; a result of its
+    ``[H, 2]`` or ``[H, 1]`` form; the compiler's nameless moves of an
+    ``H``-row array between memory spaces), nor the one bool column's
+    (a byte a row: the sharded program copies it between memory spaces
+    and reduces it on its way out of ``shard_map``)."""
+    dims = {str(d) for d in ring_dims}
+    tab = str(span_tab_rows)
+    roots, comp, rows = {}, None, []
+    for line in hlo.splitlines():
+        if line.startswith(("%", "ENTRY")):
+            comp = line.split()[1 if line.startswith("ENTRY") else 0]
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        root, shape, op = m.groups()
+        if root:
+            roots[comp] = op
+        arrays = [(dt, g.split(",")) for dt, g in re.findall(
+            r"([a-z0-9]+)\[([0-9,]*)\]", shape) if dims & set(g.split(","))]
+        if arrays:
+            rows.append((op, arrays, line))
+    offending, x64 = [], []
+    for op, arrays, line in rows:
+        target = re.search(r'custom_call_target="([^"]+)"', line)
+        target = target.group(1) if target else ""
+        called = re.search(r"calls=(%[^,)\s]+)", line)
+        move = op in _MOVES or target in _MOVES
+        if op in _FREE or (op == "fusion" and roots.get(
+                called.group(1)) == "dynamic-update-slice"):
+            continue
+        if target.startswith("X64"):
+            x64.append(target)
+        elif any(scope in line for scope in _NOT_A_RING) or all(
+                d[-2:] in ([tab, "2"], [tab, "1"]) or (move and tab in d)
+                or dt == "pred" for dt, d in arrays):
+            continue
+        else:
+            offending.append(line.strip()[:200])
+    return offending, x64
+
+
+def _assert_rings_in_place(compiled, config, n_leaves, temp_limit):
+    text = compiled.as_text()
+    rings = {config.capacity, config.ann_capacity, config.bann_capacity,
+             config.pending_slots}
+    offending, x64 = _ring_sized(text, rings, config.tab_slots)
+    assert not offending, offending[:5]
+    # Three a column for the 18 i64 ring columns, and no more.
+    n_i64 = sum(
+        leaf.dtype == jnp.int64 and leaf.shape in {(d,) for d in rings}
+        for leaf in jax.tree_util.tree_leaves(
+            jax.eval_shape(lambda: dev.init_state(config))))
+    assert n_i64 == 18 and len(x64) <= 3 * n_i64, (n_i64, len(x64))
+    # The detector sees a pass when there is one.
+    seen, _ = _ring_sized(
+        "ENTRY %main (p: s32[8]) -> s32[8] {\n"
+        f"  %a = s32[{config.capacity}]{{0}} add(%p, %p)\n", rings, 0)
+    assert len(seen) == 1
+    header = text.split("\n", 1)[0]
+    assert len(re.findall(r"(?:may|must)-alias", header)) == n_leaves
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < temp_limit, mem
+    assert dev.active_paths(config)["ring_write"] == (
+        "ann:window", "bann:window", "pend:window", "span:window")
+
+
+def test_ingest_step_writes_rings_in_place(one_chip):
+    """The benchmark's step: ring 2^22, pads 2048/16384/4096. The
+    temporaries were 60,322,816 B with the rings scattered into (PR 26)
+    and 3.34 GB before the arena's planes."""
+    config = _daemon_config(1 << 22)
+    batch = dev.make_device_batch(
+        SpanBatch.empty(0, 0, 0), np.zeros(0, np.int32),
+        np.zeros(0, bool), 2048, 16384, 4096, error_flag=np.zeros(0, bool))
+    state = _state(config, one_chip)
+    compiled = _compiled_on_tpu(dev.ingest_step.lower(
+        state, _abstract(batch, one_chip)))
+    _assert_rings_in_place(
+        compiled, config, len(jax.tree_util.tree_leaves(state)), 80e6)
+
+
 def test_sharded_ingest_compiles_for_four_chips(topo):
     """``--shards 4``: the per-shard fused step plus its cross-shard
     summary (psum / pmax / all_gather) as ONE program over the 2x2
     mesh. The TPU lowers only SUM all-reduces over 64-bit types, so a
     64-bit pmax/pmin in the summary is refused here, not on the host
-    with four chips (parallel/shard._pmax64)."""
+    with four chips (parallel/shard._pmax64). At the four-shard
+    fixture's geometry (2^20 rows a shard, its pads), where each
+    shard's rings are written in place too."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from zipkin_tpu.parallel.shard import make_sharded_ingest
 
+    # (a pending ring of its own size: the daemon's 2^18 slots at this
+    # capacity are also the 512 x 512 links of the cross-shard summary)
+    config = _daemon_config(1 << 20)._replace(pend_slots=1 << 19)
     mesh = Mesh(np.array(topo.devices[:4]), ("shard",))
     sharded = NamedSharding(mesh, P("shard"))
     batch = dev.make_device_batch(
         SpanBatch.empty(0, 0, 0), np.zeros(0, np.int32),
-        np.zeros(0, bool), 512, 1024, 512, error_flag=np.zeros(0, bool))
+        np.zeros(0, bool), 1024, 4096, 2048, error_flag=np.zeros(0, bool))
+    state = _abstract(jax.eval_shape(lambda: dev.init_state(config)),
+                      sharded, lead=(4,))
     compiled = _compiled_on_tpu(make_sharded_ingest(mesh).lower(
-        _abstract(jax.eval_shape(lambda: dev.init_state(CONFIG)),
-                  sharded, lead=(4,)),
-        _abstract(batch, sharded, lead=(4,))))
+        state, _abstract(batch, sharded, lead=(4,))))
     assert "all-reduce" in compiled.as_text()
+    _assert_rings_in_place(
+        compiled, config, len(jax.tree_util.tree_leaves(state)), 80e6)
 
 
 def test_index_read_compiles(one_chip):

@@ -61,6 +61,20 @@ History of the measured counts at the smoke shapes:
   below) are gone, and with them 3.3 GB of step temporaries at the
   2^22 ring (chipless compile, PR 26: 60 MB).
 
+- PR 30 ring windows:     -41 scatters / +0 sorts / +0 gathers on BASE,
+  +27 scatters on +PAGED — a launch writes the span, annotation and
+  binary rings at consecutive slots, so their 35 column writes (27 + 7
+  + 7 scatters: two plane scatters an i64 column, one for the others)
+  are two slice updates each (device ``_ring_write``) and no scatter:
+  95 -> 54. The pending ring's eight stay, now as 2048-row packing
+  scatters (its rows go to the front of a batch-sized buffer by their
+  running count, then in as a window). The paged layout's span rows
+  keep the planner's slots and so ``_uset``: the span ring's 27
+  scatters moved from BASE into +PAGED (2 -> 29; paged on is 83 as
+  before). What it bought: the 162 whole-column ops beside those
+  scatters (RING_SWEEP_OPS below) are gone, a quarter of the step on
+  the chip at the 2^22 ring.
+
 Raise a ceiling only with a note here explaining what bought the
 extra launches.
 """
@@ -74,9 +88,10 @@ import re
 # composed rows at exact equality, so an ungated path shows up as a
 # census mismatch, not a silent regression).
 LOWERING_TABLE = {
-    "BASE": (95, 4, 84),
+    "BASE": (54, 4, 84),
     "+WINDOW": (5, 0, 2),   # r13 windowed Moments-sketch arena
-    "+PAGED": (2, 0, 2),    # r19 paged span layout
+    "+PAGED": (29, 0, 2),   # r19 paged span layout (PR 30: + its 27
+                            # span-ring scatters, off BASE)
 }
 
 
@@ -136,6 +151,43 @@ def stablehlo_arena_sweeps(stablehlo_text: str, slots: int) -> list:
     the caller to coincide with no other dimension). A step that costs
     the batch and not the arena has none (ARENA_SWEEP_OPS). Returns the
     offending op names, in order."""
+    return _stablehlo_sweeps(stablehlo_text, {slots}, ("gather", "scatter"))
+
+
+# Ops of the fused step that pass over a whole ring column (span,
+# annotation, binary or pending ring), other than the ops that touch the
+# batch's rows: gathers, scatters and, since PR 30, the window reads and
+# writes (stablehlo_ring_sweeps below; tests/test_ring_window.py gates
+# ring and paged layouts, window arena on and off). A launch writes a
+# ring at consecutive slots, so its write is two slice updates on the
+# donated leaf in its own dtype; scattered into through ``_uset`` an i64
+# column was bitcast to planes, sliced, scattered and stacked back: 162
+# whole-column ops at the gate's shapes (nine for each of 18 i64
+# columns) and a quarter of the step on the chip at the 2^22 ring
+# (PERF.md 6, PR 30). Exempt, by the caller's choice of dimensions:
+# ``span_tab`` (hashed slots, an [H, 2] leaf whose planes are still
+# sliced and stacked: its cure is the leaf's form) and the paged
+# layout's span ring (the planner's slots, ``_uset``). Nothing buys a
+# raise: consecutive slots are written as a window; hashed slots want
+# the leaf in plane form.
+RING_SWEEP_OPS = 0
+
+
+def stablehlo_ring_sweeps(stablehlo_text: str, dims) -> list:
+    """Ops of a StableHLO lowering that pass over a whole ring column:
+    every op other than ``gather``, ``scatter``, ``dynamic_slice`` and
+    ``dynamic_update_slice`` (and the entry function's own arguments
+    and results) with an operand or result that has a dimension in
+    ``dims`` (the rings' capacities, chosen by the caller to coincide
+    with no other dimension, ``span_tab``'s among them). Returns the
+    offending op names, in order (RING_SWEEP_OPS)."""
+    return _stablehlo_sweeps(
+        stablehlo_text, set(dims),
+        ("gather", "scatter", "dynamic_slice", "dynamic_update_slice"))
+
+
+def _stablehlo_sweeps(stablehlo_text: str, sizes: set, row_ops) -> list:
+    sizes = {str(d) for d in sizes}
     dims = re.compile(r"tensor<((?:\d+x)+)[a-z]")
     name = re.compile(r'"?\b(?:stablehlo|chlo|func)\.([a-z_]+)"?|\b(call) @')
     out, open_ops = [], []
@@ -150,10 +202,10 @@ def stablehlo_arena_sweeps(stablehlo_text: str, slots: int) -> list:
         elif ln.endswith("({"):
             open_ops.append(op)
             continue  # a region op's types come on its closing line
-        if op in ("gather", "scatter", "return") or (
+        if op in row_ops or op == "return" or (
                 op == "func" and "public @main" in ln):
             continue
-        if any(str(slots) in d.split("x") for d in dims.findall(ln)):
+        if any(sizes & set(d.split("x")) for d in dims.findall(ln)):
             out.append(op or ln[:60])
     return out
 

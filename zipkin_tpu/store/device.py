@@ -372,7 +372,9 @@ def _pack_layout(fams):
 # promise holds for the whole index vector.
 #
 # Rule: a state leaf that a step scatters into lives in PLANE form
-# (see "the index arena's plane form" below).
+# (see "the index arena's plane form" below), and only HASHED slots are
+# scattered: a launch's CONSECUTIVE ring slots go in as a window
+# (_ring_write below), on the leaf as it is.
 
 
 def _p32(x):
@@ -411,6 +413,53 @@ def _uset(arr, idx, vals, ok):
         return _p64(jnp.stack([lo, hi], axis=-1))
     return arr.at[safe].set(jnp.asarray(vals, arr.dtype), mode="drop",
                             unique_indices=True)
+
+
+def _ring_windowed(cap: int, pad: int) -> bool:
+    """Whether a ``pad``-row launch writes a ``cap``-row ring as windows
+    (``_ring_write``): from shapes alone, at trace time. A pad past the
+    ring (tiny-ring tests) has no window to land in and scatters."""
+    return pad <= cap
+
+
+def _ring_write(arr, start, vals, n):
+    """arr[(start + k) % cap] = vals[k] for every k < n: the write of
+    ``n`` CONSECUTIVE ring slots from ``start`` (< cap), bit for bit
+    what ``_uset(arr, (start + arange(P)) % cap, vals, arange(P) < n)``
+    leaves, without a scatter: two P-row windows, each a slice read, a
+    select and a ``dynamic_update_slice`` on the donated leaf in its own
+    dtype (an i64 column is never bitcast to planes: that bitcast of the
+    whole column and the stack back were a quarter of the step at the
+    2^22 ring, PERF.md 6, PR 30). Window A starts at
+    min(start, cap - P) and holds every slot up to the ring's end;
+    window B starts at 0 and holds what lapped. Slot s0 + j of a window
+    is batch row k = (s0 + j - start) mod cap and is written where
+    k < n, so a window no row of the batch falls in writes back what it
+    read (P rows, nothing of the ring's size) and a lap needs no
+    ``lax.cond``. The batch rows come by slicing ``vals`` doubled, not
+    by a gather. n <= P; a pad past the ring falls back to ``_uset``."""
+    cap, pad = arr.shape[0], vals.shape[0]
+    j = jnp.arange(pad, dtype=jnp.int32)
+    start, n = start.astype(jnp.int32), n.astype(jnp.int32)
+    if not _ring_windowed(cap, pad):
+        return _uset(arr, (start + j) % cap, vals, j < n)
+    twice = jnp.tile(jnp.asarray(vals, arr.dtype), 2)
+
+    def window(a, s0):
+        r = (s0 - start) % cap  # the window's first slot is batch row r
+        t = cap - r             # and from row t of the window on, j - t
+        head = j < t
+        k = jnp.where(head, r + j, j - t)
+        v = jnp.where(
+            head,
+            jax.lax.dynamic_slice(twice, (jnp.minimum(r, pad),), (pad,)),
+            jax.lax.dynamic_slice(twice, (pad - jnp.minimum(t, pad),),
+                                  (pad,)))
+        old = jax.lax.dynamic_slice(a, (s0,), (pad,))
+        return jax.lax.dynamic_update_slice(
+            a, jnp.where(k < n, v, old), (s0,))
+
+    return window(window(arr, jnp.minimum(start, cap - pad)), jnp.int32(0))
 
 
 def _uset_p(arr2, idx, vals, ok):
@@ -1600,9 +1649,14 @@ def _note_path(config: StoreConfig, kind: str, value: str) -> None:
 
 
 def active_paths(config: StoreConfig) -> Dict[str, Tuple[str, ...]]:
-    """{"rank": ("counting", ...), "scatter": ("xla", ...)} — every
-    implementation this config's compiled ingest steps used (may hold
-    both when different launch shapes picked different modes)."""
+    """{"rank": ("counting", ...), "scatter": ("xla", ...),
+    "ring_write": ("ann:window", "bann:window", "pend:window",
+    "span:window")} — every implementation this config's compiled
+    ingest steps used (may hold both when different launch shapes
+    picked different modes). ``ring_write`` names, ring by ring, the
+    form its write took: ``window`` (consecutive slots as slice
+    updates, ``_ring_write``) or ``scatter`` (``_uset``: the paged
+    layout's span ring, or a pad past a tiny ring)."""
     with _ACTIVE_PATHS_LOCK:
         return {
             k: tuple(sorted(v))
@@ -2144,7 +2198,7 @@ def dep_archive_auto(state: "StoreState", incoming=None) -> "StoreState":
 def stablehlo_op_census(stablehlo_text: str,
                         ops=("scatter", "gather", "sort")) -> dict:
     """Scatter/gather/sort census of a StableHLO lowering — the ONE
-    counter behind the tier-1 95/5 ceiling (scripts/bench_smoke.py),
+    counter behind the tier-1 census ceilings (store/census.py),
     TpuSpanStore.step_census, and the counter-block purity gate; keep a
     single definition so the gate and the runtime observable can never
     drift. Backend-independent: counts ops the program ISSUES, not what
@@ -2298,6 +2352,15 @@ def dep_moments_in_range(state: "StoreState", start_ts, end_ts):
 # ---------------------------------------------------------------------------
 
 
+# The span ring's columns that a launch writes from the batch's column
+# of the same name (row_gid, the sixteenth, is the step's own).
+_SPAN_RING_COLS = (
+    "trace_id", "span_id", "parent_id", "name_id", "name_lc_id",
+    "service_id", "ts_cs", "ts_cr", "ts_sr", "ts_ss", "ts_first",
+    "ts_last", "duration", "flags", "indexable",
+)
+
+
 @partial(jax.jit, donate_argnums=(0,))
 def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
     c = state.config
@@ -2306,15 +2369,29 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
     PA = b.ann_ts.shape[0]
     PB = b.bann_key_id.shape[0]
 
-    # The ring writes assert unique_indices to XLA (duplicate slots
-    # would be silent state corruption, not just nondeterminism). The
-    # uniqueness invariant is on VALID rows only — n_spans <= capacity,
+    # A launch writes every ring at CONSECUTIVE slots from its cursor,
+    # so the writes are windows (_ring_write: slice, select, slice
+    # update, on the donated leaf) and not scatters; only the paged
+    # layout's span rows, whose slots are the planner's, scatter
+    # (_uset, which asserts unique_indices to XLA: duplicate slots
+    # would be silent state corruption, not just nondeterminism).
+    # Either way the valid rows must fit the ring — n_spans <= capacity,
     # n_anns <= ann_capacity, n_banns <= bann_capacity, pending count
     # <= pending_slots — which are dynamic values the host chunker
     # enforces per batch (TpuSpanStore.write_batch raises on violation,
-    # store/tpu.py). Padded rows past the valid count are remapped to
-    # DISTINCT out-of-bounds slots by _uset, so P itself may exceed the
-    # ring (tiny-ring tests pad well past capacity).
+    # store/tpu.py). The pad P itself may exceed the ring (tiny-ring
+    # tests pad well past capacity): such a ring scatters too, its
+    # padded rows remapped to DISTINCT out-of-bounds slots by _uset.
+    def ring_write(ring, cap, pos, n, cols):
+        """``cols`` ({leaf: batch column}) at ``n`` consecutive slots
+        of one ring from its cursor ``pos``; notes the form it took."""
+        for name, vals in cols.items():
+            upd[name] = _ring_write(getattr(state, name), pos % cap, vals, n)
+        pad = next(iter(cols.values())).shape[0]
+        _note_path(c, "ring_write", ring + (
+            ":window" if _ring_windowed(cap, pad) else ":scatter"))
+
+    upd = {}
     mask = jnp.arange(P) < b.n_spans
     mask_a = jnp.arange(PA) < b.n_anns
     mask_b = jnp.arange(PB) < b.n_banns
@@ -2327,8 +2404,8 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
     # unique among valid rows by construction (pages fill
     # monotonically, pages are distinct), and slot == gid % capacity
     # still holds, so every liveness check downstream is layout-blind.
-    # Either way the column writes ride the fast unique plane scatter
-    # (_uset).
+    # The ring's consecutive slots are written as windows; the paged
+    # layout's column writes ride the fast unique plane scatter (_uset).
     with jax.named_scope("ingest.ring_write"):
         if c.paged_enabled:
             R = c.page_rows
@@ -2352,20 +2429,17 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
                     r_ok,
                 )
             gids = b.span_gid
-            slots = b.span_slot
+            for col in _SPAN_RING_COLS:
+                upd[col] = _uset(getattr(state, col), b.span_slot,
+                                 getattr(b, col), mask)
+            upd["row_gid"] = _uset(row_gid0, b.span_slot, gids, mask)
+            _note_path(c, "ring_write", "span:scatter")
         else:
-            row_gid0 = state.row_gid
             gids = state.write_pos + jnp.arange(P, dtype=jnp.int64)
-            slots = (gids % c.capacity).astype(jnp.int32)
-        upd = {}
-        for col in (
-            "trace_id", "span_id", "parent_id", "name_id", "name_lc_id",
-            "service_id", "ts_cs", "ts_cr", "ts_sr", "ts_ss", "ts_first",
-            "ts_last", "duration", "flags", "indexable",
-        ):
-            upd[col] = _uset(getattr(state, col), slots, getattr(b, col),
-                             mask)
-        upd["row_gid"] = _uset(row_gid0, slots, gids, mask)
+            ring_write(
+                "span", c.capacity, state.write_pos, b.n_spans,
+                {**{col: getattr(b, col) for col in _SPAN_RING_COLS},
+                 "row_gid": gids})
         upd["write_pos"] = state.write_pos + b.n_spans.astype(jnp.int64)
 
     # -- annotation ring writes ----------------------------------------
@@ -2375,36 +2449,25 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
     # keep working unchanged in paged mode.
     with jax.named_scope("ingest.annotation_ring_write"):
         a_gids = state.ann_write_pos + jnp.arange(PA, dtype=jnp.int64)
-        a_slots = (a_gids % c.ann_capacity).astype(jnp.int32)
         if c.paged_enabled:
             span_gid_of_ann = gids[b.ann_span_idx]
         else:
             span_gid_of_ann = state.write_pos + b.ann_span_idx.astype(jnp.int64)
-        upd["ann_gid"] = _uset(
-            state.ann_gid, a_slots, jnp.where(mask_a, span_gid_of_ann, -1),
-            mask_a,
-        )
-        for col in ("ann_ts", "ann_value_id", "ann_service_id", "ann_endpoint_id"):
-            upd[col] = _uset(getattr(state, col), a_slots, getattr(b, col),
-                             mask_a)
+        ring_write(
+            "ann", c.ann_capacity, state.ann_write_pos, b.n_anns,
+            {"ann_gid": span_gid_of_ann,
+             **{col: getattr(b, col) for col in ANN_MAT_COLS[1:]}})
         upd["ann_write_pos"] = state.ann_write_pos + b.n_anns.astype(jnp.int64)
 
         bb_gids = state.bann_write_pos + jnp.arange(PB, dtype=jnp.int64)
-        bb_slots = (bb_gids % c.bann_capacity).astype(jnp.int32)
         if c.paged_enabled:
             span_gid_of_bann = gids[b.bann_span_idx]
         else:
             span_gid_of_bann = state.write_pos + b.bann_span_idx.astype(jnp.int64)
-        upd["bann_gid"] = _uset(
-            state.bann_gid, bb_slots,
-            jnp.where(mask_b, span_gid_of_bann, -1), mask_b,
-        )
-        for col in (
-            "bann_key_id", "bann_value_id", "bann_type", "bann_service_id",
-            "bann_endpoint_id",
-        ):
-            upd[col] = _uset(getattr(state, col), bb_slots, getattr(b, col),
-                             mask_b)
+        ring_write(
+            "bann", c.bann_capacity, state.bann_write_pos, b.n_banns,
+            {"bann_gid": span_gid_of_bann,
+             **{col: getattr(b, col) for col in BANN_MAT_COLS[1:]}})
         upd["bann_write_pos"] = state.bann_write_pos + b.n_banns.astype(jnp.int64)
 
     # -- streaming dependency join -------------------------------------
@@ -2428,15 +2491,20 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
         # Children whose parent hasn't arrived yet wait in the pending ring
         # (re-probed by dep_sweep); the ring overwrites oldest-first, the
         # bounded-wait analogue of the reference's index TTL.
-        Qp = state.pend_key.shape[0]
-        rank = jnp.cumsum(pending.astype(jnp.int64)) - 1
-        pslot = ((state.pend_pos + rank) % Qp).astype(jnp.int32)
-        upd["pend_key"] = _uset(state.pend_key, pslot,
-                                _tab_pack(ckey, b.service_id), pending)
-        upd["pend_dur"] = _uset(state.pend_dur, pslot, b.duration, pending)
-        upd["pend_tsf"] = _uset(state.pend_tsf, pslot, b.ts_first, pending)
-        upd["pend_tsl"] = _uset(state.pend_tsl, pslot, b.ts_last, pending)
-        upd["pend_pos"] = state.pend_pos + pending.sum(dtype=jnp.int64)
+        # The pending rows take consecutive slots too, once packed to the
+        # front of a P-row buffer by their running count (a scatter of
+        # the batch's size).
+        rank = jnp.cumsum(pending.astype(jnp.int32)) - 1
+        n_pend = pending.sum(dtype=jnp.int64)
+        front = jnp.zeros(P, jnp.int64)
+        ring_write(
+            "pend", c.pending_slots, state.pend_pos, n_pend,
+            {name: _uset(front, rank, vals, pending)
+             for name, vals in (
+                 ("pend_key", _tab_pack(ckey, b.service_id)),
+                 ("pend_dur", b.duration), ("pend_tsf", b.ts_first),
+                 ("pend_tsl", b.ts_last))})
+        upd["pend_pos"] = state.pend_pos + n_pend
 
     # -- index column families -----------------------------------------
     # (written before the counter block; the ann-derived columns below
@@ -3567,7 +3635,7 @@ def capture_eviction_rows(
     insertion order — the cold tier's batched host pull. Same stacked
     matrix shape as gather_trace_rows so the host decode path is
     shared. A PURE READ: the fused ingest step's lowering is untouched
-    (bench_smoke's 95/5/79 census gate holds with capture wired); the
+    (bench_smoke's census gate holds with capture wired); the
     cold tier pays one extra read-only launch + one D2H per capture
     window on the existing archive cadence.
 

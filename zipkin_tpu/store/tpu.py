@@ -2187,7 +2187,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         """Scatter/gather/sort census of the fused ingest step's
         StableHLO lowering at the given pad shapes — the portable proxy
         for per-batch launch cost (gated in tier-1 at
-        95 scatters / 5 sorts). Memoized per shape; computed only when
+        ``census.LOWERING_TABLE``). Memoized per shape; computed only when
         asked (a trace, not a compile) — metric scrapes never pay it."""
         key = (n_spans, n_anns, n_banns)
         memo = getattr(self, "_census_memo", None)
@@ -2247,6 +2247,14 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
             "counting" in paths.get("rank", ()))
         out["scatter_path_pallas"] = float(
             "pallas" in paths.get("scatter", ()))
+        # Rings (span, ann, bann, pend) every compiled step wrote as
+        # windows, and rings some step scattered into (the paged
+        # layout's span ring; a pad past a tiny ring).
+        forms = [f.split(":") for f in paths.get("ring_write", ())]
+        scattered = {ring for ring, form in forms if form == "scatter"}
+        out["ring_write_scatter"] = float(len(scattered))
+        out["ring_write_window"] = float(
+            len({ring for ring, _ in forms} - scattered))
         out["batch_spans_limit"] = float(self._max_chunk_spans())
         # Paged-layout allocator occupancy (host mirrors — the same
         # numbers the zipkin_store_pages_* gauges export).
