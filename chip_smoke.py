@@ -356,11 +356,10 @@ def report_metrics(daemon: Daemon, shards: int) -> None:
 
     if not shards:
         # Single-device store observables (the sharded store exports
-        # neither): compiles so far, and which rank / arena-scatter
+        # neither): compiles so far, and which rank and ring-write
         # implementations its compiled steps took (dev.active_paths).
         say("jit_compiles_total", int(m["zipkin_store_jit_compiles_total"]))
         say("rank_path_counting", int(counter("rank_path_counting")))
-        say("scatter_path_pallas", int(counter("scatter_path_pallas")))
         say("ring_write_window", int(counter("ring_write_window")))
         say("ring_write_scatter", int(counter("ring_write_scatter")))
     say("ring_occupancy", int(counter("ring_occupancy")))

@@ -1,21 +1,19 @@
-"""CPU-runnable smoke bench: one JSON line of perf-structure evidence.
+"""CPU-runnable structure smoke: one JSON line of counts and identities.
 
-Three things the full bench (bench.py) can only prove on real hardware
-are provable structurally on any backend, every CI run:
+The question this script answers, on any backend, every CI run: how
+many ops does the fused ingest step lower to, and are the duplicate
+paths bitwise equal. It reports counts (the StableHLO scatter/gather/
+sort census, recompiles, launches, records, bytes) and identities
+(pipelined == serial, replay == oracle, replica == primary, paged ==
+ring), one phase per subsystem, each gated by its own case of
+tests/test_bench_smoke.py. It reports NO time, rate or overhead: a CPU
+run is no evidence for those (ROADMAP.md north star). How fast the
+served path is on the chip is ``benchmark/``'s question; what one step
+costs on the device is ``scripts/step_time.py``'s.
 
-1. **Fused-ingest timing** at small N — a regression canary, not a
-   throughput claim (CPU ms/step moves with the machine; the JSON
-   carries it for trending).
-2. **Index-family op counts** — the whole r5→r6 tentpole is "fewer
-   scatter/gather launches per ingest step" (the unified index arena:
-   one rank-sort + one entry scatter block + ONE shared watermark
-   scatter for all seven families). Per-kernel overhead dominates on
-   the target device class (NOTES_r03 §3), so the SCATTER COUNT of the
-   compiled step is the portable proxy for the TPU win, and the tier-1
-   lane asserts it doesn't creep back up (tests/test_bench_smoke.py).
-3. **Batched-query scaling** — k queries through one
-   ``get_trace_ids_multi`` launch vs k singular calls; the read-path
-   dispatch-floor amortization the query coalescer rides on.
+Per-kernel overhead dominates on the target device class (NOTES_r03
+§3), so the SCATTER COUNT of the compiled step is the portable proxy
+for its TPU cost, and the tier-1 lane asserts it doesn't creep back up.
 
 Usage:  python scripts/bench_smoke.py [--spans 7000] [--k 8]
 Emits exactly one JSON line on stdout.
@@ -55,9 +53,7 @@ def run_archive() -> dict:
     (a) eviction capture adds ZERO ops to the fused ingest step (its
     lowering census with a sink attached equals the plain store's),
     (b) a 4x-ring ingest leaves every evicted span answerable, and
-    (c) zone-map pruning actually skips segments. Also times the
-    capture overhead (ingest with sink vs without, same spans) and the
-    cold trace-fetch latency."""
+    (c) zone-map pruning actually skips segments."""
     import numpy as np
 
     from zipkin_tpu.columnar.schema import SpanBatch
@@ -79,30 +75,15 @@ def run_archive() -> dict:
     spans = [s for t in traces for s in t][:n_spans]
     chunk = 128
 
-    # Warm the jit cache on a scratch store so neither timed run pays
-    # compilation (the overhead delta is the measurement, not the
-    # compile).
-    warm = TpuSpanStore(config)
-    for i in range(0, len(spans), chunk):
-        warm.apply(spans[i:i + chunk])
-
-    # Baseline: same spans, no sink.
-    plain = TpuSpanStore(config)
-    t0 = time.perf_counter()
-    for i in range(0, len(spans), chunk):
-        plain.apply(spans[i:i + chunk])
-    plain_s = time.perf_counter() - t0
-
+    plain = TpuSpanStore(config)  # no sink: the census's other side
     hot = TpuSpanStore(config)
     tiered = TieredSpanStore(hot, params=ArchiveParams.for_config(
         config, compact_fanin=2, small_span_limit=config.capacity,
         bloom_bits=1 << 12, cms_width=1 << 10, hll_p=6,
     ))
     oracle = InMemorySpanStore()
-    t0 = time.perf_counter()
     for i in range(0, len(spans), chunk):
         tiered.apply(spans[i:i + chunk])
-    tiered_s = time.perf_counter() - t0
     oracle.apply(spans)
 
     # The fused step's lowering with the sink ATTACHED — must census
@@ -123,12 +104,10 @@ def run_archive() -> dict:
     sample = tids[:4] + tids[len(tids) // 2:len(tids) // 2 + 4] \
         + tids[-4:]
     end_ts = 1 << 60
-    t0 = time.perf_counter()
     fetch_ok = all(
         tiered.get_spans_by_trace_ids([t])
         == oracle.get_spans_by_trace_ids([t]) for t in sample
     )
-    cold_fetch_s = time.perf_counter() - t0
     svc = sorted(oracle.get_all_service_names())[0]
     ids_ok = (
         tiered.get_trace_ids_by_name(svc, None, end_ts, 10 * n_spans)
@@ -144,12 +123,6 @@ def run_archive() -> dict:
     c = tiered.counters()
     return {
         "spans": len(spans),
-        "capture_overhead_pct": round(
-            100.0 * (tiered_s - plain_s) / plain_s, 1),
-        "ingest_plain_s": round(plain_s, 3),
-        "ingest_tiered_s": round(tiered_s, 3),
-        "cold_fetch_ms_per_trace": round(
-            cold_fetch_s / len(sample) * 1e3, 2),
         "segments_written": int(c["archive_segments_written"]),
         "compactions": int(c["archive_compactions"]),
         "segments_pruned": int(
@@ -174,10 +147,7 @@ def run_pipeline(depth: int = 4) -> dict:
     H2D staging adds zero ops to the fused step's lowering, and (d)
     ingest never stalled on capture sealing (stall counter stays 0 at
     a generous backlog — deliberate backpressure is exercised in
-    tests/test_pipeline.py instead). Overlap efficiency is reported as
-    stage-busy-seconds / wall: > 1.0 means host encode + staging
-    genuinely overlapped device compute (expect ~1.0 on the CPU
-    backend, where "device compute" shares the host)."""
+    tests/test_pipeline.py instead)."""
     import jax
     import numpy as np
 
@@ -195,8 +165,7 @@ def run_pipeline(depth: int = 4) -> dict:
         quantile_buckets=256,
     )
     # 2 ring turns: enough to lap the ring and seal several capture
-    # windows (the gates are identity / recompiles / census / stall,
-    # not throughput), at half the archive phase's drive cost.
+    # windows, at half the archive phase's drive cost.
     n_spans = 2 * config.capacity
     traces = generate_traces(n_traces=n_spans // 4, max_depth=3,
                              n_services=8)
@@ -212,20 +181,15 @@ def run_pipeline(depth: int = 4) -> dict:
         ))
 
     def drive(tiered):
-        t0 = time.perf_counter()
         for i in range(0, len(spans), chunk):
             tiered.apply(spans[i:i + chunk])
-        return time.perf_counter() - t0
 
-    # Warm every jit the measured PIPELINED drive will hit (ingest,
+    # Warm every jit the gated PIPELINED drive will hit (ingest,
     # sweep, bucket close, capture — staged device-resident arguments
     # key their own jit cache rows, distinct from host-numpy ones, see
     # dev.stage_batch), so the recompile gate below is a true
-    # steady-state zero. The serial side needs no warm drive of its
-    # own here: run() calls run_archive() first, which streams this
-    # exact config/chunk geometry serially three times (standalone
-    # run_pipeline callers just see compile time inside serial_s —
-    # nothing is gated on it).
+    # steady-state zero. Nothing is gated on the serial side's
+    # compiles.
     warm_ph, warm_pt = build(64)
     warm_ph.start_pipeline(depth)
     drive(warm_pt)
@@ -233,21 +197,15 @@ def run_pipeline(depth: int = 4) -> dict:
     warm_pt.close()
 
     serial_hot, serial_t = build(0)
-    serial_s = drive(serial_t)
+    drive(serial_t)
 
     pipe_hot, pipe_t = build(64)
     compiles0 = dev.compile_count()
-    pipe = pipe_hot.start_pipeline(depth)
-    t0 = time.perf_counter()
-    for i in range(0, len(spans), chunk):
-        pipe_t.apply(spans[i:i + chunk])
+    pipe_hot.start_pipeline(depth)
+    drive(pipe_t)
     pipe_hot.drain_pipeline()
     pipe_hot.seal_barrier()
-    pipelined_s = time.perf_counter() - t0
     recompiles = dev.compile_count() - compiles0
-    encode_s = pipe.h_encode.sum
-    stage_s = pipe.h_stage.sum
-    commit_s = pipe.h_commit.sum
     pipe_hot.stop_pipeline()
     sealer = pipe_hot._sealer
     capture_stall_s = float(sealer.c_stall.value) if sealer else 0.0
@@ -281,16 +239,6 @@ def run_pipeline(depth: int = 4) -> dict:
     return {
         "spans": len(spans),
         "depth": depth,
-        "serial_ingest_s": round(serial_s, 3),
-        "pipelined_ingest_s": round(pipelined_s, 3),
-        "speedup": round(serial_s / pipelined_s, 2) if pipelined_s
-        else 0,
-        "overlap_efficiency": round(
-            (encode_s + stage_s + commit_s) / pipelined_s, 2)
-        if pipelined_s else 0,
-        "encode_s": round(encode_s, 3),
-        "stage_s": round(stage_s, 3),
-        "commit_s": round(commit_s, 3),
         "capture_stall_s": round(capture_stall_s, 4),
         "windows_sealed": int(sealer.c_sealed.value) if sealer else 0,
         "recompiles_after_warmup": int(recompiles),
@@ -301,19 +249,15 @@ def run_pipeline(depth: int = 4) -> dict:
 
 def run_wal() -> dict:
     """Durability phase (r10 tentpole): the same spans driven through
-    a plain store (the throughput baseline AND the uncrashed oracle)
-    and through WAL-attached stores at the group-commit default and at
-    fsync=off, proving on every CI run that (a) a full-log replay into
-    a fresh store lands a BITWISE identical device state (the
-    ack-after-append contract's other half: what was journaled is
-    exactly what recovery rebuilds), (b) journaling adds ZERO jit
-    recompiles in steady state and replay adds zero more (replay
-    re-pads through the same pow2 buckets the drive compiled), and
-    (c) the append overhead stays inside the acceptance budget (<= 10%
-    at the group-commit default; fsync=off reproduces the no-WAL
-    throughput). Overheads are paired per-round ratios, min over four
-    interleaved rounds — the structural gates (identity/recompiles)
-    are exact, the ratios are trend data on a noisy CPU."""
+    a plain store (the uncrashed oracle) and through a WAL-attached
+    store at the group-commit default, proving on every CI run that
+    (a) a full-log replay into a fresh store lands a BITWISE identical
+    device state (the ack-after-append contract's other half: what was
+    journaled is exactly what recovery rebuilds) and (b) journaling
+    adds ZERO jit recompiles in steady state and replay adds zero more
+    (replay re-pads through the same pow2 buckets the drive compiled).
+    What the append costs is a question for the chip: ROADMAP.md S11's
+    WAL on/off pair of cells."""
     import os
     import shutil
     import tempfile
@@ -335,87 +279,40 @@ def run_wal() -> dict:
     chunk = 128
 
     def drive(store):
-        t0 = time.perf_counter()
         for i in range(0, len(spans), chunk):
             store.apply(spans[i:i + chunk])
-        return time.perf_counter() - t0
+        return store
 
     root = tempfile.mkdtemp(prefix="wal-smoke-")
     try:
-        n_dir = [0]
-
-        def build(fsync):
-            store = TpuSpanStore(config)
-            if fsync is not None:
-                n_dir[0] += 1
-                d = os.path.join(root, f"wal-{fsync}-{n_dir[0]}")
-                store.attach_wal(WriteAheadLog(d, fsync=fsync))
-            return store
-
-        drive(TpuSpanStore(config))  # jit warm-up (uncounted)
+        # The no-WAL drive is the oracle AND the jit warm-up: the
+        # journaled drive after it must compile nothing.
+        oracle = drive(TpuSpanStore(config))
         compiles0 = dev.compile_count()
-        # Interleaved rounds with PAIRED ratios: host noise (GC,
-        # allocator warmth, machine load) drifts over seconds and
-        # swamps the per-record append cost, so each round drives the
-        # three configs back-to-back under the same conditions and the
-        # overhead is the round's WAL/baseline ratio — load drift
-        # cancels within a round where a ratio of cross-round floors
-        # would pair a lucky-fast baseline against unlucky WAL drives.
-        # The min over rounds is the least-noise estimate of the
-        # intrinsic overhead (the structural gates are exact; the
-        # ratios remain trend data on a noisy CI host).
-        rounds = []
-        last = {}
-        for _ in range(4):
-            times = {}
-            for fsync in (None, "interval", "off"):
-                store = build(fsync)
-                times[fsync] = drive(store)
-                prev = last.get(fsync)
-                if prev is not None and prev.wal is not None:
-                    prev.wal.close()
-                last[fsync] = store
-            rounds.append(times)
-        base_s = min(r[None] for r in rounds)
-        interval_s = min(r["interval"] for r in rounds)
-        off_s = min(r["off"] for r in rounds)
-        overhead_interval = min(
-            r["interval"] / r[None] for r in rounds) - 1.0
-        overhead_off = min(r["off"] / r[None] for r in rounds) - 1.0
-        oracle, s_int, s_off = last[None], last["interval"], last["off"]
+        s_int = TpuSpanStore(config)
+        wal_dir = os.path.join(root, "wal-interval")
+        s_int.attach_wal(WriteAheadLog(wal_dir, fsync="interval"))
+        drive(s_int)
         steady_recompiles = dev.compile_count() - compiles0
 
         wal_stats = s_int.wal.stats()
-        wal_dir = s_int.wal.directory
         s_int.wal.sync()
         s_int.wal.close()
 
         # Full-log replay into a FRESH store == the uncrashed oracle.
         compiles1 = dev.compile_count()
         wal2 = WriteAheadLog(wal_dir, fsync="off")
-        t0 = time.perf_counter()
         rec, rstats = recover(
             None, wal2, fresh_store=lambda: TpuSpanStore(config))
-        recovery_s = time.perf_counter() - t0
         replay_recompiles = dev.compile_count() - compiles1
         identical = states_bitwise_equal(oracle.state, rec.state)
         wal2.close()
-        s_off.wal.close()
         return {
             "spans": len(spans),
-            "baseline_ingest_s": round(base_s, 3),
-            "wal_interval_ingest_s": round(interval_s, 3),
-            "wal_off_ingest_s": round(off_s, 3),
-            "append_overhead_interval": round(overhead_interval, 3),
-            "append_overhead_off": round(overhead_off, 3),
             "steady_state_recompiles": int(steady_recompiles),
             "replay_recompiles": int(replay_recompiles),
             "replay_identical": bool(identical),
             "replayed_records": rstats["replayed_records"],
-            "recovery_s": round(recovery_s, 3),
-            "replay_spans_per_s": round(
-                rstats["replayed_spans"] / max(rstats["replay_s"],
-                                               1e-9), 1),
             "wal_bytes_per_span": round(
                 wal_stats["wal_bytes"] / len(spans), 1),
             "wal_segments": wal_stats["wal_segments"],
@@ -429,14 +326,13 @@ def run_query() -> dict:
     path (query/engine.py) proven structurally on every CI run:
     (a) sketch-tier answers (catalogs, quantiles, top-k, HLL) are
     IDENTICAL to the device read path's while costing zero device
-    round-trips — p50 is gated in single-digit ms even on CPU;
+    round-trips;
     (b) the steady-state query loop performs ZERO jit recompiles (the
     resident programs stay resident); (c) a cache hit returns answers
     bitwise-equal to the cold computation, and an ingest commit
     invalidates precisely (the frontier-keyed re-answer matches a
-    fresh store read). Index-tier latency is trend data on CPU (the
-    ~110 ms dispatch floor this engine kills is a device-class
-    property), but its p99 rides the JSON for the TPU bench to gate."""
+    fresh store read). What a tier costs is a question for the chip
+    (no cell reads yet: ROADMAP.md S8)."""
     from zipkin_tpu import obs
     from zipkin_tpu.query.engine import QueryEngine
     from zipkin_tpu.store import device as dev
@@ -481,20 +377,12 @@ def run_query() -> dict:
     # across the ingest jits AND the resident query programs
     # (dev.query_compile_count, the kernels the executor dispatches).
     compiles0 = dev.compile_count() + dev.query_compile_count()
-    sk = obs.LatencySketch("q_sketch_s", "sketch-tier serve",
-                           quantiles=(0.5, 0.99))
     for _ in range(40):
-        t0 = time.perf_counter()
         engine.service_duration_quantiles(svcs[0], qs)
         engine.top_annotations(svcs[1 % len(svcs)])
         engine.get_all_service_names()
-        sk.observe((time.perf_counter() - t0) / 3.0)
-    ix = obs.LatencySketch("q_index_s", "index-tier dispatch",
-                           quantiles=(0.5, 0.99))
     for _ in range(20):
-        t0 = time.perf_counter()
         engine.executor.run(queries)  # cache-bypassing resident path
-        ix.observe(time.perf_counter() - t0)
     recompiles = (dev.compile_count() + dev.query_compile_count()
                   - compiles0)
 
@@ -512,14 +400,9 @@ def run_query() -> dict:
     after = ids(engine.get_trace_ids_multi(queries))
     fresh = ids(store.get_trace_ids_multi(queries))
     invalidation_ok = after == fresh
-    sks, ixs = sk.snapshot(), ix.snapshot()
     return {
         "spans": len(spans),
         "sketch_identical": bool(ident),
-        "sketch_p50_ms": round(sks["p50"] * 1e3, 3),
-        "sketch_p99_ms": round(sks["p99"] * 1e3, 3),
-        "index_p50_ms": round(ixs["p50"] * 1e3, 3),
-        "index_p99_ms": round(ixs["p99"] * 1e3, 3),
         "steady_recompiles": int(recompiles),
         "cache_hit_identical": bool(cache_hit_ok),
         "cache_invalidation_exact": bool(invalidation_ok),
@@ -530,9 +413,9 @@ def run_query() -> dict:
 
 
 def run_ingest_structure() -> dict:
-    """Ingest-roofline phase (r12 tentpole): the three structural
-    claims behind the batch-escalation / counting-sort / pallas work,
-    proven on every CI run:
+    """Ingest-structure phase (r12 tentpole): the structural claims
+    behind the batch-escalation and counting-sort work, proven on
+    every CI run:
 
     (a) the counting-sort rank path's fused-step lowering carries
         strictly fewer stablehlo.sort ops than the argsort path's (the
@@ -544,14 +427,10 @@ def run_ingest_structure() -> dict:
         through the three-stage pipeline performs ZERO steady-state
         jit recompiles once warmed — escalation changes pad buckets,
         not compile-cache churn;
-    (c) the stage-1 sketch-mirror COO delta (riding the hot encode
-        path since r11) adds at most MAX_MIRROR_DELTA_RATIO to the
-        encode stage, measured as paired per-round ratios (min over
-        rounds — the WAL phase's noise discipline)."""
+    (c) every ring of the ring layout is written as a window."""
     import numpy as np
 
-    from zipkin_tpu.store import census, device as dev
-    from zipkin_tpu.store.base import should_index
+    from zipkin_tpu.store import device as dev
     from zipkin_tpu.store.tpu import TpuSpanStore
     from zipkin_tpu.tracegen import generate_traces
     from zipkin_tpu.columnar.schema import SpanBatch
@@ -603,61 +482,14 @@ def run_ingest_structure() -> dict:
     meas = TpuSpanStore(cfg_big)
     compiles0 = dev.compile_count()
     meas.start_pipeline(4)
-    t0 = time.perf_counter()
     drive(meas)
     meas.drain_pipeline()
-    escalated_s = time.perf_counter() - t0
     recompiles = dev.compile_count() - compiles0
     meas.stop_pipeline()
     c_meas = meas.counters()
     warm.close()
     meas.close()
 
-    # Sketch-mirror stage-1 cost: paired encode-vs-delta rounds over
-    # the SAME launch groups (host-only — no device work — so the
-    # probe uses a bigger span set than the drives: per-group fixed
-    # delta costs then sit against a steady-state encode denominator
-    # instead of dominating a tiny one). The first pass warms the
-    # dictionaries; measured rounds are steady-state re-encodes.
-    # cfg_big's 512-span chunks: the deployment geometry the delta
-    # actually rides at (bigger launches amortize its per-group fixed
-    # cost — measuring at tiny chunks would overstate it).
-    probe = TpuSpanStore(cfg_big)
-    m_traces = generate_traces(n_traces=900, max_depth=3,
-                               n_services=16)
-    m_spans = [s for t in m_traces for s in t][:2560]
-
-    def encode_parts():
-        parts = []
-        for part in probe._chunk_by_trace(m_spans):
-            batch = probe.codec.encode(part)
-            indexable = np.fromiter(
-                (should_index(s) for s in part), bool, len(part))
-            name_lc = probe._name_lc_ids(batch)
-            parts.extend(probe._chunk_columnar(batch, name_lc,
-                                               indexable))
-        return parts
-
-    groups = list(probe._plan_units(encode_parts()))  # warm dicts
-    ratios, enc_ms, delta_ms = [], [], []
-    for _ in range(3):
-        # The FULL stage-1 body writers pay (encode + index bits +
-        # chunking + pow2 padding + the mirror delta, exactly what
-        # _apply_pipelined runs under the encode lock)...
-        t0 = time.perf_counter()
-        groups = list(probe._plan_units(encode_parts()))
-        for g in groups:
-            probe._pad_unit(g)  # includes delta_of
-        stage_s = time.perf_counter() - t0
-        # ...vs the delta alone; ratio = delta / stage-without-delta.
-        t0 = time.perf_counter()
-        for g in groups:
-            probe.sketch_mirror.delta_of(g)
-        d_s = time.perf_counter() - t0
-        ratios.append(d_s / max(stage_s - d_s, 1e-9))
-        enc_ms.append((stage_s - d_s) * 1e3)
-        delta_ms.append(d_s * 1e3)
-    probe.close()
     return {
         "spans": len(spans),
         "census_argsort": census_arg,
@@ -667,18 +499,12 @@ def run_ingest_structure() -> dict:
         "rank_path_counting_cfg": dev.active_paths(cfg_cnt).get(
             "rank", ()),
         "rank_path_counting": c_meas["rank_path_counting"],
-        "scatter_path_pallas": c_meas["scatter_path_pallas"],
         "ring_write_cfg": dev.active_paths(cfg_cnt).get("ring_write", ()),
         "ring_write_window": c_meas["ring_write_window"],
         "batch_spans_geometries": [cfg_cnt.batch_spans,
                                    cfg_big.batch_spans],
         "escalated_batch_spans_limit": c_meas["batch_spans_limit"],
         "recompiles_after_batch_escalation": int(recompiles),
-        "escalated_pipelined_s": round(escalated_s, 3),
-        "mirror_delta_ratio": round(min(ratios), 4),
-        "mirror_delta_ms": round(min(delta_ms), 2),
-        "encode_ms": round(min(enc_ms), 2),
-        "mirror_budget": census.MAX_MIRROR_DELTA_RATIO,
     }
 
 
@@ -771,15 +597,7 @@ def run_windows() -> dict:
     recompiles = dev.compile_count() - compiles0
 
     # (d) sketch-tier reads — pure host math; gate the solver's rank.
-    # p50 over warmed calls, matching the r11 sketch-tier gate: the
-    # first call pays one-time numpy/solver warmup, not serve cost.
     est = serial.windowed_quantiles("wsvc1", [0.5, 0.99])
-    samples = []
-    for _ in range(20):
-        t0 = time.perf_counter()
-        serial.windowed_quantiles("wsvc1", [0.5, 0.99])
-        samples.append(time.perf_counter() - t0)
-    q_ms = sorted(samples)[len(samples) // 2] * 1e3
     burn = serial.slo_burn("wsvc1", objective=0.99)
     heat = serial.latency_heatmap("wsvc1", bands=6)
     durs = np.sort([
@@ -811,7 +629,6 @@ def run_windows() -> dict:
         "mirror_bitwise": bool(mirror_bitwise),
         "pipelined_bitwise": bool(piped_bitwise),
         "recompiles_steady_state": int(recompiles),
-        "windowed_quantile_ms": round(q_ms, 3),
         "quantile_rank_err": round(float(rank_err), 4),
         "solver_rank_tol": win.SOLVER_RANK_TOL,
         "burn_total": burn["windows"][0]["total"],
@@ -833,10 +650,9 @@ def run_paged() -> dict:
         size) stream — per-trace reads AND id lookups answer
         identically through both layouts;
     (c) zero steady-state recompiles driving the paged layout through
-        the ingest pipeline (same stream twice through warmed shapes);
-    (d) skewed-workload ingest rate through the paged planner (a
-        regression canary; the ≥2x retention-per-byte claim needs the
-        full bench's eviction arm — bench.py bench_paged)."""
+        the ingest pipeline (same stream twice through warmed shapes).
+    Whether the layout retains more per byte, and at what rate, is not
+    measured: no cell runs it (ROADMAP.md D3)."""
     import numpy as np
 
     import jax  # noqa: F401 — device_get via stores below
@@ -885,10 +701,8 @@ def run_paged() -> dict:
 
     ring = TpuSpanStore(cfg_ring)
     drive(ring)
-    t0 = time.perf_counter()
     paged = TpuSpanStore(cfg_paged)
     drive(paged)
-    paged_first_s = time.perf_counter() - t0
 
     # (b) bitwise parity: whole-trace reads and id lookups. One
     # batched sweep covers every trace (one launch per store); the
@@ -914,9 +728,7 @@ def run_paged() -> dict:
     drive(paged, pipelined=True)
     compiles0 = dev.compile_count()
     steady = TpuSpanStore(cfg_paged)
-    t0 = time.perf_counter()
     drive(steady, pipelined=True)
-    skew_s = time.perf_counter() - t0
     recompiles = dev.compile_count() - compiles0
 
     # (a) census arithmetic: paged-on vs ring lowering at the smoke
@@ -938,8 +750,6 @@ def run_paged() -> dict:
         "query_parity_bitwise": bool(parity),
         "ids_parity_bitwise": bool(ids_parity),
         "recompiles_steady_state": int(recompiles),
-        "skewed_spans_per_s": round(len(spans) / skew_s, 1),
-        "first_drive_s": round(paged_first_s, 2),
         "pages_active": int(pstats["pages_active"]),
         "pages_free": int(pstats["pages_free"]),
         "page_reclaims_total": int(pstats["page_reclaims_total"]),
@@ -956,9 +766,9 @@ def run_replication() -> dict:
     performing ZERO jit compiles (it is device-free by construction,
     and the warm standby replays into already-compiled shapes);
     (b) a warm standby fed the same stream lands a state bitwise equal
-    to the primary's, and promoting it (the failover RTO) is
-    measured; (c) the follower kept its lag bounded under full ingest
-    load and caught up to lag 0 at the drained frontier, with the
+    to the primary's and can be promoted (how long a failover takes is
+    the chip's question: ROADMAP.md R6, `crash-recover`); (c) the
+    follower kept its lag bounded under full ingest load and caught up to lag 0 at the drained frontier, with the
     un-fetched tail pinned against truncation by its cursor."""
     import os  # noqa: F401 — tempdir cleanup below
     import shutil
@@ -972,7 +782,6 @@ def run_replication() -> dict:
         StandbyTarget,
         WalShipper,
     )
-    from zipkin_tpu.replicate.protocol import config_from_dict
     from zipkin_tpu.store import device as dev
     from zipkin_tpu.store.archive import TieredSpanStore
     from zipkin_tpu.store.replica import ReplicaSpanStore
@@ -1026,7 +835,7 @@ def run_replication() -> dict:
 
         rc = ShipClient("127.0.0.1", port, "smoke-replica",
                         mode="replica")
-        replica = ReplicaSpanStore(config_from_dict(
+        replica = ReplicaSpanStore(dev.config_from_dict(
             rc.connect()["config"]))
         stores.append(replica)
         f_rep = Follower(ReplicaTarget(replica), rc,
@@ -1046,15 +855,11 @@ def run_replication() -> dict:
             primary.apply(spans[i:i + chunk])
             max_lag = max(max_lag, f_rep.lag_records())
         wal.sync()
-        # Failover clock starts at the primary's last write: RTO =
-        # standby applies the remaining durable tail + promote.
-        t0 = time.perf_counter()
+        # Failover: the standby applies the remaining durable tail and
+        # is promoted.
         sby_up = f_sby.drain(60.0)
         promoted = f_sby.promote()
-        rto_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
         rep_up = f_rep.drain(60.0)
-        catch_up_s = time.perf_counter() - t0
         caught_up = sby_up and rep_up
         standby_bitwise = states_bitwise_equal(
             primary.hot.state, promoted.state)
@@ -1100,18 +905,6 @@ def run_replication() -> dict:
         agree &= (replica.get_traces_duration(tids)
                   == primary.get_traces_duration(tids))
 
-        # Sketch-tier latency off the replica (pure numpy).
-        from zipkin_tpu import obs
-
-        sk = obs.LatencySketch("bench_replica_sketch_seconds",
-                               "replica sketch-tier serve")
-        for i in range(60):
-            t0 = time.perf_counter()
-            replica.service_duration_quantiles(
-                svcs[i % len(svcs)], [0.5, 0.99])
-            sk.observe(time.perf_counter() - t0)
-        p50_ms = sk.snapshot()["p50"] * 1e3
-
         status = shipper.status()
         cursors = wal.cursors()
         return {
@@ -1124,11 +917,8 @@ def run_replication() -> dict:
             "replica_answers_identical": bool(agree),
             "replication_recompiles": int(replication_compiles),
             "standby_bitwise": bool(standby_bitwise),
-            "failover_rto_s": round(max(rto_s, 1e-4), 4),
             "max_lag_records": int(max_lag),
             "caught_up": bool(caught_up),
-            "catch_up_s": round(catch_up_s, 3),
-            "replica_sketch_p50_ms": round(p50_ms, 3),
             "follower_cursor_pinned": bool(
                 cursors.get("smoke-replica", 0) >= 1),
         }
@@ -1190,9 +980,7 @@ def run_sharded() -> dict:
     store = ShardedSpanStore(mesh, config, dispatch_window_s=0.5)
     single = TpuSpanStore(config)
     try:
-        t0 = time.perf_counter()
         store.apply(spans)
-        ingest_s = time.perf_counter() - t0
         single.apply(spans)
         svcs = sorted(store.get_all_service_names())[:4]
         end_ts = 2**62
@@ -1238,11 +1026,9 @@ def run_sharded() -> dict:
             t.start()
         compiles0 = dev.compile_count()
         launches0 = store.collective_launches()
-        t0 = time.perf_counter()
         barrier.wait()
         for t in threads:
             t.join(timeout=120.0)
-        burst_s = time.perf_counter() - t0
         burst_launches = store.collective_launches() - launches0
         recompiles = dev.compile_count() - compiles0
 
@@ -1278,9 +1064,7 @@ def run_sharded() -> dict:
         return {
             "shards": store.n,
             "spans": len(spans),
-            "ingest_spans_per_s": round(len(spans) / ingest_s, 1),
             "burst_reads": 8,
-            "burst_ms": round(burst_s * 1e3, 2),
             "burst_launches": int(burst_launches),
             "steady_state_recompiles": int(recompiles),
             "dispatcher_batches": dstats["batches"],
@@ -1304,10 +1088,10 @@ def run_fleet_obs() -> dict:
     both processes' samples label-distinguished with values bitwise
     identical to each process's own scrape; (c) the stall watchdog
     fires on an injected parked-fsync error and clears when the error
-    does; (d) self-tracing at the production sampling cadence costs
-    ≤5% ingest wall time (paired min-of-N, lineage on vs off) and adds
+    does; (d) self-tracing at the production sampling cadence adds
     ZERO new device launches in steady state (compile-count delta 0,
-    fused-step census equality)."""
+    fused-step census equality). What it costs in ingest time is a
+    question for the chip: ROADMAP.md S11's lineage on/off pair."""
     import os
     import shutil
     import tempfile
@@ -1321,7 +1105,6 @@ def run_fleet_obs() -> dict:
         ShipServer,
         WalShipper,
     )
-    from zipkin_tpu.replicate.protocol import config_from_dict
     from zipkin_tpu.store import device as dev
     from zipkin_tpu.store.replica import ReplicaSpanStore
     from zipkin_tpu.store.tpu import TpuSpanStore
@@ -1363,7 +1146,7 @@ def run_fleet_obs() -> dict:
         freg = obs.Registry()
         rc = ShipClient("127.0.0.1", port, "smoke-fleet-replica",
                         mode="replica")
-        replica = ReplicaSpanStore(config_from_dict(
+        replica = ReplicaSpanStore(dev.config_from_dict(
             rc.connect()["config"]), background_compaction=False)
         stores.append(replica)
         flin = fobs.FollowerLineage("smoke-fleet-replica",
@@ -1442,19 +1225,13 @@ def run_fleet_obs() -> dict:
                           in fired["reasons"][0]["reason"])
         watchdog_cleared = bool(cleared["ready"] and len(rec_ring) == 2)
 
-        # -- (d) overhead + zero new device launches ------------------
+        # -- (d) zero new device launches -----------------------------
         def drive(store):
-            t0 = time.perf_counter()
             for i in range(0, len(spans), chunk):
                 store.apply(spans[i:i + chunk])
-            return time.perf_counter() - t0
 
-        off = TpuSpanStore(config)
+        off = TpuSpanStore(config)  # no lineage: the census's other side
         stores.append(off)
-        wal_off = WriteAheadLog(os.path.join(root, "wal-off"),
-                                fsync="off")
-        wals.append(wal_off)
-        off.attach_wal(wal_off)
         on = TpuSpanStore(config)
         stores.append(on)
         wal_on = WriteAheadLog(os.path.join(root, "wal-on"),
@@ -1463,13 +1240,12 @@ def run_fleet_obs() -> dict:
         on.attach_wal(wal_on)
         trk_on = fobs.LineageTracker(on.apply, registry=obs.Registry())
         on.attach_lineage(trk_on)  # production cadence (1-in-64)
-        drive(off), drive(on)  # warm every pad bucket both will hit
+        drive(on)  # warm every pad bucket the gated drive will hit
         compiles0 = dev.compile_count() + dev.query_compile_count()
-        t_off = min(drive(off) for _ in range(3))
-        t_on = min(drive(on) for _ in range(3))
+        drive(on)
         lineage_compiles = (dev.compile_count()
                             + dev.query_compile_count() - compiles0)
-        overhead_ratio = t_on / t_off if t_off > 0 else 0.0
+
         def _census(store):
             db = dev.make_device_batch(
                 *ColumnarTraceGen(store.dicts, n_services=8)
@@ -1490,9 +1266,6 @@ def run_fleet_obs() -> dict:
             "visible_lag_recorded": bool(visible_lag_recorded),
             "watchdog_fired": bool(watchdog_fired),
             "watchdog_cleared": bool(watchdog_cleared),
-            "overhead_ratio": round(overhead_ratio, 4),
-            "lineage_on_s": round(t_on, 4),
-            "lineage_off_s": round(t_off, 4),
             "lineage_steady_state_compiles": int(lineage_compiles),
             "census_equal": census_on == census_off,
             "fleet_processes": len(fleet.status()["processes"]),
@@ -1524,14 +1297,13 @@ def run_lint() -> dict:
     whole package against the checked-in baseline. Zero NEW findings
     is the gate — the lock-order/guarded-by/sync-under-lock/jit
     conventions the write path depends on stay machine-checked on
-    every CI run, inside the analyzer's 30s budget."""
+    every CI run."""
     import os
 
     from zipkin_tpu.analysis import ALL_RULES, analyze, load_project
     from zipkin_tpu.analysis import baseline as lint_baseline
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    t0 = time.perf_counter()
     project = load_project([os.path.join(repo, "zipkin_tpu")], repo)
     findings = analyze(project)
     base_path = os.path.join(repo, "graftlint-baseline.json")
@@ -1548,7 +1320,6 @@ def run_lint() -> dict:
         "findings_new": len(new),
         "stale_baseline_entries": len(stale),
         "new": [f.render() for f in new[:20]],
-        "elapsed_s": round(time.perf_counter() - t0, 2),
     }
 
 
@@ -1594,37 +1365,19 @@ def run(total_spans: int = 7000, k_queries: int = 8) -> dict:
     ops = _count_ops(dev.ingest_step.lower(state, dbs[0]).as_text())
     cb_ops = _count_ops(dev.counter_block.lower(state).as_text())
 
-    # Fused-ingest timing (compile excluded: first step warms). The
-    # warm-up step's spans are excluded from the rate — spans_seen is
-    # snapshotted before t0 so the numerator matches the timed window.
-    # The timed loop stays ASYNC (dispatch pipelining included), the
-    # r6 methodology — ingest_spans_per_s remains trend-comparable.
+    # The main stream: every batch through the fused step. "spans" is
+    # what the device counted past the first (compiling) step.
     state = dev.ingest_step(state, dbs[0])
     import jax
 
     warm = int(jax.device_get(state.counters["spans_seen"]))
-    t0 = time.perf_counter()
     for db in dbs:
         state = dev.ingest_step(state, db)
     seen = int(jax.device_get(state.counters["spans_seen"]))
-    dt = time.perf_counter() - t0
     total = seen - warm
-    # Telemetry sketch pass: a SEPARATE loop, synced per step
-    # (device_get is the reliable barrier), so the per-step p50/p99
-    # never perturbs the throughput window above.
-    from zipkin_tpu import obs
-
-    step_sketch = obs.LatencySketch(
-        "bench_ingest_step_seconds", "per-step wall time")
-    for db in dbs:
-        ts_step = time.perf_counter()
-        state = dev.ingest_step(state, db)
-        jax.device_get(state.write_pos)
-        step_sketch.observe(time.perf_counter() - ts_step)
-    seen = int(jax.device_get(state.counters["spans_seen"]))
     store.adopt_state(state, spans_written=seen)
 
-    # Batched-query scaling: k singular launches vs one multi launch.
+    # Batched queries: k singular launches against one multi launch.
     end_ts = int(jax.device_get(state.ts_max)) + 1
     svcs = sorted(store.get_all_service_names())
     queries = [
@@ -1639,24 +1392,14 @@ def run(total_spans: int = 7000, k_queries: int = 8) -> dict:
     def batched():
         return store.get_trace_ids_multi(queries)
 
-    serial(), batched()  # warm both paths' compile caches
-    t0 = time.perf_counter()
     want = serial()
-    serial_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
     got = batched()
-    batched_s = time.perf_counter() - t0
     identical = [
         [(i.trace_id, i.timestamp) for i in ids] for ids in got
     ] == [
         [(i.trace_id, i.timestamp) for i in ids] for ids in want
     ]
 
-    step_ms = {
-        k: (round(v * 1e3, 3) if k in ("sum", "mean", "stddev", "p50",
-                                       "p99") and v == v else v)
-        for k, v in step_sketch.snapshot().items()
-    }
     from zipkin_tpu.store import census
 
     return {
@@ -1681,8 +1424,6 @@ def run(total_spans: int = 7000, k_queries: int = 8) -> dict:
             "gather": census.BASE_STEP_GATHERS,
         },
         "spans": total,
-        "ingest_spans_per_s": round(total / dt, 1),
-        "ingest_ms_per_batch": round(dt / len(dbs) * 1e3, 2),
         "step_scatters": ops["scatter"],
         "step_gathers": ops["gather"],
         "step_sorts": ops["sort"],
@@ -1690,13 +1431,9 @@ def run(total_spans: int = 7000, k_queries: int = 8) -> dict:
             "counter_block": store.counter_block(),
             "counter_block_scatters": cb_ops["scatter"],
             "counter_block_sorts": cb_ops["sort"],
-            "ingest_step_ms": step_ms,
         },
         "multi_query": {
             "k": k_queries,
-            "serial_ms": round(serial_s * 1e3, 2),
-            "batched_ms": round(batched_s * 1e3, 2),
-            "speedup": round(serial_s / batched_s, 2) if batched_s else 0,
             "identical": identical,
         },
     }

@@ -140,9 +140,9 @@ _SLOW_TESTS = {
     "test_replica_retention_drops_old_segments",
     "test_standby_follow_promote_bitwise",
     # Paged-layout deep coverage (tests/test_paged.py): tier-1 keeps
-    # the SPI conformance sweep, the Pallas/XLA bitwise gate, planner
-    # geometry guards, the reclaim fuzz, rev-18 + pre-18 checkpoint
-    # compat, and WAL-replay bitwise; bench_smoke's paged phase gates
+    # the SPI conformance sweep, planner geometry guards, the reclaim
+    # fuzz, rev-18 + pre-18 checkpoint compat, and WAL-replay bitwise;
+    # bench_smoke's paged phase gates
     # census arithmetic, ring-vs-paged bitwise parity and the
     # zero-recompile bound every tier-1 run, so the long skewed-stream
     # parity drive, the tiered eviction/capture drive, the mirror
@@ -156,8 +156,9 @@ _SLOW_TESTS = {
 
 # The suite's CPU-hungriest files (TPU compiles for a described chip; a
 # daemon child plus a g++ build). They run after everything else, so
-# they never share the first minutes with bench_smoke's paired-timing
-# gates. A stable sort: every xdist worker collects the same order.
+# they do not starve the tests that wait on threads (bench_smoke's
+# barrier-released burst has a 0.5 s window to land in one batch). A
+# stable sort: every xdist worker collects the same order.
 _LAST_FILES = {"test_chip_compile.py", "test_chip_smoke.py"}
 
 

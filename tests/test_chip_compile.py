@@ -1,19 +1,16 @@
 """Chipless compile guards: the served path's device programs, compiled
 for a DESCRIBED v5e by the installed TPU compiler (no chip attached).
 
-Interpret-mode tests cannot see what Mosaic or the TPU backend refuse
-(64-bit leaks in a kernel body, unaligned slices, VMEM overruns, a
-program that does not fit HBM). These cases compile the fused ingest
-step (both rank paths), one index-hit read, the trace gather and the
-Pallas kernels at the geometry the daemon serves, so every later PR
-meets the chip's compiler at no chip time. Nothing runs: a compile that
+CPU tests cannot see what the TPU backend refuses (a program that does
+not fit HBM, a ring copied where it should be written in place). These
+cases compile the fused ingest step (both rank paths), one index-hit
+read and the trace gather at the geometry the daemon serves, so every
+later PR meets the chip's compiler at no chip time. Nothing runs: a compile that
 passes says nothing about results or speed.
 
-The chip takes branches the CPU suites never take — ``rank_mode``'s
-"auto", ``gather_paged_trace_rows`` and ``pallas_kernels._interpret``
-all ask ``jax.default_backend()`` while tracing — so the cases steer
-them HERE (explicit ``rank_path``, a monkeypatched ``_interpret``),
-never through an option of the program.
+The chip takes a branch the CPU suites never take — ``rank_mode``'s
+"auto" asks ``jax.default_backend()`` while tracing — so the cases
+steer it HERE (explicit ``rank_path``).
 
 The topology is described inside a module-scoped fixture: only one
 process may load the TPU library, so no topology call may run at import
@@ -30,7 +27,6 @@ import numpy as np
 import pytest
 
 from zipkin_tpu.columnar.schema import SpanBatch
-from zipkin_tpu.ops import pallas_kernels as PK
 from zipkin_tpu.store import device as dev
 
 # The daemon's default geometry (main/example.py: --capacity 65536,
@@ -70,12 +66,6 @@ def no_persistent_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
-
-
-@pytest.fixture
-def mosaic(monkeypatch):
-    """Compile the Pallas kernels for Mosaic, as the chip does."""
-    monkeypatch.setattr(PK, "_interpret", lambda: False)
 
 
 def _abstract(tree, sharding, lead=()):
@@ -289,43 +279,3 @@ def test_trace_gather_compiles(one_chip):
         st.write_pos, st.ann_write_pos, st.bann_write_pos,
         CONFIG.capacity, CONFIG.ann_capacity, CONFIG.bann_capacity,
         256, 512, 256, False))
-
-
-def test_flat_histogram_compiles(one_chip, mosaic):
-    """The per-service latency histogram (256 services x 2048 buckets,
-    m = 524288 f32) at the cert launch's 114688 rows."""
-    n, m = 114688, 524288
-    _compiled_on_tpu(PK.flat_histogram.lower(
-        _spec((n,), jnp.int32, one_chip),
-        _spec((n,), jnp.float32, one_chip), m=m))
-
-
-def test_cms_update_compiles(one_chip, mosaic):
-    """Count-min update at the default sketch: 4 x 65536 i32."""
-    d, w, n = 4, 65536, PADS[0]
-    _compiled_on_tpu(jax.jit(PK.cms_update).lower(
-        _spec((d, w), jnp.int32, one_chip),
-        _spec((d, n), jnp.int32, one_chip)))
-
-
-def test_paged_page_gather_compiles(one_chip, mosaic):
-    """The paged trace-assembly block gather at the cert ring: 2^22
-    rows in 128-row pages, 12 columns as 24 bit-planes, 64 pages."""
-    capacity, page_rows, w, k = 1 << 22, 128, 24, 64
-    assert PK.paged_gather_supported(capacity, page_rows, w // 2, k)
-    _compiled_on_tpu(PK.paged_page_gather.lower(
-        _spec((w, capacity), jnp.int32, one_chip),
-        _spec((k,), jnp.int32, one_chip), page_rows=page_rows))
-
-
-def test_arena_claim_scatter_compiles(one_chip, mosaic):
-    """The fused claim + entry scatter (behind --use-pallas) at a
-    VMEM-resident arena: 2^15 slots, 2^10 buckets, 4096 rows."""
-    s, nb, n = 1 << 15, 1 << 10, 4096
-    assert PK.arena_scatter_supported(s, nb)
-    i32 = _spec((n,), jnp.int32, one_chip)
-    planes = (_spec((s,), jnp.int32, one_chip),) * dev.ARENA_PLANES
-    _compiled_on_tpu(PK.arena_claim_scatter.lower(
-        planes, i32, i32, i32, i32,
-        _spec((n, 3), jnp.int64, one_chip),
-        _spec((n,), jnp.bool_, one_chip), n_buckets=nb))

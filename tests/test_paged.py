@@ -1,6 +1,5 @@
 """Paged span layout (r19): conformance, bitwise ring parity, page
-reclaim under wrap, Pallas-vs-XLA gather identity, rev-18 checkpoint
-and WAL replay determinism.
+reclaim under wrap, rev-18 checkpoint and WAL replay determinism.
 
 The layout contract under test (docs/STORAGE_TIERS.md): spans land in
 fixed ``page_rows`` device pages claimed from a free list during the
@@ -20,7 +19,6 @@ import pytest
 
 from zipkin_tpu import checkpoint
 from zipkin_tpu.models.span import Annotation, BinaryAnnotation, Endpoint, Span
-from zipkin_tpu.store import device as dev
 from zipkin_tpu.store.census import expected_census
 from zipkin_tpu.store.device import StoreConfig
 from zipkin_tpu.store.paged import PagePlanner
@@ -38,8 +36,7 @@ CFG_RING = StoreConfig(
     max_binary_keys=64, cms_width=1 << 10, hll_p=8,
     quantile_buckets=512,
 )
-# 1024 / 128 = 8 pages — the planner's minimum pool, and 128 rows is
-# lane-aligned so the Pallas gather path is eligible on TPU.
+# 1024 / 128 = 8 pages — the planner's minimum pool.
 CFG_PAGED = CFG_RING._replace(layout="paged", page_rows=128)
 
 BASE_TS = 1_700_000_000_000_000
@@ -170,52 +167,6 @@ def test_mirror_is_layout_independent():
     for i, (a, b) in enumerate(zip(ring.sketch_mirror.arrays(),
                                    paged.sketch_mirror.arrays())):
         np.testing.assert_array_equal(a, b, err_msg=f"mirror array {i}")
-
-
-# ---------------------------------------------------------------------------
-# Pallas page gather == XLA take fallback, bitwise
-# ---------------------------------------------------------------------------
-
-
-def test_pallas_and_xla_page_gather_bitwise_identical():
-    """Both lowering paths of _paged_gather_impl feed the same per-row
-    (slot, epoch) validity mask and mask dead rows to -1, so their four
-    output arrays must be bit-for-bit equal (the kernel runs in
-    interpreter mode on CPU)."""
-    spans, sizes = _skewed_stream(seed=5, total=700)
-    store = TpuSpanStore(CFG_PAGED)
-    _drive(store, spans)
-
-    qids = np.asarray(sorted(sizes)[:24], np.int64)
-    chains = store._planner.chains_for(qids)
-    assert chains is not None
-    pages, epochs = chains
-    assert len(pages) >= 2  # stream is big enough to span pages
-    k = max(2, 1 << (len(pages) - 1).bit_length())
-    pages = np.concatenate([pages, np.full(k - len(pages), -1, np.int32)])
-    epochs = np.concatenate([epochs, np.zeros(k - len(epochs), np.int64)])
-
-    state = store.state
-    c = state.config
-
-    def gather(pallas: bool):
-        return dev._paged_gather_impl(
-            tuple(getattr(state, col) for col in dev.SPAN_MAT_COLS),
-            tuple(getattr(state, col) for col in dev.ANN_MAT_COLS),
-            tuple(getattr(state, col) for col in dev.BANN_MAT_COLS),
-            jax.numpy.asarray(qids),
-            jax.numpy.asarray(pages), jax.numpy.asarray(epochs),
-            state.ann_write_pos, state.bann_write_pos,
-            c.capacity, c.page_rows, c.ann_capacity, c.bann_capacity,
-            256, 512, 256, pallas,
-        )
-
-    out_p = jax.device_get(gather(True))
-    out_x = jax.device_get(gather(False))
-    names = ("counts", "span_mat", "ann_mat", "bann_mat")
-    for name, a, b in zip(names, out_p, out_x):
-        np.testing.assert_array_equal(a, b, err_msg=name)
-    assert int(out_p[0][0]) == sum(sizes[int(t)] for t in qids)
 
 
 # ---------------------------------------------------------------------------

@@ -22,7 +22,7 @@ from zipkin_tpu.replicate import (
     StandbyTarget,
     WalShipper,
 )
-from zipkin_tpu.replicate.protocol import config_from_dict
+from zipkin_tpu.store.device import config_from_dict
 from zipkin_tpu.store import device as dev
 from zipkin_tpu.store.archive import TieredSpanStore
 from zipkin_tpu.store.replica import ReplicaSpanStore, ReplicaReadOnlyError

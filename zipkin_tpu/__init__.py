@@ -1,7 +1,7 @@
 """zipkin-tpu: a TPU-native distributed-tracing analytics framework.
 
 Re-implements the capability surface of Twitter Zipkin (reference:
-/root/reference, Scala/Finagle) as an idiomatic JAX/XLA/Pallas design:
+/root/reference, Scala/Finagle) as an idiomatic JAX/XLA design:
 
 - span ingest with backpressure + adaptive sampling (zipkin-collector,
   zipkin-sampler)
@@ -14,7 +14,7 @@ Re-implements the capability surface of Twitter Zipkin (reference:
 - a JSON/HTTP API mirroring zipkin-web's routes, and a vectorized
   tracegen benchmark harness (zipkin-tracegen)
 
-The compute path is JAX (jit/shard_map/pallas); strings live in a host
+The compute path is JAX (jit/shard_map); strings live in a host
 dictionary encoder, the device sees only fixed-width integers/floats.
 """
 

@@ -694,7 +694,7 @@ def load(path: str, mesh=None, config_defaults=None):
     cfg_map = dict(meta["config"])
     for k, v in (config_defaults or {}).items():
         cfg_map.setdefault(k, v)
-    config = dev.StoreConfig(**cfg_map)
+    config = dev.config_from_dict(cfg_map)
 
     dicts = DictionarySet.__new__(DictionarySet)
     from zipkin_tpu.columnar.dictionary import Dictionary

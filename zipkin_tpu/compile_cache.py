@@ -2,8 +2,7 @@
 
 Cold compiles at serving geometry cost minutes (a 2^22 ring's fused
 step alone is tens of seconds per launch shape), so the long-running
-entry points (``main/example.py``, ``bench.py``) keep compiled programs
-on disk. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself
+entry point (``main/example.py``) keeps compiled programs on disk. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself
 and nothing is set in code; otherwise the cache lives at the FIXED path
 ``<checkout>/.jax_cache`` — the path is part of the cache key, so a
 directory that moves (temp name, pid, home) never hits.

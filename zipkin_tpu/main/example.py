@@ -44,20 +44,13 @@ def build_parser() -> argparse.ArgumentParser:
                         "only; echoed at /vars/layout)")
     p.add_argument("--page-rows", type=int, default=128,
                    help="rows per page for --layout paged (power of "
-                        "two dividing --capacity; 128 keeps the "
-                        "Pallas gather lane-aligned — echoed at "
+                        "two dividing --capacity; echoed at "
                         "/vars/pageRows)")
     p.add_argument("--batch-spans", type=int, default=0,
                    help="ingest batch escalation: max spans per device "
                         "launch (0 = the store's legacy 4096 default; "
                         "the ring guards still clamp to capacity/2 — "
                         "see docs/PERFORMANCE.md for picking the knee)")
-    p.add_argument("--use-pallas", action="store_true",
-                   help="route ingest scatter-adds (and, when the "
-                        "index arena fits VMEM, the fused claim+"
-                        "scatter) through the pallas kernels instead "
-                        "of XLA scatter; the active path is reported "
-                        "in counters()/metrics (scatter_path_pallas)")
     p.add_argument("--rank-path", default="auto",
                    choices=("auto", "argsort", "counting"),
                    help="index-write FIFO rank implementation (both "
@@ -294,7 +287,6 @@ def build_app(args):
                     capacity=args.capacity,
                     **_side_rings(args.capacity),
                     batch_spans=args.batch_spans,
-                    use_pallas=args.use_pallas,
                     rank_path=args.rank_path,
                     window_seconds=args.window_seconds,
                     window_buckets=args.window_buckets,
@@ -311,7 +303,6 @@ def build_app(args):
                 capacity=args.capacity,
                 **_side_rings(args.capacity),
                 batch_spans=args.batch_spans,
-                use_pallas=args.use_pallas,
                 rank_path=args.rank_path,
                 window_seconds=args.window_seconds,
                 window_buckets=args.window_buckets,
@@ -481,7 +472,7 @@ def build_follower_app(args):
         ShipClient,
         StandbyTarget,
     )
-    from zipkin_tpu.replicate.protocol import config_from_dict
+    from zipkin_tpu.store.device import config_from_dict
 
     host, _, port = args.follow.rpartition(":")
     if not host or not port.isdigit():

@@ -13,8 +13,7 @@ dispatch. QueryCoalescer adds the cross-request tier: the first
 arriving thread becomes the micro-batch LEADER, waits ``window_s`` for
 followers, then executes the union through one get_trace_ids_multi
 call and hands each caller its slice. Aggregate query throughput then
-scales with concurrency instead of serializing on the dispatch floor
-(bench.py's batched-query phase measures exactly this).
+scales with concurrency instead of serializing on the dispatch floor.
 
 Correctness: get_trace_ids_multi resolves every query independently
 (data-independent probes in one kernel; per-query scan fallbacks run

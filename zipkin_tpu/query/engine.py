@@ -1,8 +1,8 @@
 """Resident query engine: three latency tiers over the SpanStore SPI.
 
 Every on-device query used to pay the same ~105–115 ms p50 at 1B spans
-regardless of work (BENCH_1B.json) — the cost is per-request dispatch
-+ D2H, not compute. The engine splits the read path so most requests
+regardless of work (r5 code; PERF.md 6, "r4/r5 records") — the cost is
+per-request dispatch + D2H, not compute. The engine splits the read path so most requests
 never touch the device at all, and the ones that must share launches:
 
 1. **Sketch tier** — quantiles, top-k annotations/keys, HLL

@@ -199,13 +199,3 @@ def decode_anchor(meta: dict, blob: bytes):
 def config_to_dict(config) -> dict:
     """A StoreConfig as a JSON-safe dict (NamedTuple of scalars)."""
     return {k: v for k, v in config._asdict().items()}
-
-
-def config_from_dict(d: dict):
-    from zipkin_tpu.store.device import StoreConfig
-
-    base = StoreConfig()._asdict()
-    # Ignore fields this build doesn't know (forward compat) and let
-    # the defaults fill ones the primary didn't send.
-    base.update({k: v for k, v in d.items() if k in base})
-    return StoreConfig(**base)

@@ -208,10 +208,3 @@ def _stablehlo_sweeps(stablehlo_text: str, sizes: set, row_ops) -> list:
         if any(sizes & set(d.split("x")) for d in dims.findall(ln)):
             out.append(op or ln[:60])
     return out
-
-
-# Stage-1 sketch-mirror budget: the host COO delta (store/mirror,
-# riding the hot encode path since r11) may add at most this fraction
-# to the encode stage — bench_smoke's ingest-structure phase measures
-# it paired and the tier-1 test gates it.
-MAX_MIRROR_DELTA_RATIO = 0.05

@@ -106,22 +106,12 @@ class StoreConfig(NamedTuple):
     # Per-key cursor table slots (0 = 2x total candidate buckets). See
     # StoreState.key_tab.
     idx_key_slots: int = 0
-    # Route ingest scatter-adds through the VMEM-resident pallas
-    # histogram kernels (ops/pallas_kernels.py) instead of XLA scatter.
-    # Benchmarked on the real chip by bench.py --compare-kernels; arrays
-    # whose size is not a multiple of 128 lanes fall back to XLA.
-    # With r12 this also routes the index-arena entry scatter through
-    # the grid-sequential claim+scatter kernel WHEN the arena fits VMEM
-    # (pallas_kernels.arena_scatter_supported); bigger arenas keep the
-    # XLA plane-scatter path (the NOTES_r06 §3 roofline boundary).
-    use_pallas: bool = False
     # Host-side per-launch span bound (the ingest batch-escalation knob,
     # r12): 0 keeps the store's legacy MAX_CHUNK default (4096); larger
     # values let one launch carry more spans, amortizing the per-launch
-    # scatter entry costs — re-measure the knee with
-    # scripts/profile_ingest.py --batch-spans-sweep / bench.py
-    # --ingest-matrix. The ring-capacity guards (capacity//2,
-    # pending_slots, ann/bann rings) still clamp it per launch.
+    # scatter entry costs (scripts/step_time.py times a step on the
+    # chip). The ring-capacity guards (capacity//2, pending_slots,
+    # ann/bann rings) still clamp it per launch.
     batch_spans: int = 0
     # FIFO-rank computation for the unified index write (_index_write):
     # "argsort" = the r6 stable rank sort; "counting" = the r12
@@ -162,9 +152,7 @@ class StoreConfig(NamedTuple):
     # historical FIFO layout; its fused-step lowering is byte-identical
     # with these fields present (static branch, store/census.py BASE).
     layout: str = "ring"
-    # Rows per device page. Power of two >= 8; multiples of 128 keep
-    # the pallas page-gather kernel eligible (lane-aligned sublane
-    # slices — see ops/pallas_kernels.paged_gather_supported).
+    # Rows per device page. Power of two >= 8.
     page_rows: int = 256
     # Host page-table chain bound per trace: a trace spanning more
     # pages than this stops being page-addressable and its reads fall
@@ -337,6 +325,16 @@ class StoreConfig(NamedTuple):
         return win_x_shift(self.quantile_buckets)
 
 
+def config_from_dict(d: dict) -> StoreConfig:
+    """The StoreConfig a stored or shipped dict describes (a snapshot's
+    meta.json, the replication handshake): keys this build does not
+    know are dropped, keys the dict lacks take the defaults, so an
+    option can be added or deleted without stranding what was written
+    before."""
+    return StoreConfig(**{
+        k: v for k, v in d.items() if k in StoreConfig._fields})
+
+
 def _next_pow2_int(n: int) -> int:
     p = 1
     while p < n:
@@ -359,7 +357,7 @@ def _pack_layout(fams):
 
 # -- fast scatter primitives -------------------------------------------------
 #
-# Measured on the real chip (scripts/profile_scatter*.py, round 4): any
+# Measured on the real chip (round 4; PERF.md 6, "r4/r5 records"): any
 # 64-bit scatter (set/add/min/max) on this backend serializes at
 # ~100-125 ns/row — a 917k-row index write costs ~100 ms — while 1-D
 # int32 scatter-set with unique indices vectorizes at ~4.5 ns/row, and
@@ -605,7 +603,7 @@ def _slot_war(slot, packed, active, n_slots: int):
     smallest ``packed`` wins — bitwise the same outcome as the old
     ``.at[slot].min(packed)`` + re-read, but built from sorts and
     elementwise ops (i64 scatters serialize at ~100 ns/row on this
-    backend; sorts are nearly free — scripts/profile_scatter*.py).
+    backend; sorts are nearly free — PERF.md 6, "r4/r5 records").
 
     Returns (seg_min, write_row), both in ORIGINAL row order:
     ``seg_min`` is the minimum packed offered at the row's slot this
@@ -1036,17 +1034,17 @@ def init_state(config: StoreConfig = StoreConfig()) -> StoreState:
     )
 
 
-def _scatter_add(counts, idx, weights, use_pallas: bool):
+def _scatter_add(counts, idx, weights):
     """``counts.reshape(-1)[idx] += weights`` with idx < 0 dropped —
     the one primitive behind every ingest counter/presence/sketch
     update (the reference's 5-index-writes-per-span hot loop,
-    processor/IndexService.scala:30-38). Dispatches to the
-    VMEM-resident pallas kernel when enabled and lane-aligned."""
-    from zipkin_tpu.ops import pallas_kernels as PK
-
-    if use_pallas and counts.size % PK.LANES == 0:
-        return PK.histogram_update(counts, idx, weights)
-    return PK.scatter_histogram_xla(counts, idx, weights)
+    processor/IndexService.scala:30-38)."""
+    flat = counts.reshape(-1)
+    m = flat.shape[0]
+    safe = jnp.where(idx >= 0, idx, m)
+    out = jnp.concatenate([flat, jnp.zeros(1, flat.dtype)])
+    out = out.at[safe].add(weights.astype(flat.dtype))
+    return out[:m].reshape(counts.shape)
 
 
 def svc_histogram(state: StoreState) -> Q.LogHistogram:
@@ -1447,7 +1445,7 @@ def _tab_insert(tab, key48, svc, valid):
     # from sorts and one unique plane scatter. The table itself lives
     # in i32 plane form (StoreState.span_tab): probe loads are i32 row
     # gathers, writes i32 plane scatters — i64 gathers/scatters are the
-    # serialized class on this backend (profile_scatter*.py).
+    # serialized class on this backend (PERF.md 6, "r4/r5 records").
     for slot in slots:
         cur = _p64(tab[slot])
         curu = cur.astype(jnp.uint64)
@@ -1606,8 +1604,8 @@ def _fifo_ranks_counting(bucket, valid, n_buckets: int, block: int):
     g = rows // jnp.int32(block)
     sidx = b_eff * jnp.int32(groups) + g
     # Per-(bucket, block) occupancy — duplicate-index i32 scatter-add,
-    # the vectorized class (profile_scatter*.py); indices are in-range
-    # by construction, mode="drop" is belt-and-braces.
+    # the vectorized class (PERF.md 6, "r4/r5 records"); indices are
+    # in-range by construction, mode="drop" is belt-and-braces.
     cnt = jnp.zeros((n_buckets + 1) * groups, jnp.int32).at[sidx].add(
         1, mode="drop")
     cnt2 = cnt.reshape(n_buckets + 1, groups)
@@ -1627,7 +1625,7 @@ def _fifo_ranks_counting(bucket, valid, n_buckets: int, block: int):
     return pre + w
 
 
-# Active-path registry: which rank / arena-scatter implementations each
+# Active-path registry: which rank and ring-write implementations each
 # StoreConfig's compiled steps actually took (trace-time records — one
 # entry per compile, so steady state writes nothing). Surfaced through
 # TpuSpanStore.counters() -> /metrics and the bench JSON, so every
@@ -1649,7 +1647,7 @@ def _note_path(config: StoreConfig, kind: str, value: str) -> None:
 
 
 def active_paths(config: StoreConfig) -> Dict[str, Tuple[str, ...]]:
-    """{"rank": ("counting", ...), "scatter": ("xla", ...),
+    """{"rank": ("counting", ...),
     "ring_write": ("ann:window", "bann:window", "pend:window",
     "span:window")} — every implementation this config's compiled
     ingest steps used (may hold both when different launch shapes
@@ -1669,7 +1667,7 @@ def _index_write(entries, pos, wm, key_tab, key_wm, ann_poison,
                  keyed_from: int, n_cand_rows: int, n_cand_buckets: int,
                  poison_bucket=None, poison_gid=None, poison_ok=None,
                  wm_shift: int = 0, ts_shift: int = _WM_TS_SHIFT,
-                 rank_sel=("argsort", 0), scatter_mode: str = "xla"):
+                 rank_sel=("argsort", 0)):
     """ONE combined append of (gid, verify, ts) rows into the UNIFIED
     index arena — candidate families and trace-membership families
     alike: ``gbucket`` is the global bucket id (addressing pos/wm),
@@ -1769,23 +1767,7 @@ def _index_write(entries, pos, wm, key_tab, key_wm, ann_poison,
     tr_ok = occupied[trc] | (valid[trc] & ~keep[trc])
     verify = jnp.asarray(verify, jnp.int64)
     vals = jnp.stack([gid, verify, jnp.asarray(ts, jnp.int64)], axis=-1)
-    if scatter_mode == "pallas":
-        # Grid-sequential fused claim+scatter (ops/pallas_kernels):
-        # the kernel re-derives each row's FIFO slot from a
-        # VMEM-resident cursor walk (claim) and writes ALL valid rows
-        # in arrival order — in-batch overflow rows are overwritten by
-        # their newest same-slot successor, which lands the bitwise
-        # SAME final arena as the rank-gated unique scatter (every
-        # dropped row's slot is rewritten by the rank+depth successor
-        # that displaced it). `keep`/`rank` stay load-bearing for the
-        # displacement bookkeeping above/below either way.
-        from zipkin_tpu.ops import pallas_kernels as PK
-
-        entries = PK.arena_claim_scatter(
-            entries, b_c, pos_b, slot0, depth, vals, valid,
-            n_buckets=n_b)
-    else:
-        entries = _arena_set(entries, slot, vals, keep)
+    entries = _arena_set(entries, slot, vals, keep)
     pos = pos + cnt.astype(pos.dtype)
 
     # -- per-key fingerprint records (suffix rows only) ----------------
@@ -2645,18 +2627,9 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
         # compiled steps actually used. Both rank paths are bitwise-
         # identical, so a mixed-shape store (different pad buckets
         # picking different modes) still lands one deterministic state.
-        from zipkin_tpu.ops import pallas_kernels as PK
-
         rank_sel = rank_mode(
             c.rank_path, cat[0].shape[0], c.idx_layout[1], wm_shift)
-        scatter_mode = (
-            "pallas"
-            if c.use_pallas and PK.arena_scatter_supported(
-                c.idx_layout[2], c.idx_layout[1])
-            else "xla"
-        )
         _note_path(c, "rank", rank_sel[0])
-        _note_path(c, "scatter", scatter_mode)
         with jax.named_scope("ingest.index_write"):
             (upd["cand_idx"], upd["cand_pos"], upd["cand_wm"],
              upd["key_tab"], upd["key_wm"], upd["ann_poison"],
@@ -2669,7 +2642,7 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
                 poison_bucket=a_host, poison_gid=span_gid_of_ann,
                 poison_ok=mid,
                 wm_shift=wm_shift,
-                rank_sel=rank_sel, scatter_mode=scatter_mode,
+                rank_sel=rank_sel,
             )
 
     # -- per-service latency histogram ---------------------------------
@@ -2683,20 +2656,20 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
         upd["svc_hist"] = _scatter_add(
             state.svc_hist,
             jnp.where(svc_ok, g * c.quantile_buckets + bidx, -1),
-            ones_p, c.use_pallas,
+            ones_p,
         )
 
         # -- counters / presence matrices ----------------------------------
         svc_cnt_ok = mask & (b.service_id >= 0) & (b.service_id < S)
         upd["svc_span_counts"] = _scatter_add(
             state.svc_span_counts, jnp.where(svc_cnt_ok, b.service_id, -1),
-            ones_p, c.use_pallas,
+            ones_p,
         )
         a_svc = b.ann_service_id
         a_svc_ok = mask_a & (a_svc >= 0) & (a_svc < S)
         upd["ann_svc_counts"] = _scatter_add(
             state.ann_svc_counts, jnp.where(a_svc_ok, a_svc, -1),
-            ones_a, c.use_pallas,
+            ones_a,
         )
 
         # span-name presence keyed by annotation-host service (the semantics
@@ -2711,7 +2684,7 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
         upd["name_presence"] = _scatter_add(
             state.name_presence,
             jnp.where(np_ok, a_svc * c.max_span_names + ann_name, -1),
-            ones_a, c.use_pallas,
+            ones_a,
         )
 
         # top annotations per service (user annotations only).
@@ -2723,7 +2696,7 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
         upd["ann_value_counts"] = _scatter_add(
             state.ann_value_counts,
             jnp.where(av_ok, a_svc * c.max_annotation_values + b.ann_value_id, -1),
-            ones_a, c.use_pallas,
+            ones_a,
         )
 
         bk_svc = b.bann_service_id
@@ -2734,7 +2707,7 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
         upd["bann_key_counts"] = _scatter_add(
             state.bann_key_counts,
             jnp.where(bk_ok, bk_svc * c.max_binary_keys + b.bann_key_id, -1),
-            jnp.ones(PB, jnp.int32), c.use_pallas,
+            jnp.ones(PB, jnp.int32),
         )
 
         # -- probabilistic state -------------------------------------------
@@ -2750,7 +2723,7 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
         cms_flat = jnp.where(mask[None, :], cms_flat, -1).reshape(-1)
         upd["cms_trace_spans"] = _scatter_add(
             state.cms_trace_spans, cms_flat,
-            jnp.ones(c.cms_depth * P, jnp.int32), c.use_pallas,
+            jnp.ones(c.cms_depth * P, jnp.int32),
         )
 
     # -- windowed Moments-sketch arena ---------------------------------
@@ -2788,7 +2761,7 @@ def ingest_step(state: StoreState, b: DeviceBatch) -> StoreState:
                 jnp.where(d_ok, base3 + 2, -1),
             ])
             upd["win_counts"] = _scatter_add(
-                counts_w, idx_c, jnp.ones(3 * P, jnp.int32), c.use_pallas
+                counts_w, idx_c, jnp.ones(3 * P, jnp.int32)
             )
             flat_s = sums_w.reshape(-1)
             xi = x.astype(jnp.int64)
@@ -3685,12 +3658,12 @@ def gather_trace_rows(
     )
 
 
-@partial(jax.jit, static_argnums=(8, 9, 10, 11, 12, 13, 14, 15))
+@partial(jax.jit, static_argnums=(8, 9, 10, 11, 12, 13, 14))
 def _paged_gather_impl(
     span_cols, ann_cols, bann_cols, sorted_qids, pages, epochs,
     ann_write_pos, bann_write_pos,
     capacity: int, page_rows: int, ann_capacity: int, bann_capacity: int,
-    k_spans: int, k_anns: int, k_banns: int, pallas: bool,
+    k_spans: int, k_anns: int, k_banns: int,
 ):
     """Paged trace assembly (r19): gather span rows from an explicit
     page list instead of scanning the whole ring.
@@ -3701,11 +3674,8 @@ def _paged_gather_impl(
     the expected gid of slot (p, j) is epoch*capacity + p*R + j, and a
     gathered row counts only when its live row_gid equals that AND its
     trace_id is one of ``sorted_qids`` (pages are shared by small
-    traces, so a page may carry rows of non-queried traces). Both
-    gather paths — the Pallas block-gather kernel and the XLA take
-    fallback — feed the same mask, and the output span_mat is masked to
-    -1 on dead rows, so the two are bitwise identical
-    (tests/test_paged.py gates it).
+    traces, so a page may carry rows of non-queried traces). The
+    output span_mat is masked to -1 on dead rows.
 
     Annotation/binary rows stay on their FIFO rings (no pages), so
     their membership is the _gather_impl scan unchanged.
@@ -3726,19 +3696,8 @@ def _paged_gather_impl(
         + page_slots.astype(jnp.int64),
         jnp.int64(-1),
     ).reshape(-1)                                            # [K*R]
-    ncols = len(span_cols)
-    if pallas:
-        from zipkin_tpu.ops import pallas_kernels as PK
-
-        cols64 = jnp.stack([col.astype(jnp.int64) for col in span_cols])
-        planes = jnp.moveaxis(_p32(cols64), 2, 1).reshape(
-            2 * ncols, capacity)
-        out = PK.paged_page_gather(planes, pages, R)         # [2C, K*R]
-        rows = _p64(jnp.moveaxis(out.reshape(ncols, 2, -1), 1, 2))
-    else:
-        slot = page_slots.reshape(-1)
-        rows = jnp.stack(
-            [col[slot].astype(jnp.int64) for col in span_cols])
+    slot = page_slots.reshape(-1)
+    rows = jnp.stack([col[slot].astype(jnp.int64) for col in span_cols])
     g_tid = rows[0]
     g_gid = rows[-1]
     g_live = (expected >= 0) & (g_gid == expected)
@@ -3786,17 +3745,9 @@ def gather_paged_trace_rows(
     k_spans: int, k_anns: int, k_banns: int,
 ):
     """Paged twin of gather_trace_rows: span rows come from the page
-    list (Pallas block-gather when eligible, XLA take fallback — the
-    r12 arena_claim_scatter gating pattern), annotation rows from the
-    ring scan. Same four-array contract, so the host decode and
-    escalation paths are shared."""
-    from zipkin_tpu.ops import pallas_kernels as PK
-
+    list, annotation rows from the ring scan. Same four-array contract,
+    so the host decode and escalation paths are shared."""
     c = state.config
-    use_pallas = PK.paged_gather_supported(
-        c.capacity, c.page_rows, len(SPAN_MAT_COLS),
-        len(pages),
-    ) and (c.use_pallas or jax.default_backend() == "tpu")
     return _paged_gather_impl(
         tuple(getattr(state, col) for col in SPAN_MAT_COLS),
         tuple(getattr(state, col) for col in ANN_MAT_COLS),
@@ -3805,7 +3756,7 @@ def gather_paged_trace_rows(
         jnp.asarray(pages, jnp.int32), jnp.asarray(epochs, jnp.int64),
         state.ann_write_pos, state.bann_write_pos,
         c.capacity, c.page_rows, c.ann_capacity, c.bann_capacity,
-        k_spans, k_anns, k_banns, use_pallas,
+        k_spans, k_anns, k_banns,
     )
 
 
@@ -3843,8 +3794,8 @@ _INGEST_JITS = (
 # The resident query programs (query/engine.py's index tier): the
 # batched multi-probe kernel plus every read kernel the engine's
 # cached paths dispatch. A warmed steady state must hold their cache
-# sizes flat — bench_smoke's query phase and bench.py's query-engine
-# phase gate query_compile_count() deltas at ZERO.
+# sizes flat — bench_smoke's query phase gates query_compile_count()
+# deltas at ZERO.
 _QUERY_JITS = (
     _iq_multi_impl, _iq_service_impl, _iq_verify_impl,
     _iq_verify2_impl, _iq_durations_impl, _iq_gather_impl,

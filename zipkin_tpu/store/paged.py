@@ -9,7 +9,7 @@ Attention" design, PAPERS.md) carves the SAME span arena into
 
 - big traces (>= page_rows/2 spans in a unit, or already holding an
   open page) get EXCLUSIVE pages chained per trace — their rows are
-  block-contiguous for the Pallas page gather and survive together;
+  block-contiguous for the page gather and survive together;
 - small traces share a communal open page (a 1-span poll costs one
   row, not a page) — page rows are validated per (slot, epoch) at read
   time, so sharing is free;

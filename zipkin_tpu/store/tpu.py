@@ -1642,15 +1642,12 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
 
     def get_trace_ids_by_name(
         self, service_name: str, span_name: Optional[str],
-        end_ts: int, limit: int, force_scan: bool = False,
+        end_ts: int, limit: int,
     ) -> List[IndexedTraceId]:
-        """``force_scan`` pins the read to the O(ring) scan kernels —
-        the on-device index-vs-scan exactness harness (bench.py
-        --tpu-exactness) compares both paths on one live store."""
         svc = self._svc_id(service_name)
         if svc is None or limit <= 0:
             return []
-        force_scan = force_scan or service_scan_only(svc, self.config)
+        scan_only = service_scan_only(svc, self.config)
         if span_name is not None:
             name_lc = self.dicts.span_names.get(span_name.lower())
             if name_lc is None:
@@ -1683,7 +1680,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         # against epoch-encoded gids — id lookups take the exact
         # O(ring) scan (index WRITES still run, keeping the lowering
         # within one census table of the ring step).
-        if (self.config.use_index and not force_scan
+        if (self.config.use_index and not scan_only
                 and self._planner is None):
             return self._index_first(
                 limit, self.config.ann_capacity, index_fetch, fetch
@@ -1699,14 +1696,14 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
 
     def get_trace_ids_by_annotation(
         self, service_name: str, annotation: str, value: Optional[bytes],
-        end_ts: int, limit: int, force_scan: bool = False,
+        end_ts: int, limit: int,
     ) -> List[IndexedTraceId]:
         if annotation in CORE_ANNOTATIONS or limit <= 0:
             return []
         svc = self._svc_id(service_name)
         if svc is None:
             return []
-        force_scan = force_scan or service_scan_only(svc, self.config)
+        scan_only = service_scan_only(svc, self.config)
         resolved = resolve_annotation_query(self.dicts, annotation, value)
         if resolved is None:
             return []
@@ -1740,7 +1737,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         # semantics); the index families are per-side, so the rare
         # mixed case takes the scan.
         mixed = ann_value >= 0 and bann_key >= 0
-        if (c.use_index and not mixed and not force_scan
+        if (c.use_index and not mixed and not scan_only
                 and self._planner is None):
             return self._index_first(
                 limit, c.ann_capacity + c.bann_capacity, index_fetch,
@@ -1812,13 +1809,11 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
             np.asarray([to_signed64(t) for t in trace_ids], np.int64)
         )
 
-    def _durations_mat(self, qids: np.ndarray,
-                       force_scan: bool = False) -> np.ndarray:
+    def _durations_mat(self, qids: np.ndarray) -> np.ndarray:
         """[4, nq] duration matrix: trace-membership fast path when its
         exactness gate holds, the full-ring scan otherwise."""
         with self._rw.read():
-            if (self.config.use_index and not force_scan
-                    and self._planner is None):
+            if self.config.use_index and self._planner is None:
                 mat, exact = jax.device_get(
                     dev.iquery_durations(self.state, qids)
                 )
@@ -1835,18 +1830,16 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         return exist_from_duration_mat(canon, qids, mat[0], self.pins,
                                        self._lock)
 
-    def _gather_trace_mats(self, trace_ids: Sequence[int],
-                           force_scan: bool = False):
+    def _gather_trace_mats(self, trace_ids: Sequence[int]):
         """Shared ring gather for whole-trace reads: (n_s, n_a, n_b,
         span_mat, ann_mat, bann_mat)."""
         qids = self._sorted_qids(trace_ids)
         with self._rw.read():
             st = self.state
             payload = None
-            if (self.config.use_index and not force_scan
-                    and self._planner is None):
+            if self.config.use_index and self._planner is None:
                 payload = self._gather_via_index(st, qids)
-            if self._planner is not None and not force_scan:
+            if self._planner is not None:
                 payload = self._gather_via_pages(st, qids)
             if payload is None:
                 def fetch(k_s, k_a, k_b):
@@ -1859,8 +1852,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
                 payload = gather_with_escalation(self.config, fetch)
         return payload
 
-    def get_trace_rows(self, trace_ids: Sequence[int],
-                       force_scan: bool = False
+    def get_trace_rows(self, trace_ids: Sequence[int]
                        ) -> List[Tuple[int, Span]]:
         """Ring rows of the requested traces as (row gid, Span) pairs
         in insertion order, WITHOUT pin-bank merging — the hot-tier
@@ -1870,7 +1862,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         if not trace_ids:
             return []
         n_s, n_a, n_b, span_mat, ann_mat, bann_mat = (
-            self._gather_trace_mats(trace_ids, force_scan))
+            self._gather_trace_mats(trace_ids))
         if n_s == 0:
             return []
         batch, gids = mats_to_batch(
@@ -1879,13 +1871,12 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
             (int(g), s) for g, s in zip(gids, self.codec.decode(batch))
         ]
 
-    def get_spans_by_trace_ids(self, trace_ids: Sequence[int],
-                               force_scan: bool = False
+    def get_spans_by_trace_ids(self, trace_ids: Sequence[int]
                                ) -> List[List[Span]]:
         if not trace_ids:
             return []
         n_s, n_a, n_b, span_mat, ann_mat, bann_mat = (
-            self._gather_trace_mats(trace_ids, force_scan))
+            self._gather_trace_mats(trace_ids))
         spans = self._decode_gathered(
             n_s, n_a, n_b, span_mat, ann_mat, bann_mat
         )
@@ -1914,9 +1905,9 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
     def _gather_via_pages(self, st, qids: np.ndarray):
         """Whole-trace gather over the queried traces' PAGE CHAINS —
         the paged layout's answer to the index gather: the kernel
-        touches K·page_rows candidate rows (dev.gather_paged_trace_rows,
-        Pallas block-gather under the VMEM gate) instead of scanning
-        the full arena. Returns None when a chain overflowed
+        touches K·page_rows candidate rows
+        (dev.gather_paged_trace_rows) instead of scanning the full
+        arena. Returns None when a chain overflowed
         page_max_chain — those reads stay exact via the ring scan."""
         chains = self._planner.chains_for(qids)
         if chains is None:
@@ -1958,13 +1949,13 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         return index_gather_with_escalation(self.config, len(qids), fetch)
 
     def get_traces_duration(
-        self, trace_ids: Sequence[int], force_scan: bool = False
+        self, trace_ids: Sequence[int]
     ) -> List[TraceIdDuration]:
         if not trace_ids:
             return []
         canon = self._canon_ids(trace_ids)
         qids = self._sorted_qids(trace_ids)
-        mat = self._durations_mat(qids, force_scan)
+        mat = self._durations_mat(qids)
         return durations_from_mat(trace_ids, canon, qids, mat, self.pins,
                                   self._lock)
 
@@ -2238,15 +2229,13 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         s = self._sealer
         if s is not None:
             out["capture_backlog"] = float(s.queued())
-        # Active ingest kernel paths (r12): which rank / arena-scatter
-        # implementations this config's compiled steps took, so every
-        # /metrics scrape and bench record says which kernel produced
-        # its numbers (dev.active_paths — trace-time records).
+        # Active ingest kernel paths (r12): which rank implementations
+        # this config's compiled steps took, so every /metrics scrape
+        # says which kernel produced its numbers (dev.active_paths —
+        # trace-time records).
         paths = dev.active_paths(self.config)
         out["rank_path_counting"] = float(
             "counting" in paths.get("rank", ()))
-        out["scatter_path_pallas"] = float(
-            "pallas" in paths.get("scatter", ()))
         # Rings (span, ann, bann, pend) every compiled step wrote as
         # windows, and rings some step scattered into (the paged
         # layout's span ring; a pad past a tiny ring).
