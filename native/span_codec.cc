@@ -109,6 +109,26 @@ struct Reader {
   }
 };
 
+// The base64 table, built at compile time: the frame decode runs on
+// many threads at once without the GIL, so no table may be filled on
+// first use.
+struct Table { int8_t v[256]; };
+
+constexpr Table make_b64() {
+  Table t{};
+  for (int i = 0; i < 256; i++) t.v[i] = -1;
+  const char* tbl =
+      "ABCDEFGHIJKLMNOPQRSTUVWXYZabcdefghijklmnopqrstuvwxyz0123456789+/";
+  for (int i = 0; i < 64; i++) t.v[(uint8_t)tbl[i]] = (int8_t)i;
+  return t;
+}
+
+constexpr Table kB64 = make_b64();
+
+inline uint8_t ascii_lower(uint8_t c) {
+  return (c >= 'A' && c <= 'Z') ? c + 32 : c;
+}
+
 struct Endpoint {
   int32_t ipv4 = 0;
   int32_t port = 0;
@@ -330,22 +350,13 @@ int32_t zk_group_strings(
 // Standard base64 decode (for scribe LogEntry payloads); returns output
 // length or -1 on bad input. Skips whitespace; handles padding.
 int64_t zk_base64_decode(const uint8_t* in, int64_t in_len, uint8_t* out) {
-  static int8_t lut[256];
-  static bool init = false;
-  if (!init) {
-    for (int i = 0; i < 256; i++) lut[i] = -1;
-    const char* tbl =
-        "ABCDEFGHIJKLMNOPQRSTUVWXYZabcdefghijklmnopqrstuvwxyz0123456789+/";
-    for (int i = 0; i < 64; i++) lut[(uint8_t)tbl[i]] = (int8_t)i;
-    init = true;
-  }
   uint32_t acc = 0;
   int bits = 0;
   int64_t o = 0;
   for (int64_t i = 0; i < in_len; i++) {
     uint8_t c = in[i];
     if (c == '=' || c == '\n' || c == '\r' || c == ' ') continue;
-    int8_t v = lut[c];
+    int8_t v = kB64.v[c];
     if (v < 0) return -1;
     acc = (acc << 6) | (uint32_t)v;
     bits += 6;
@@ -355,6 +366,105 @@ int64_t zk_base64_decode(const uint8_t* in, int64_t in_len, uint8_t* out) {
     }
   }
   return o;
+}
+
+// One scribe Log call's argument struct, {1: list<LogEntry{1: category,
+// 2: message}>}, from ``pos`` (the byte after the message header):
+// walked as scribe_server._parse_log_args walks it, filtered by
+// category as ScribeReceiver.decode filters it, each kept message
+// base64-decoded into ONE contiguous ``out`` with the entry's end
+// offset in ``ends``. Called through ctypes.CDLL, so without the GIL:
+// no state but the arguments and the compile-time table.
+//
+// ``cats``/``cat_lens`` are the receiver's categories, lowercased ASCII,
+// back to back. counts = {received, ignored, kept, undecided}.
+//
+// The walk is strict and the python code stays the definition: a
+// message that is not canonical base64 (length a multiple of four,
+// alphabet only, at most two '=' and only at the end) is kept as an
+// empty entry with ``src`` = the message's offset in the frame (else
+// -1), for base64.b64decode(validate=False) to give its verdict; a
+// frame this walk cannot decide whole (truncated, a bad list header,
+// nesting too deep, a category byte >= 0x80, more kept entries than
+// ``max_kept``) returns -1 with nothing to be used, and the caller
+// parses it again in python. 0 on success.
+int zk_decode_log(
+    const uint8_t* frame, int64_t len, int64_t pos,
+    const uint8_t* cats, const int32_t* cat_lens, int32_t n_cats,
+    uint8_t* out, int64_t out_cap,
+    int64_t* ends, int64_t* src, int64_t max_kept,
+    int64_t* counts) {
+  if (pos < 0 || pos > len) return -1;
+  Reader r{frame, (size_t)len, (size_t)pos, true};
+  int64_t received = 0, ignored = 0, kept = 0, undecided = 0, o = 0;
+  while (r.ok) {
+    uint8_t ft = r.u8();
+    if (!r.ok) return -1;
+    if (ft == T_STOP) break;
+    int16_t fid = r.i16();
+    if (!(fid == 1 && ft == T_LIST)) { r.skip(ft); continue; }
+    uint8_t et = r.u8();
+    int32_t n = r.i32();
+    if (!r.ok || et != T_STRUCT || n < 0) return -1;
+    for (int32_t i = 0; i < n; i++) {
+      int64_t c_off = 0, m_off = 0;
+      int32_t c_len = 0, m_len = 0;
+      while (r.ok) {
+        uint8_t eft = r.u8();
+        if (eft == T_STOP) break;
+        int16_t eid = r.i16();
+        if (eid == 1 && eft == T_STRING) c_off = r.str(&c_len);
+        else if (eid == 2 && eft == T_STRING) m_off = r.str(&m_len);
+        else r.skip(eft);
+      }
+      if (!r.ok) return -1;
+      received++;
+      const uint8_t* c = frame + c_off;
+      for (int32_t k = 0; k < c_len; k++) if (c[k] >= 0x80) return -1;
+      bool wanted = false;
+      const uint8_t* want = cats;
+      for (int32_t j = 0; j < n_cats && !wanted; want += cat_lens[j++]) {
+        if (cat_lens[j] != c_len) continue;
+        int32_t k = 0;
+        while (k < c_len && ascii_lower(c[k]) == want[k]) k++;
+        wanted = k == c_len;
+      }
+      if (!wanted) { ignored++; continue; }
+      if (kept >= max_kept) return -1;
+      if (o + (int64_t)m_len / 4 * 3 > out_cap) return -1;
+      const uint8_t* m = frame + m_off;
+      int64_t w = o;
+      bool canonical = (m_len & 3) == 0;
+      int32_t body = m_len;
+      if (canonical && m_len && m[m_len - 1] == '=') body -= 4;
+      int32_t k = 0;
+      for (; canonical && k < body; k += 4) {
+        int32_t a = kB64.v[m[k]], b = kB64.v[m[k + 1]];
+        int32_t c2 = kB64.v[m[k + 2]], d = kB64.v[m[k + 3]];
+        if ((a | b | c2 | d) < 0) { canonical = false; break; }
+        out[w++] = (uint8_t)((a << 2) | (b >> 4));
+        out[w++] = (uint8_t)((b << 4) | (c2 >> 2));
+        out[w++] = (uint8_t)((c2 << 6) | d);
+      }
+      if (canonical && body < m_len) {  // the quad that ends in '='
+        bool two = m[k + 2] == '=';
+        int32_t a = kB64.v[m[k]], b = kB64.v[m[k + 1]];
+        int32_t c2 = two ? 0 : kB64.v[m[k + 2]];
+        if ((a | b | c2) < 0) canonical = false;
+        else {
+          out[w++] = (uint8_t)((a << 2) | (b >> 4));
+          if (!two) out[w++] = (uint8_t)((b << 4) | (c2 >> 2));
+        }
+      }
+      if (canonical) { o = w; src[kept] = -1; }
+      else { src[kept] = m_off; undecided++; }
+      ends[kept++] = o;
+    }
+  }
+  if (!r.ok) return -1;
+  counts[0] = received; counts[1] = ignored;
+  counts[2] = kept; counts[3] = undecided;
+  return 0;
 }
 
 }  // extern "C"

@@ -247,3 +247,106 @@ class TestFastIngestPath:
         # Slow-path parity: debug short-circuits before the sampler.
         assert sampler.allowed == 0 and sampler.denied == 0
         assert collector.spans_stored == 1
+
+
+class TestLogFrameDecode:
+    """zk_decode_log's wrapper and what it returns (the differential
+    cases against the python decode are tests/test_scribe_server.py's)."""
+
+    @staticmethod
+    def _frame(messages):
+        from zipkin_tpu.ingest.scribe_server import encode_log_call
+
+        return encode_log_call([("zipkin", m) for m in messages])[4:], 15
+
+    def _segments(self, pieces):
+        import base64
+
+        frame, pos = self._frame(
+            [base64.b64encode(p).decode() for p in pieces])
+        segments, received, ignored, undecided = native.decode_log(
+            frame, pos, {"zipkin"})
+        assert (received, ignored, undecided) == (len(pieces), 0, [])
+        return segments
+
+    def test_segments_are_a_sequence_of_entries(self):
+        pieces = [b"a", b"", b"bcd", b"ef" * 40, b"\x00\xff", b"g"]
+        s = self._segments(pieces)
+        assert isinstance(s, native.LogSegments)
+        assert len(s) == 6 and list(s) == pieces
+        assert [s[i] for i in range(6)] == pieces and s[-1] == b"g"
+        assert s.joined() == b"".join(pieces)
+        with pytest.raises(IndexError):
+            s[6]
+
+    def test_slices_keep_entry_boundaries(self):
+        pieces = [bytes([i]) * (i + 1) for i in range(9)]
+        s = self._segments(pieces)
+        mid = len(s) // 2
+        left, right = s[:mid], s[mid:]
+        assert list(left) == pieces[:mid] and list(right) == pieces[mid:]
+        assert left.joined() + right.joined() == s.joined()
+        inner = right[1:3]
+        assert list(inner) == pieces[mid + 1:mid + 3] and inner[-1] == pieces[mid + 2]
+        assert len(s[4:4]) == 0 and s[4:4].joined() == b"" and not s[7:2]
+        assert not self._segments([]) and self._segments([]).joined() == b""
+
+    def test_undecided_entries_come_back_with_their_message(self):
+        frame, pos = self._frame(["QUJD", "QUJ D", "QUJDRA", "RUY="])
+        segments, received, ignored, undecided = native.decode_log(
+            frame, pos, {"zipkin"})
+        assert (received, ignored) == (4, 0)
+        assert undecided == [(1, b"QUJ D"), (2, b"QUJDRA")]
+        assert list(segments) == [b"ABC", b"", b"", b"EF"]
+
+    def test_concurrent_decodes_agree(self):
+        """Eight threads in the library at once (ctypes.CDLL lets go of
+        the GIL): no state is shared but the compile-time tables."""
+        import threading
+
+        pieces = [bytes((i + k) % 256 for k in range(100 + i))
+                  for i in range(256)]
+        want = b"".join(pieces)
+        got = []
+
+        def work():
+            for _ in range(20):
+                got.append(self._segments(pieces).joined() == want)
+
+        threads = [threading.Thread(target=work) for _ in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert got == [True] * 160
+
+    def test_oversized_call_is_halved_on_entry_boundaries(self):
+        """ParseCapacityError halves the segments; every part the store
+        sees is whole entries, joined in one piece."""
+        from zipkin_tpu.ingest.collector import Collector
+
+        pieces = [span_to_bytes(s) for s in spans_fixture()] * 3
+        segments = self._segments(pieces)
+        seen = []
+
+        class Store:
+            def write_thrift(self, payload, sample_threshold=0):
+                if len(payload) > 2 * max(map(len, pieces)):
+                    raise native.ParseCapacityError("too large")
+                seen.append(payload)
+                return 1, 0, 0
+
+            def apply(self, spans):
+                raise AssertionError("no part is one entry too large")
+
+            def close(self):
+                pass
+
+        collector = Collector(Store(), max_queue=5, concurrency=1)
+        try:
+            collector.ingest_thrift_durable(segments)
+        finally:
+            collector.close()
+        assert b"".join(seen) == b"".join(pieces) and len(seen) > 2
+        bounds = {sum(map(len, pieces[:i])) for i in range(len(pieces) + 1)}
+        assert {sum(map(len, seen[:i])) for i in range(len(seen) + 1)} <= bounds

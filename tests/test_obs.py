@@ -619,6 +619,7 @@ class TestDeviceCounterBlock:
             max_annotation_values=256, max_binary_keys=64,
             cms_width=1 << 10, hll_p=8, quantile_buckets=256,
         ), registry=reg)
+        store.RUN_AHEAD = 0  # every launch waits for itself
         store.apply([span(9)])
         d = reg.as_dict()
         assert d["zipkin_store_ingest_launches_total"] == 1
@@ -826,12 +827,12 @@ class TestStageSpans:
         spans = [s for t in generate_traces(n_traces=24, max_depth=3,
                                             n_services=6) for s in t]
         try:
-            # compile outside the capture; then every launch syncs
+            # compile outside the capture; then every launch waits
             assert rig.log(spans[0::4]) == ResultCode.OK
             rig.store.drain_pipeline()
             rig.tracker.flush()
             rig.store.drain_pipeline()
-            rig.store.INGEST_SYNC_EVERY = 1
+            rig.store.RUN_AHEAD = 0
             if depth:
                 # a commit slow enough that the queues fill: the feed
                 # stall is a span only where the queue was full

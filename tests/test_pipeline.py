@@ -293,23 +293,57 @@ def test_pipeline_lifecycle_and_error_surfacing():
     store2.close()
 
 
-def test_ingest_latency_metrics_split():
-    """The r9 _observe_ingest fix: dispatch time is always observed,
-    TRUE step latency (device completion) is sampled — the first
-    launch always observes so even one write reports."""
+@pytest.mark.parametrize("depth", [0, 2], ids=["serial", "pipelined"])
+def test_host_leads_the_device_by_run_ahead_launches(depth):
+    """Every launch leaves a marker and waits for the one RUN_AHEAD
+    launches back: never a drain of the whole queue, never more than
+    RUN_AHEAD launches unwaited-for, and a marker outlives the
+    donation of the state it was computed from."""
     from zipkin_tpu import obs
 
     reg = obs.Registry()
     store = TpuSpanStore(CONFIG, registry=reg)
+    store.RUN_AHEAD = 2
+    waits = obs.stage_family().labels(stage="device_sync_wait")
+    before = waits.count
+    spans = _spans(n_traces=28)
+    n = 7
+    if depth:
+        store.start_pipeline(depth)
+    for i in range(n):
+        store.apply(spans[i::n])
+        assert len(store._in_flight) <= store.RUN_AHEAD
+    store.drain_pipeline()
+    d = reg.as_dict()
+    assert d["zipkin_store_ingest_launches_total"] == n
+    assert waits.count - before == n - store.RUN_AHEAD
+    assert d["zipkin_store_ingest_step_seconds_count"] == n - store.RUN_AHEAD
+    # the two left are the newest launches', ready or not, and readable
+    # though their states were donated since (the last one's apart)
+    assert len(store._in_flight) == store.RUN_AHEAD
+    assert [int(m) for m, _ in store._in_flight][-1] == int(
+        store.state.write_pos)
+    store.close()
+
+
+def test_ingest_latency_metrics_split():
+    """Dispatch time is always observed; a launch's dispatch until the
+    device had run it is observed where the host waits for that launch
+    (with RUN_AHEAD 0: at once, so even one write reports)."""
+    from zipkin_tpu import obs
+
+    reg = obs.Registry()
+    store = TpuSpanStore(CONFIG, registry=reg)
+    store.RUN_AHEAD = 0  # every launch waits for itself
     spans = _spans(n_traces=10)
     store.apply(spans)
     d = reg.as_dict()
     launches = d["zipkin_store_ingest_launches_total"]
     assert launches >= 1
     assert d["zipkin_store_ingest_dispatch_seconds_count"] == launches
-    assert d["zipkin_store_ingest_step_seconds_count"] >= 1
-    # The sampled true latency includes device compute, so its mean
-    # cannot undercut dispatch-only timing on the same launch count.
+    assert d["zipkin_store_ingest_step_seconds_count"] == launches
+    # Dispatch until the device had run the launch: device compute
+    # is in it.
     assert d["zipkin_store_ingest_step_seconds_sum"] > 0
     assert store.counters()["jit_compiles"] == dev.compile_count() > 0
     store.close()
@@ -319,7 +353,7 @@ def test_ingest_latency_metrics_split():
 def test_stage_counts_per_log_call(tmp_path, depth):
     """N Log calls through a ScribeServer socket: every stage of the
     call is observed once a call (stage 1 twice on the serial path,
-    round the journal), the sync wait once a sync, and the sketches
+    round the journal), the sync wait once a wait, and the sketches
     the benchmark reads count what they counted before the stages
     were rewritten onto obs.stage (the parent commit gives the same
     numbers for this input: one launch unit, one append and, under
@@ -355,7 +389,8 @@ def test_stage_counts_per_log_call(tmp_path, depth):
         assert count[s] == n, (s, count)
     assert count["encode"] == (n if depth else 2 * n)
     syncs = d["zipkin_store_ingest_step_seconds_count"]
-    assert count["device_sync_wait"] == syncs == 1  # launch 1 of 32
+    # launch 5 waits for launch 1; the first RUN_AHEAD wait for none
+    assert count["device_sync_wait"] == syncs == n - rig.store.RUN_AHEAD == 1
     assert d["zipkin_collector_write_seconds_count"] == n
     assert d["zipkin_store_ingest_dispatch_seconds_count"] == n
     assert d["zipkin_store_ingest_launches_total"] == n

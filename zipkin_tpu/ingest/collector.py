@@ -26,6 +26,7 @@ from typing import Optional, Sequence
 from zipkin_tpu import obs
 from zipkin_tpu.ingest.queue import ItemQueue
 from zipkin_tpu.models.span import Span
+from zipkin_tpu.native import LogSegments
 from zipkin_tpu.obs.stages import stage
 from zipkin_tpu.sampler.adaptive import (
     AdaptiveConfig,
@@ -46,7 +47,16 @@ class _ThriftPayload:
     __slots__ = ("segments",)
 
     def __init__(self, segments: Sequence[bytes]):
-        self.segments = list(segments)
+        self.segments = segments
+
+
+def _segments_of(payload) -> Sequence[bytes]:
+    """One bytes blob, or a sequence of per-message segments; the
+    receiver's ``native.LogSegments`` stays as it is (one buffer with
+    the entries' boundaries, no ``bytes`` per entry)."""
+    if isinstance(payload, (bytes, bytearray)):
+        return [payload]
+    return payload if isinstance(payload, LogSegments) else list(payload)
 
 
 class Collector:
@@ -169,9 +179,7 @@ class Collector:
         available (ScribeSpanReceiver.scala:96-107's scrooge hot decode),
         falling back to the python codec. Sampling is applied either
         way. Raises QueueFullException when full."""
-        segments = [payload] if isinstance(payload, (bytes, bytearray)) \
-            else list(payload)
-        self.queue.add(_ThriftPayload(segments))
+        self.queue.add(_ThriftPayload(_segments_of(payload)))
 
     # -- durable (ack-after-append) entries -----------------------------
     #
@@ -199,10 +207,8 @@ class Collector:
     def ingest_thrift_durable(self, payload) -> int:
         """Synchronous raw-thrift ingest + durable-append barrier;
         drop-in ``process_thrift`` target for receivers."""
-        segments = [payload] if isinstance(payload, (bytes, bytearray)) \
-            else list(payload)
         with stage("collector.write", self._h_write):
-            stored = self._write_thrift(segments)
+            stored = self._write_thrift(_segments_of(payload))
         self._wal_barrier()
         return stored
 
@@ -306,8 +312,10 @@ class Collector:
         from zipkin_tpu.native import ParseCapacityError
 
         try:
+            joined = (segments.joined() if isinstance(segments, LogSegments)
+                      else b"".join(segments))
             written, dropped, written_debug = self.store.write_thrift(
-                b"".join(segments), sample_threshold=self.sampler.threshold
+                joined, sample_threshold=self.sampler.threshold
             )
         except ParseCapacityError:
             # Valid but oversized: halve and retry (single segments that

@@ -15,7 +15,7 @@ import json
 import threading
 from typing import Callable, Dict, Iterable, List, Optional, Sequence
 
-from zipkin_tpu import obs
+from zipkin_tpu import native, obs
 from zipkin_tpu.ingest.queue import QueueFullException
 from zipkin_tpu.models.span import (
     Annotation,
@@ -60,26 +60,54 @@ class ScribeReceiver:
         self.stats: Dict[str, int] = {
             "received": 0, "ignored": 0, "bad": 0, "pushed_back": 0,
         }
+        # How the TCP door's frames were decoded (``decode_frame``), and
+        # the entries whose base64 went back to python for a verdict.
+        self.frames: Dict[str, int] = {
+            "native": 0, "python": 0, "sent_back": 0,
+        }
 
     def _bump(self, key: str, n: int = 1) -> None:
         with self._stats_lock:
             self.stats[key] += n
+
+    def count_frame(self, decode: str, received: int = 0, ignored: int = 0,
+                    bad: int = 0, sent_back: int = 0) -> None:
+        """One frame's accounting under one lock."""
+        with self._stats_lock:
+            self.frames[decode] += 1
+            self.frames["sent_back"] += sent_back
+            self.stats["received"] += received
+            self.stats["ignored"] += ignored
+            self.stats["bad"] += bad
 
     def export_stats(self, registry, transport: str) -> None:
         """This receiver's entry accounting on /metrics, read at
         scrape: ``zipkin_scribe_entries{transport, result}``, one
         family for every scribe door of the process (the HTTP route's
         receiver and the TCP server's are different objects)."""
-        fam = registry.get("zipkin_scribe_entries")
-        if fam is None:
-            fam = registry.register(obs.Gauge(
-                "zipkin_scribe_entries",
-                "Scribe receiver entry accounting "
-                "(received/ignored/bad/pushed_back) per transport",
-                labelnames=("transport", "result")))
+        def family(name: str, help: str, *labelnames: str):
+            return registry.get(name) or registry.register(
+                obs.Gauge(name, help, labelnames=labelnames))
+
+        fam = family("zipkin_scribe_entries",
+                     "Scribe receiver entry accounting "
+                     "(received/ignored/bad/pushed_back) per transport",
+                     "transport", "result")
         for key in self.stats:
             fam.labels(transport=transport, result=key).set_function(
                 lambda k=key: self.stats[k])
+        fam = family("zipkin_scribe_frames",
+                     "Scribe Log frames by the decode that took them: "
+                     "native (one call off the GIL) or python",
+                     "transport", "decode")
+        for key in ("native", "python"):
+            fam.labels(transport=transport, decode=key).set_function(
+                lambda k=key: self.frames[k])
+        family("zipkin_scribe_entries_sent_back",
+               "Entries of natively decoded frames whose base64 was "
+               "not canonical and went to python for its verdict",
+               "transport").labels(transport=transport).set_function(
+                   lambda: self.frames["sent_back"])
 
     def log(self, entries: Sequence[tuple]) -> ResultCode:
         """entries: (category, message) pairs — the Scribe.Log call."""
@@ -112,6 +140,39 @@ class ScribeReceiver:
             except ValueError:  # ThriftError, binascii.Error, non-ascii
                 self._bump("bad")
         return out
+
+    def decode_frame(self, frame: bytes, pos: int):
+        """``decode`` for a whole ``Log`` frame (``pos``: the byte after
+        the message header) in one native call that runs without the
+        GIL: no python object per entry, and what comes back keeps the
+        entry boundaries (``native.LogSegments``). Taken where the
+        payloads stay raw thrift and the library loads; None otherwise,
+        and where the strict native walk cannot decide the frame: the
+        caller then runs ``_parse_log_args`` + ``decode``, which stay
+        the definition. A message that is not canonical base64 gets
+        ``decode``'s own verdict, entry by entry."""
+        if self.process_thrift is None or not native.available():
+            return None
+        got = native.decode_log(frame, pos, self.categories)
+        if got is None:
+            return None
+        segments, received, ignored, undecided = got
+        bad = 0
+        if undecided:
+            # Rare by construction (a client that wraps or strips its
+            # base64): back to a list of per-entry payloads.
+            payloads = list(segments)
+            for i, message in reversed(undecided):
+                try:
+                    payloads[i] = base64.b64decode(
+                        message.decode("utf-8", "replace").encode("ascii"),
+                        validate=False)
+                except ValueError:
+                    del payloads[i]
+                    bad += 1
+            segments = payloads
+        self.count_frame("native", received, ignored, bad, len(undecided))
+        return segments
 
     def deliver(self, payloads: list) -> ResultCode:
         """Hand decoded payloads on; any failure is TRY_LATER."""

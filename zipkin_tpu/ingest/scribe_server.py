@@ -134,7 +134,10 @@ def handle_call(receiver: ScribeReceiver, frame: bytes) -> Optional[bytes]:
     if name != "Log":
         return _exception_reply(name, seqid, f"unknown method {name!r}")
     with stage("ingest.decode"):
-        payloads = receiver.decode(_parse_log_args(r))
+        payloads = receiver.decode_frame(frame, r.pos)
+        if payloads is None:
+            payloads = receiver.decode(_parse_log_args(r))
+            receiver.count_frame("python")
     return _reply(name, seqid, receiver.deliver(payloads))
 
 

@@ -124,6 +124,15 @@ def get_lib():
             lib.zk_base64_decode.argtypes = [
                 ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p,
             ]
+            lib.zk_decode_log.restype = ctypes.c_int
+            lib.zk_decode_log.argtypes = [
+                ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
+                ctypes.c_char_p, ctypes.POINTER(ctypes.c_int32),
+                ctypes.c_int32,
+                ctypes.c_void_p, ctypes.c_int64,
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+                ctypes.c_void_p,
+            ]
             lib.zk_group_strings.restype = ctypes.c_int32
             lib.zk_group_strings.argtypes = [
                 ctypes.c_char_p,
@@ -184,6 +193,95 @@ def base64_decode(data: bytes) -> bytes:
     if n < 0:
         raise ValueError("bad base64 payload")
     return out.raw[:n]
+
+
+class LogSegments:
+    """The kept entries of one scribe ``Log`` call, base64-decoded back
+    to back into one buffer, with each entry's end offset: what
+    ``decode_log`` hands the collector in place of a list of 2048
+    ``bytes``. A sequence of the entries' payloads (index, slice,
+    iterate, ``len``: entry boundaries intact, so a corrupt entry can
+    still be isolated and an oversized call halved), and ``joined()``
+    is the whole payload in one copy."""
+
+    __slots__ = ("_buf", "_ends", "_lo", "_hi")
+
+    def __init__(self, buf: np.ndarray, ends: np.ndarray,
+                 lo: int = 0, hi: Optional[int] = None):
+        self._buf = buf
+        self._ends = ends
+        self._lo = lo
+        self._hi = len(ends) if hi is None else hi
+
+    def __len__(self) -> int:
+        return self._hi - self._lo
+
+    def _span(self, lo: int, hi: int) -> bytes:
+        start = int(self._ends[lo - 1]) if lo else 0
+        stop = int(self._ends[hi - 1]) if hi else 0
+        return self._buf[start:stop].tobytes()
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            lo, hi, step = i.indices(len(self))
+            if step != 1:
+                raise ValueError("LogSegments slices are contiguous")
+            return LogSegments(self._buf, self._ends,
+                               self._lo + lo, self._lo + max(lo, hi))
+        if i < 0:
+            i += len(self)
+        if not 0 <= i < len(self):
+            raise IndexError(i)
+        return self._span(self._lo + i, self._lo + i + 1)
+
+    def __iter__(self):
+        for i in range(self._lo, self._hi):
+            yield self._span(i, i + 1)
+
+    def joined(self) -> bytes:
+        return self._span(self._lo, self._hi)
+
+
+def decode_log(frame: bytes, pos: int, categories):
+    """A scribe ``Log`` frame's argument struct (from ``pos``, the byte
+    after the message header) → ``(segments, received, ignored,
+    undecided)`` in one native call with the GIL released
+    (``zk_decode_log``): the category filter of ``ScribeReceiver.decode``
+    against ``categories`` (lowercased) and the base64 of every kept
+    message. ``undecided`` lists ``(index, message bytes)`` of kept
+    entries whose message is not canonical base64 — each an empty entry
+    of ``segments`` until python's lenient decode has given its verdict.
+    None where the strict walk cannot decide the frame; the caller then
+    parses it whole in python, which is where a malformed frame raises."""
+    lib = get_lib()
+    # A non-ASCII category can match no entry this walk accepts.
+    cats = [c.encode() for c in categories if c.isascii()]
+    cat_lens = (ctypes.c_int32 * len(cats))(*map(len, cats))
+    room = len(frame) - pos
+    # A kept entry takes 9 bytes of frame at the least (a category of
+    # one byte); where "" is a category and a frame has more, python
+    # decodes it.
+    max_kept = room // 9 + 1
+    out = np.empty(room * 3 // 4 + 3, np.uint8)
+    meta = np.empty((2, max_kept), np.int64)
+    counts = np.empty(4, np.int64)
+    rc = lib.zk_decode_log(
+        frame, len(frame), pos, b"".join(cats), cat_lens, len(cats),
+        out.ctypes.data, len(out),
+        meta[0].ctypes.data, meta[1].ctypes.data, max_kept,
+        counts.ctypes.data,
+    )
+    if rc != 0:
+        return None
+    received, ignored, kept, n_undecided = map(int, counts)
+    undecided = []
+    if n_undecided:
+        for i in np.flatnonzero(meta[1, :kept] >= 0):
+            off = int(meta[1, i])
+            n = int.from_bytes(frame[off - 4:off], "big")
+            undecided.append((int(i), frame[off:off + n]))
+    segments = LogSegments(out, meta[0, :kept].copy())
+    return segments, received, ignored, undecided
 
 
 _CORE_TS = {CLIENT_SEND: "ts_cs", CLIENT_RECV: "ts_cr",

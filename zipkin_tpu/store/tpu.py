@@ -20,8 +20,10 @@ from __future__ import annotations
 import contextlib
 import threading
 import time as _time
+from collections import deque
 from typing import (
     TYPE_CHECKING,
+    Deque,
     Dict,
     List,
     Optional,
@@ -230,6 +232,13 @@ def gate_multi_probes(probes, limits, per_probe):
                 limits[qi], win_total, cands, complete, wm
             )
     return out
+
+
+@jax.jit
+def _launch_marker(write_pos):
+    """A scalar that is ready once the launch that produced
+    ``write_pos`` has run, in a buffer no later launch donates."""
+    return write_pos + 0
 
 
 def device_memory(device) -> Dict[str, float]:
@@ -454,17 +463,16 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         reg = registry or obs.default_registry()
         self._registry = reg
         # Launch dispatch is ASYNC under JAX, so a per-step wall clock
-        # only measures host dispatch. Every INGEST_SYNC_EVERY-th
-        # launch blocks on a tiny scalar, which waits for every step
-        # queued before it (see _observe_ingest); the dispatch sketch
-        # keeps the always-on host-side number.
+        # only measures host dispatch. Every launch leaves a marker
+        # scalar behind and waits for the marker RUN_AHEAD launches
+        # back (see _observe_ingest); the dispatch sketch keeps the
+        # always-on host-side number.
         self._h_ingest = reg.register(obs.LatencySketch(
             "zipkin_store_ingest_step_seconds",
-            "Dispatch of a launch through the drain of every step "
-            "queued on the device before it (sampled: every "
-            f"{self.INGEST_SYNC_EVERY}th launch blocks on a scalar; "
-            "that block is the write path's only back-pressure on the "
-            "device queue). Not one step's latency"))
+            "A launch's dispatch until the device had run it, queue "
+            "and all (observed RUN_AHEAD launches later, where the "
+            "host waits for it: that wait is the write path's "
+            "back-pressure on the device queue)"))
         self._h_dispatch = reg.register(obs.LatencySketch(
             "zipkin_store_ingest_dispatch_seconds",
             "Host dispatch time per fused step/chain (async: excludes "
@@ -472,7 +480,9 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         self._c_launches = reg.register(obs.Counter(
             "zipkin_store_ingest_launches_total",
             "Device ingest launches (chained chunks count as one)"))
-        self._launch_seq = 0
+        # (marker, dispatch time) of the launches the device may not
+        # have run yet, oldest first; the committing thread's alone.
+        self._in_flight: Deque[Tuple[jax.Array, float]] = deque()
         reg.register(obs.Counter(
             "zipkin_store_jit_compiles_total",
             "Compiled variants across the ingest/staging/capture jits "
@@ -551,10 +561,12 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
     # TTL-map bound (store/base.MAX_TTL_ENTRIES — shared with the
     # sharded and replica stores; kept as a class attr for callers).
     MAX_TTL_ENTRIES = MAX_TTL_ENTRIES
-    # True-latency sampling cadence: every Nth launch blocks on one
-    # scalar (write_pos) to observe dispatch->completion. The first
-    # launch is always sampled so a single-write store still reports.
-    INGEST_SYNC_EVERY = 32
+    # How many launches the host may lead the device by: launch i
+    # waits until launch i - RUN_AHEAD has run. Enough queued work
+    # (4 x 36 ms at the benchmark's geometry) that a host hiccup does
+    # not idle the device; few enough that no wait is long (0: every
+    # launch waits for itself, as tests do to see one).
+    RUN_AHEAD = 4
     # Default prefetch depth for start_pipeline(None).
     PIPELINE_DEPTH = 8
     # Default staged-unit (H2D double-buffer) slots for start_pipeline.
@@ -1050,7 +1062,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         the only device writer while a pipeline is active)."""
         self.ensure_writable()
         unit_id = unit.wal_seq
-        with stage("store.commit", unit=unit_id) as commit, \
+        with stage("store.commit", unit=unit_id), \
                 stage("store.dispatch", self._h_dispatch,
                       unit=unit_id) as dispatch:
             if self._planner is not None:
@@ -1096,7 +1108,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
                     self.state = dev.dep_sweep(self.state)
                     self._step_seq += 1
                     self._batches_since_sweep = 0
-            self._observe_ingest(commit)
+            self._observe_ingest()
 
     def _write_device_many(self, group) -> None:
         """One chained launch over ≥2 chunks: pad every chunk to the
@@ -1112,27 +1124,32 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         already fits the ring capacities."""
         self._commit_group([(batch, name_lc, indexable)])
 
-    def _observe_ingest(self, commit: stage) -> None:
+    def _observe_ingest(self) -> None:
         """Launch accounting past the always-on dispatch sketch (the
-        ``store.dispatch`` span of _commit_unit): every
-        INGEST_SYNC_EVERY-th launch blocks on the write_pos scalar
-        (one tiny D2H, no ring traffic) and observes the seconds since
-        the commit began. Dispatch is ASYNC and the device runs its
-        queue in order, so that block returns when EVERY step queued
-        so far has run: the observation is dispatch through the drain
-        of the whole device queue, not one step's latency, and the
-        block is the write path's only back-pressure on that queue.
-        ``store.device_sync_wait`` times the block alone."""
+        ``store.dispatch`` span of _commit_unit), and the write path's
+        back-pressure on the device queue. Dispatch is ASYNC and the
+        device runs its queue in order, so the launch leaves a marker
+        behind (a scalar of its own computed from the new write_pos:
+        the state's leaves are donated to the next launch, a marker
+        is not) and the host then waits for the marker RUN_AHEAD
+        launches back: it leads the device by that many launches and
+        no more, and every wait is short (a drain of the whole queue
+        is a quarter of a second with the store's lock held once the
+        device sets the rate, and the acks come in bursts).
+        ``store.device_sync_wait`` times the wait alone; the sketch,
+        dispatch until done."""
         self._c_launches.inc()
-        self._launch_seq += 1
-        if self._launch_seq % self.INGEST_SYNC_EVERY == 1 \
-                or self.INGEST_SYNC_EVERY == 1:
-            # Under the read lock: a reader-triggered pending sweep
-            # (get_dependencies) is a DONATING step — blocking on a
-            # state the sweep just consumed would hit deleted buffers.
-            with self._rw.read(), stage("store.device_sync_wait"):
-                jax.block_until_ready(self.state.write_pos)
-            self._h_ingest.observe(commit.elapsed())
+        # Under the read lock: a reader-triggered pending sweep
+        # (get_dependencies) is a DONATING step, and reading a state
+        # the sweep just consumed would hit deleted buffers.
+        with self._rw.read():
+            marker = _launch_marker(self.state.write_pos)
+        self._in_flight.append((marker, _time.perf_counter()))
+        if len(self._in_flight) > self.RUN_AHEAD:
+            marker, dispatched = self._in_flight.popleft()
+            with stage("store.device_sync_wait"):
+                jax.block_until_ready(marker)
+            self._h_ingest.observe(_time.perf_counter() - dispatched)
 
     # Write-path sweep cadence (batches). Each sweep is one small launch
     # over the pending ring; 64 bounds a cross-batch child's link
