@@ -2,30 +2,38 @@
 
 The one-number experiment of ROADMAP S1: the daemon's geometry
 (``main/example.py``: side rings from ``_side_rings``, window arena on)
-at each ``--capacity``, one launch shape (the benchmark's pads for a
-2048-span ``Log`` call), ``--steps`` donated steps chained back to back
-and one barrier at the end. A step whose cost follows the batch reads
-the same at every capacity; one that sweeps a state leaf grows with it.
+at each ``--capacity``, at each launch shape ``--pads`` names (none:
+the pads the store gives a 2048-span ``Log`` call of the benchmark),
+``--steps`` donated steps chained back to back and one barrier at the
+end. A step whose cost follows the batch reads the same at every
+capacity; one that sweeps a state leaf grows with it; one whose cost is
+the launch's rows follows the pads.
 
 Usage (the chip must be otherwise idle; fails without one):
     python scripts/step_time.py --capacity 1048576,4194304
+    python scripts/step_time.py --capacity 4194304 \
+        --pads 2048,16384,4096 --pads 2048,12288,4096
 
-Prints one JSON line per capacity. ``ms_per_step`` is wall time over
-the chained steps; ``enqueue_ms_per_step`` is what the host needed to
-launch them (where the two are close, the host bound the run and
-``ms_per_step`` is an upper bound of the device's).
+Prints one JSON line per capacity and shape. ``ms_per_step`` is wall
+time over the chained steps; ``enqueue_ms_per_step`` is what the host
+needed to launch them (where the two are close, the host bound the run
+and ``ms_per_step`` is an upper bound of the device's).
 
 The batches have the shape of the benchmark's stream
 (``benchmark/gen.py``: 6 annotations a span on two hosts, 2 of them
 user annotations, and 2 binary annotations), so a launch carries 12,288
-valid annotation rows of 16,384 and 4,096 of 4,096 binary rows, as a
-served 2048-span ``Log`` call does.
+valid annotation rows and 4,096 binary rows whatever its pads, as a
+served 2048-span ``Log`` call does. ``--pads S,A,B`` (repeatable, run
+in the order given) pads them to another shape, each at least the
+valid rows: 2048,16384,4096 is the power-of-two shape the daemon
+launched until PR 35.
 
 ``--profile DIR`` captures ``--profile-steps`` more steps at each
-capacity with the JAX profiler and writes ``DIR/ops_<capacity>.json``:
-the device time of ``jit_ingest_step`` a run and of every device op
-summed by name (the name carries the result shape), longest first. Run
-it from another checkout's root to time that checkout's step.
+capacity and shape with the JAX profiler and writes
+``DIR/ops_<capacity>_<S>-<A>-<B>.json``: the device time of
+``jit_ingest_step`` a run and of every device op summed by name (the
+name carries the result shape), longest first. Run it from another
+checkout's root to time that checkout's step.
 """
 
 import argparse
@@ -39,8 +47,18 @@ import numpy as np
 
 sys.path.insert(0, ".")
 
-PADS = (2048, 16384, 4096)  # spans, annotations, binary annotations
+# A benchmark Log call's valid rows: spans, annotations, binary
+# annotations. The launch's pads are the store's own for these counts
+# (``served_pads``) unless ``--pads`` gives others.
+ROWS = (2048, 12288, 4096)
 SPANS_PER_TRACE = 8
+
+
+def served_pads():
+    """The shape ``TpuSpanStore._pad_unit`` launches ``ROWS`` at."""
+    from zipkin_tpu.store.tpu import _next_pow2, _pad_rows
+
+    return (_next_pow2(ROWS[0]), _pad_rows(ROWS[1]), _pad_rows(ROWS[2]))
 
 
 def benchmark_shape(gen, n_traces):
@@ -125,11 +143,15 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--capacity", default="1048576,4194304")
     ap.add_argument("--steps", type=int, default=40)
+    ap.add_argument("--pads", action="append", default=[],
+                    help="S,A,B: a launch shape (repeatable; none: "
+                         "the store's own pads for the benchmark's "
+                         "rows)")
     ap.add_argument("--batches", type=int, default=8,
                     help="distinct batches cycled through (new trace "
                          "ids each, so index buckets differ by step)")
     ap.add_argument("--profile", default="",
-                    help="directory for ops_<capacity>.json")
+                    help="directory for ops_<capacity>_<S>-<A>-<B>.json")
     ap.add_argument("--profile-steps", type=int, default=8)
     args = ap.parse_args()
 
@@ -145,14 +167,16 @@ def main():
         raise SystemExit(f"no chip: {d.platform}")
     gen = ColumnarTraceGen(DictionarySet(), n_services=64,
                            spans_per_trace=SPANS_PER_TRACE, topology=True)
-    batches = [
-        jax.device_put(dev.make_device_batch(
-            *benchmark_shape(gen, PADS[0] // SPANS_PER_TRACE), *PADS))
-        for _ in range(args.batches)
-    ]
-    valid = {f: int(getattr(batches[0], f))
-             for f in ("n_spans", "n_anns", "n_banns")}
-    for cap in (int(x) for x in args.capacity.split(",")):
+    shapes = [tuple(int(x) for x in p.split(",")) for p in args.pads]
+    host = [benchmark_shape(gen, ROWS[0] // SPANS_PER_TRACE)
+            for _ in range(args.batches)]
+    runs = [(int(cap), pads) for cap in args.capacity.split(",")
+            for pads in shapes or [served_pads()]]
+    for cap, pads in runs:
+        batches = [jax.device_put(dev.make_device_batch(*b, *pads))
+                   for b in host]
+        valid = {f: int(getattr(batches[0], f))
+                 for f in ("n_spans", "n_anns", "n_banns")}
         config = dev.StoreConfig(capacity=cap, **_side_rings(cap),
                                  window_seconds=60, window_buckets=64)
         state = dev.init_state(config)
@@ -168,7 +192,7 @@ def main():
         jax.block_until_ready(state.write_pos)
         t2 = time.perf_counter()
         print(json.dumps({
-            "capacity": cap, "pads": PADS, "valid_rows": valid,
+            "capacity": cap, "pads": pads, "valid_rows": valid,
             "steps": args.steps,
             "arena_slots": config.idx_layout[2],
             "ms_per_step": (t2 - t0) / args.steps * 1e3,
@@ -186,14 +210,15 @@ def main():
                 jax.profiler.stop_trace()
                 table = ops_by_name(raw)
             os.makedirs(args.profile, exist_ok=True)
-            with open(os.path.join(args.profile, f"ops_{cap}.json"),
-                      "w") as f:
-                json.dump({"capacity": cap, **table}, f)
+            name = f"ops_{cap}_{'-'.join(map(str, pads))}.json"
+            with open(os.path.join(args.profile, name), "w") as f:
+                json.dump({"capacity": cap, "pads": pads, **table}, f)
             print(json.dumps({
-                "capacity": cap, "profiled_steps": len(table["step_ms"]),
+                "capacity": cap, "pads": pads,
+                "profiled_steps": len(table["step_ms"]),
                 "device_ms_per_step": float(np.mean(table["step_ms"])),
             }), flush=True)
-        del state
+        del state, batches
 
 
 if __name__ == "__main__":

@@ -212,14 +212,19 @@ def _assert_rings_in_place(compiled, config, n_leaves, temp_limit):
         "ann:window", "bann:window", "pend:window", "span:window")
 
 
-def test_ingest_step_writes_rings_in_place(one_chip):
-    """The benchmark's step: ring 2^22, pads 2048/16384/4096. The
+@pytest.mark.parametrize("pad_anns", [12288, 16384])
+def test_ingest_step_writes_rings_in_place(one_chip, pad_anns):
+    """The benchmark's step: ring 2^22, and the pads of a 2048-span Log
+    call of 12,288 annotation rows: 2048/12288/4096, the served shape
+    since the pad ladder (PR 35; ``tests/test_pad_ladder.py`` pins the
+    rung), and the rung above it, the power of two served before. The
     temporaries were 60,322,816 B with the rings scattered into (PR 26)
     and 3.34 GB before the arena's planes."""
     config = _daemon_config(1 << 22)
     batch = dev.make_device_batch(
         SpanBatch.empty(0, 0, 0), np.zeros(0, np.int32),
-        np.zeros(0, bool), 2048, 16384, 4096, error_flag=np.zeros(0, bool))
+        np.zeros(0, bool), 2048, pad_anns, 4096,
+        error_flag=np.zeros(0, bool))
     state = _state(config, one_chip)
     compiled = _compiled_on_tpu(dev.ingest_step.lower(
         state, _abstract(batch, one_chip)))

@@ -544,19 +544,21 @@ class ShardedSpanStore(WindowedAnalytics, SuspectGuard):
     def _build_unit(self, parts):
         """Host stage-1 body shared by the serial writer, the ingest
         pipeline, and WAL replay: pad every shard's encoded part to
-        fleet-wide pow2 buckets, stack host-side, and compute each
+        fleet-wide buckets (the single store's rule: ``_next_pow2``
+        spans, ``_pad_rows`` annotation and binary rows), stack
+        host-side, and compute each
         shard's sketch-mirror delta from the PRE-PAD columns. ``parts``
         is one (SpanBatch, name_lc, indexable) triple per shard, in
         shard order. Journaled parts replayed through this same body
         re-cut bitwise-identical launches (wal/recovery)."""
         from zipkin_tpu.aggregate import windows as win_mod
         from zipkin_tpu.store.pipeline import IngestUnit
-        from zipkin_tpu.store.tpu import _next_pow2
+        from zipkin_tpu.store.tpu import _next_pow2, _pad_rows
 
         batches = [b for b, _, _ in parts]
         pad_s = _next_pow2(max(b.n_spans for b in batches))
-        pad_a = _next_pow2(max(b.n_annotations for b in batches))
-        pad_b = _next_pow2(max(b.n_binary for b in batches))
+        pad_a = _pad_rows(max(b.n_annotations for b in batches))
+        pad_b = _pad_rows(max(b.n_binary for b in batches))
         if self.config.window_enabled:
             ea, eb = win_mod.error_ids(self.dicts)
             err_of = lambda b: win_mod.span_error_flags(b, ea, eb)  # noqa: E731

@@ -14,7 +14,7 @@ discipline (PAPERS.md):
 write path (see docs/INGEST_PIPELINE.md):
 
 1. **produce** (caller threads, under the store's encode lock):
-   encode + index-policy bits + pow2 padding — everything that needs
+   encode + index-policy bits + padding — everything that needs
    the dictionaries but not the device — feeding a bounded prefetch
    queue whose depth is the ONLY backpressure on writers;
 2. **stage** (one thread): ``jax.device_put`` of the padded chunk
@@ -26,7 +26,7 @@ write path (see docs/INGEST_PIPELINE.md):
    is held for dispatch only, never for encode or H2D.
 
 Batches flow through the queues strictly FIFO and the pads are the
-same pow2 buckets the serial path uses, so a pipelined drive lands a
+same pad buckets the serial path uses, so a pipelined drive lands a
 final device state BITWISE IDENTICAL to the serial path's (gated in
 tests/test_pipeline.py and bench_smoke's pipeline phase) and hits the
 same jit cache entries (zero steady-state recompiles,
@@ -224,7 +224,7 @@ class IngestPipeline(_StageBase):
         self.h_encode = reg.register(obs.LatencySketch(
             "zipkin_store_pipeline_encode_seconds",
             "Stage 1 per apply/write_thrift call: columnar encode + "
-            "index bits + pow2 padding (outside the write lock)"))
+            "index bits + padding (outside the write lock)"))
         self.h_stage = reg.register(obs.LatencySketch(
             "zipkin_store_pipeline_stage_seconds",
             "Stage 2 per unit: H2D device_put of the padded batch"))

@@ -257,6 +257,28 @@ def _next_pow2(n: int) -> int:
     return p
 
 
+# From this many rows up a launch's annotation and binary pads take the
+# half-octave rung 3 * 2^(k-1) between each pair of powers of two.
+_LADDER_MIN = 4096
+
+
+def _pad_rows(n: int) -> int:
+    """The pad of a launch's annotation or binary-annotation dimension:
+    the smallest rung >= n of ..., 2048, 4096, 6144, 8192, 12288,
+    16384, 24576, ... (``_next_pow2`` below ``_LADDER_MIN``). The index
+    write launches each of these dimensions five times over, and a
+    padded row costs what a valid one does, so the rung between two
+    powers of two takes up to a quarter of that work off a launch
+    (12,288 annotation rows launch 12,288 and not 16,384) for at most
+    one more compiled shape an octave. A pure function of the row
+    count, never over ``_next_pow2(n)``: WAL replay re-cuts the same
+    launches and the chunkers' ring-capacity guards hold as they
+    did (docs/PERFORMANCE.md, "The pad ladder")."""
+    p = _next_pow2(n)
+    rung = p // 4 * 3
+    return rung if n <= rung and rung >= _LADDER_MIN else p
+
+
 def name_lc_ids(batch: SpanBatch, dicts: DictionarySet,
                 cache: Dict[int, int]) -> np.ndarray:
     """Lowercased span-name dictionary id per span (-1 for empty names),
@@ -480,6 +502,22 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         self._c_launches = reg.register(obs.Counter(
             "zipkin_store_ingest_launches_total",
             "Device ingest launches (chained chunks count as one)"))
+        # What the launches were made of, by dimension: the rows their
+        # shapes carried (the pads, times the parts of a chained unit)
+        # and those of them that were padding. Every label set exists
+        # from here on, so a share of 0 reads 0 and not "no sample".
+        rows = reg.register(obs.Counter(
+            "zipkin_store_launch_rows_total",
+            "Rows the ingest launches carried, padding included "
+            "(the launch shape's pad x the chunks of a chained unit)",
+            labelnames=("dim",)))
+        pad_rows = reg.register(obs.Counter(
+            "zipkin_store_launch_pad_rows_total",
+            "Rows of zipkin_store_launch_rows_total that were padding "
+            "(launched less valid)", labelnames=("dim",)))
+        self._c_launch_rows = {
+            dim: (rows.labels(dim=dim), pad_rows.labels(dim=dim))
+            for dim in ("span", "annotation", "binary")}
         # (marker, dispatch time) of the launches the device may not
         # have run yet, oldest first; the committing thread's alone.
         self._in_flight: Deque[Tuple[jax.Array, float]] = deque()
@@ -626,7 +664,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
 
     def _apply_pipelined(self, spans: Sequence[Span]) -> None:
         """Stage 1 of the ingest pipeline (caller thread, under the
-        encode lock): encode + index bits + pow2 padding, feeding the
+        encode lock): encode + index bits + padding, feeding the
         prefetch queue. The chunk flush boundary, the CHAIN_SIZES
         grouping, and the pad buckets are IDENTICAL to the serial
         path's, so both modes cut the same launch units — the basis of
@@ -975,12 +1013,14 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
 
     def _pad_unit(self, group, wal_seq: Optional[int] = None
                   ) -> IngestUnit:
-        """Pad one planned group to its pow2 buckets (host numpy — the
+        """Pad one planned group to its buckets (host numpy — the
         H2D copy is the pipeline's stage 2, or implicit at dispatch on
-        the serial path). Chained groups pad every chunk to the group
-        max and stack along a leading scan axis. pow2 bucketing bounds
-        the jit compile cache, so a warmed steady state pads into
-        already-compiled shapes only (dev.compile_count gates this).
+        the serial path): spans to a power of two, annotation and
+        binary rows to ``_pad_rows``'s half-octave ladder. Chained
+        groups pad every chunk to the group max and stack along a
+        leading scan axis. Bucketing bounds the jit compile cache, so
+        a warmed steady state pads into already-compiled shapes only
+        (dev.compile_count gates this).
 
         The per-span error bit (the window cells' error counts) is a
         pure function of (batch, dictionary state) — WAL replay
@@ -1015,8 +1055,8 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
             db = dev.make_device_batch(
                 b, name_lc_id=lc, indexable=ix,
                 pad_spans=_next_pow2(b.n_spans),
-                pad_anns=_next_pow2(b.n_annotations),
-                pad_banns=_next_pow2(b.n_binary),
+                pad_anns=_pad_rows(b.n_annotations),
+                pad_banns=_pad_rows(b.n_binary),
                 error_flag=err_of(b),
                 span_slot=None if cp is None else cp.span_slot,
                 span_gid=None if cp is None else cp.span_gid,
@@ -1027,8 +1067,8 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
                               b.n_binary, 1, False, sketch=sketch,
                               reclaims=plan.reclaims if plan else ())
         pad_s = _next_pow2(max(b.n_spans for b, _, _ in group))
-        pad_a = _next_pow2(max(b.n_annotations for b, _, _ in group))
-        pad_b = _next_pow2(max(b.n_binary for b, _, _ in group))
+        pad_a = _pad_rows(max(b.n_annotations for b, _, _ in group))
+        pad_b = _pad_rows(max(b.n_binary for b, _, _ in group))
         dbs = [
             dev.make_device_batch(
                 b, name_lc_id=lc, indexable=ix,
@@ -1108,6 +1148,14 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
                     self.state = dev.dep_sweep(self.state)
                     self._step_seq += 1
                     self._batches_since_sweep = 0
+            db = unit.db
+            for dim, pad, valid in (
+                    ("span", db.trace_id.shape[-1], unit.n_spans),
+                    ("annotation", db.ann_ts.shape[-1], unit.n_anns),
+                    ("binary", db.bann_key_id.shape[-1], unit.n_banns)):
+                launched, padding = self._c_launch_rows[dim]
+                launched.inc(pad * unit.n_parts)
+                padding.inc(pad * unit.n_parts - valid)
             self._observe_ingest()
 
     def _write_device_many(self, group) -> None:
@@ -1511,7 +1559,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
                        stage_buffers: Optional[int] = None
                        ) -> IngestPipeline:
         """Switch the write path to the three-stage ingest pipeline:
-        apply/write_thrift become stage 1 (encode + pow2 pad, outside
+        apply/write_thrift become stage 1 (encode + pad, outside
         the device critical section), a stage thread device_puts into
         double-buffered staging slots, and a commit thread holds the
         write lock only for the donating swap. ``depth`` bounds the
