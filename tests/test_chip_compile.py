@@ -284,3 +284,45 @@ def test_trace_gather_compiles(one_chip):
         st.write_pos, st.ann_write_pos, st.bann_write_pos,
         CONFIG.capacity, CONFIG.ann_capacity, CONFIG.bann_capacity,
         256, 512, 256, False))
+
+
+@pytest.mark.parametrize("scan", [False, True], ids=["bucket", "scan"])
+def test_launch_map_gather_compiles(one_chip, scan):
+    """The whole-trace read through the span buckets (or, where a
+    bucket refuses, a scan of the span ring) and the launch map (PR
+    36): the side rows found by binary search in the runs the launches
+    wrote."""
+    st = _state(CONFIG, one_chip)
+    tlay = CONFIG.trace_layout[0][dev.StoreConfig.TR_SPAN]
+
+    def cols(names):
+        return tuple(getattr(st, c) for c in names)
+
+    z = 4096
+    launches = tuple(_spec((z,), jnp.int64, one_chip) for _ in range(5)) \
+        + (_spec((z,), jnp.bool_, one_chip),)
+    _compiled_on_tpu(dev._iq_launch_gather_impl.lower(
+        st.cand_idx, st.cand_pos, st.cand_wm,
+        cols(dev.SPAN_MAT_COLS), cols(dev.ANN_MAT_COLS),
+        cols(dev.BANN_MAT_COLS), _spec((10,), jnp.int64, one_chip),
+        st.write_pos, st.ann_write_pos, st.bann_write_pos, launches,
+        _spec((), jnp.int64, one_chip),
+        (CONFIG.capacity, CONFIG.ann_capacity, CONFIG.bann_capacity,
+         tlay, 640, 5120, 2560, 8, 4, CONFIG.ann_capacity.bit_length(),
+         CONFIG.bann_capacity.bit_length(), scan)))
+
+
+def test_id_scans_compile(one_chip):
+    """An id lookup's O(ring) scans (PR 36: one candidate a span, a
+    scatter of each side row's lap to its span's slot), both kernels."""
+    st = _state(CONFIG, one_chip)
+    i32, i64 = (_spec((), t, one_chip) for t in (jnp.int32, jnp.int64))
+    _compiled_on_tpu(dev._q_by_service_impl.lower(
+        st.ann_gid, st.ann_service_id, st.row_gid, st.indexable,
+        st.name_lc_id, st.trace_id, st.ts_last, CONFIG.capacity, 64,
+        i32, i32, i64))
+    _compiled_on_tpu(dev._q_by_annotation_impl.lower(
+        st.ann_gid, st.ann_service_id, st.ann_value_id, st.row_gid,
+        st.indexable, st.ts_last, st.trace_id, st.bann_gid,
+        st.bann_key_id, st.bann_value_id, CONFIG.capacity, 64,
+        i32, i32, i32, i32, i32, i64))

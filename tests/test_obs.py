@@ -937,7 +937,9 @@ class TestStageSpans:
             "--wal-dir", str(tmp_path / "wal"), "--scribe-port", str(port)])
         store, collector, api, _shipper = build_app(args)
         srv = start_scribe(args, store, collector, api)
-        client = ScribeClient("127.0.0.1", port)
+        # The first call compiles the fused step: on a loaded test host
+        # that takes longer than the client's default 10 s.
+        client = ScribeClient("127.0.0.1", port, timeout_s=120.0)
         try:
             assert client.log(log_entries([span(1), span(2)])) \
                 == ResultCode.OK

@@ -7,10 +7,13 @@ donates the previous state's device buffers (buffer donation is how the
 ring update stays allocation-free), so a query that is still reading a
 snapshot of the old state can see its buffers deleted mid-kernel.
 
-``RWLock`` makes the swap safe: queries hold a read lock across their
-kernel launches and host gathers; ingest takes the write lock to run
-the donating step and swap the state pointer. Writer-preference keeps
-the hot ingest path from starving behind a stream of queries.
+``RWLock`` makes the swap safe: a query holds a read lock while it
+takes its snapshot of the state and dispatches its kernels, and waits
+for their results outside it (a launch already dispatched reads its
+inputs before any later donating launch reuses them: the device runs
+its queue in order); ingest takes the write lock to run the donating
+step and swap the state pointer. Writer-preference keeps the hot
+ingest path from starving behind a stream of queries.
 """
 
 from __future__ import annotations
@@ -43,8 +46,10 @@ class RWLock:
                     self._cond.notify_all()
 
     @contextlib.contextmanager
-    def write(self):
-        with self._cond:
+    def write(self, wait=None):
+        """``wait``, a context manager, is entered round the acquisition
+        alone (a span that times the wait for the lock)."""
+        with wait or contextlib.nullcontext(), self._cond:
             self._writers_waiting += 1
             while self._writer or self._readers:
                 self._cond.wait()

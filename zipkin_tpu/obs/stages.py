@@ -23,23 +23,37 @@ from typing import Optional
 from zipkin_tpu.obs.registry import LatencySketch, default_registry
 
 FAMILY = "zipkin_ingest_stage_seconds"
+QUERY_FAMILY = "zipkin_query_stage_seconds"
+
+_HELP = {
+    FAMILY: ("Seconds per stage of the write path as the daemon sees it "
+             "(one child per span of obs.stage that names no sketch of "
+             "its own; docs/OBSERVABILITY.md has the table)"),
+    QUERY_FAMILY: ("Seconds per stage of the read path (obs.query_stage: "
+                   "compile, rw_wait, device, decode; docs/OBSERVABILITY.md "
+                   "has the table)"),
+}
 
 _annotation = None  # jax.profiler.TraceAnnotation, bound at the first span
 
 
-def stage_family() -> LatencySketch:
-    """The ``zipkin_ingest_stage_seconds{stage}`` family of the
-    process-wide registry (registered at first use)."""
+def stage_family(name: str = FAMILY) -> LatencySketch:
+    """The ``{name}{stage}`` family of the process-wide registry
+    (registered at first use): ``zipkin_ingest_stage_seconds`` for the
+    write path, ``zipkin_query_stage_seconds`` for the read path."""
     reg = default_registry()
-    fam = reg.get(FAMILY)
+    fam = reg.get(name)
     if fam is None:
-        fam = reg.register(LatencySketch(
-            FAMILY,
-            "Seconds per stage of the write path as the daemon sees it "
-            "(one child per span of obs.stage that names no sketch of "
-            "its own; docs/OBSERVABILITY.md has the table)",
-            labelnames=("stage",)))
+        fam = reg.register(LatencySketch(name, _HELP[name],
+                                         labelnames=("stage",)))
     return fam
+
+
+def query_stage(name: str, **ids) -> "stage":
+    """A span of the read path: as ``stage``, observed into
+    ``zipkin_query_stage_seconds{stage=<name after its dot>}``."""
+    return stage(name, stage_family(QUERY_FAMILY).labels(
+        stage=name.partition(".")[2] or name), **ids)
 
 
 class stage:

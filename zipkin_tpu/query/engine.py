@@ -42,7 +42,10 @@ a thin executor facade with identical semantics.
 
 Observability (the PR 4 ingest split, applied to reads):
 ``zipkin_query_serve_seconds{tier=sketch|cache|index}`` is end-to-end
-request service time including cache/sketch hits;
+request service time including cache/sketch hits, and the span
+``query.serve`` marks the same reads on the profiler's clock (the
+store's ``query.compile`` / ``query.rw_wait`` / ``query.device`` /
+``query.decode`` spans nest inside it);
 ``zipkin_query_dispatch_seconds`` isolates actual device launch + D2H
 time. Cache hits/misses and sketch answers are counters.
 
@@ -56,6 +59,7 @@ After ``close()`` queries still answer (inline, uncoalesced).
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from collections import OrderedDict
@@ -72,6 +76,19 @@ from zipkin_tpu.store.base import ReadSpanStore, service_scan_only
 DEFAULT_COALESCE_WINDOW_S = 0.002
 
 _MISS = object()
+
+
+def _served(method):
+    """One read in the engine, whatever tier answers it, as the span
+    ``query.serve`` on the profiler's clock (its seconds are the
+    ``zipkin_query_serve_seconds{tier}`` the method observes)."""
+    @functools.wraps(method)
+    def serve(self, *args, **kwargs):
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation("query.serve"):
+            return method(self, *args, **kwargs)
+    return serve
 
 
 def _copy_json(v):
@@ -234,6 +251,7 @@ class QueryEngine:
 
     # -- index tier: trace-id lookups ------------------------------------
 
+    @_served
     def get_trace_ids_multi(self, queries) -> List[list]:
         """The read hub: per-query result cache in front of the
         standing executor; only misses ride a device launch. Results
@@ -279,12 +297,14 @@ class QueryEngine:
 
     # -- index tier: row reads (frontier-cached) -------------------------
 
+    @_served
     def traces_exist(self, trace_ids: Sequence[int]) -> Set[int]:
         ids = tuple(trace_ids)
         return self._cached(("exist", ids),
                             lambda: self.store.traces_exist(ids),
                             copy=set)
 
+    @_served
     def get_spans_by_trace_ids(self, trace_ids: Sequence[int]):
         ids = tuple(trace_ids)
         return self._cached(
@@ -292,12 +312,14 @@ class QueryEngine:
             lambda: self.store.get_spans_by_trace_ids(ids),
             copy=lambda v: [list(t) for t in v])
 
+    @_served
     def get_traces_duration(self, trace_ids: Sequence[int]):
         ids = tuple(trace_ids)
         return self._cached(
             ("durations", ids),
             lambda: self.store.get_traces_duration(ids), copy=list)
 
+    @_served
     def get_dependencies(self, start_ts=None, end_ts=None):
         # The first read after writes runs the store's pending sweep
         # (a frontier advance), so it computes-without-caching; repeat
@@ -310,6 +332,7 @@ class QueryEngine:
 
     # -- sketch tier: catalogs + aggregates ------------------------------
 
+    @_served
     def get_all_service_names(self) -> Set[str]:
         t0 = time.perf_counter()
         m = self._sketch_mirror()
@@ -335,6 +358,7 @@ class QueryEngine:
         self._serve("sketch", t0)
         return out
 
+    @_served
     def get_span_names(self, service: str) -> Set[str]:
         t0 = time.perf_counter()
         m = self._sketch_mirror()
@@ -376,6 +400,7 @@ class QueryEngine:
             return m, None, False
         return m, svc, service_scan_only(svc, self.hot.config)
 
+    @_served
     def service_duration_quantiles(self, service: str,
                                    qs: Sequence[float]):
         from zipkin_tpu.ops.quantile import quantiles_host
@@ -417,18 +442,21 @@ class QueryEngine:
         self._serve("sketch", t0)
         return out
 
+    @_served
     def top_annotations(self, service: str, k: int = 10):
         return self._top_row(
             service, k, lambda m, s: m.ann_value_row(s),
             self.hot.dicts.annotations,
             self.store.top_annotations, "top_ann")
 
+    @_served
     def top_binary_keys(self, service: str, k: int = 10):
         return self._top_row(
             service, k, lambda m, s: m.bann_key_row(s),
             self.hot.dicts.binary_keys,
             self.store.top_binary_keys, "top_bkey")
 
+    @_served
     def estimated_unique_traces(self) -> float:
         from zipkin_tpu.ops import hll
 
@@ -480,6 +508,7 @@ class QueryEngine:
                 time.perf_counter() - t0)
         return out
 
+    @_served
     def windowed_quantiles(self, service: str, qs,
                            start_us=None, end_us=None):
         qs = list(qs)
@@ -489,6 +518,7 @@ class QueryEngine:
             (service, qs), {"start_us": start_us, "end_us": end_us},
             copy=list)
 
+    @_served
     def slo_burn(self, service: str, objective=None, windows_s=None,
                  now_us=None):
         key = ("win_burn", service, objective,
@@ -498,6 +528,7 @@ class QueryEngine:
             {"objective": objective, "windows_s": windows_s,
              "now_us": now_us}, copy=_copy_json)
 
+    @_served
     def latency_heatmap(self, service: str, start_us=None, end_us=None,
                         bands=None):
         return self._window_call(

@@ -264,7 +264,7 @@ def index_topk_or_none(limit: int, k: int, candidates, complete,
 
 
 def index_first_topk(limit: int, k_max: int, index_fetch,
-                     scan_fetch, stats=None) -> List["IndexedTraceId"]:
+                     scan_fetch, note=None) -> List["IndexedTraceId"]:
     """Index fast path with scan fallback, the shared read policy of the
     device stores. ``index_fetch(k)`` reads an O(depth) index bucket and
     returns (candidates, complete, watermark, window):
@@ -293,9 +293,12 @@ def index_first_topk(limit: int, k_max: int, index_fetch,
     timestamp order) keeps wrapped buckets trusted; shuffled arrival
     degrades to the scan, never to a wrong answer.
 
-    ``stats`` (optional) is any object with ``index_hits`` /
-    ``index_fallbacks`` counters — the accounting hook /metrics reads
-    (TpuSpanStore passes itself)."""
+    ``note`` (optional) is called ``note(True)`` for an answer from the
+    index and ``note(False, gate)`` for a fallback, ``gate`` naming the
+    check that refused: "watermark" (the limit-th candidate ranks under
+    what the wrapped bucket displaced) or "underfull" (a wrapped bucket
+    with fewer live candidates than the limit, or a saturated window) —
+    the accounting hook /metrics reads (TpuSpanStore.note_index)."""
     k = limit * 8
     candidates, complete, watermark, window = index_fetch(k)
     ids = index_topk_or_none(limit, min(k, window), candidates,
@@ -309,11 +312,13 @@ def index_first_topk(limit: int, k_max: int, index_fetch,
         ids = index_topk_or_none(limit, min(k, window), candidates,
                                  complete, watermark)
     if ids is not None:
-        if stats is not None:
-            stats.index_hits += 1
+        if note is not None:
+            note(True)
         return ids
-    if stats is not None:
-        stats.index_fallbacks += 1
+    if note is not None:
+        note(False, "watermark"
+             if len(dedup_rank_limit(candidates, limit)) >= limit
+             else "underfull")
     return topk_ids_with_escalation(limit, k_max, scan_fetch)
 
 

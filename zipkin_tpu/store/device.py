@@ -29,6 +29,8 @@ retraces only when shapes actually change.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import threading
 from dataclasses import dataclass, field, replace
 from functools import partial
@@ -49,6 +51,97 @@ I64_MAX = np.int64(2**63 - 1)
 I64_MIN = np.int64(-(2**63))
 I32_MIN = np.int32(-(2**31))
 NO_TS = -1
+
+
+# -- read programs, compiled before the store's read lock is taken -------
+
+_compiling = threading.local()
+
+
+def compiling() -> bool:
+    """Whether this thread is inside ``compile_only()``."""
+    return getattr(_compiling, "on", False)
+
+
+@contextlib.contextmanager
+def compile_only():
+    """Inside, a ReadProgram compiles for its arguments' shapes and
+    returns its results' shapes (jax.ShapeDtypeStruct), running nothing.
+    TpuSpanStore._fetch passes ``shapes_of(state)`` through a read's
+    launch this way before it takes the read lock, so a read that meets
+    a program or a k it has not run yet compiles it off the lock: the
+    commit never waits behind a compile."""
+    _compiling.on = True
+    try:
+        yield
+    finally:
+        _compiling.on = False
+
+
+def shapes_of(tree):
+    """``tree``'s arrays as jit keys its cache on them (a committed
+    array keeps its sharding). Reads only the arrays' metadata, so a
+    leaf that a donating launch has since taken is fine."""
+    def one(x):
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, weak_type=x.weak_type,
+            sharding=x.sharding if x.committed else None)
+    return jax.tree_util.tree_map(one, tree)
+
+
+def _arg_key(arg):
+    """What jit's cache tells a dynamic argument by: structure, shapes,
+    dtypes, weak types; a Python scalar's type and not its value."""
+    leaves, tree = jax.tree_util.tree_flatten(arg)
+    return tree, tuple(
+        (x.shape, str(x.dtype), getattr(x, "weak_type", False))
+        if hasattr(x, "dtype") else type(x) for x in leaves)
+
+
+class ReadProgram:
+    """``jax.jit(fn, static_argnums)`` that compiles ahead inside
+    ``compile_only()``. Two readers that need one new program at once
+    compile it once: the second waits for the first."""
+
+    def __init__(self, fn, static_argnums=()):
+        self._jit = jax.jit(fn, static_argnums=static_argnums)
+        self._static = frozenset(static_argnums)
+        self._shapes: dict = {}  # guarded-by: _lock
+        self._pending: dict = {}  # guarded-by: _lock
+        self._lock = threading.Lock()  # lock-order: 92 read-program (leaf)
+        functools.update_wrapper(self, fn)
+
+    def __getattr__(self, name):  # lower, trace, _cache_size, ...
+        return getattr(self._jit, name)
+
+    def __call__(self, *args):
+        if not compiling():
+            return self._jit(*args)
+        key = tuple(a if i in self._static else _arg_key(a)
+                    for i, a in enumerate(args))
+        while True:
+            with self._lock:
+                if key in self._shapes:
+                    return self._shapes[key]
+                waiting = self._pending.get(key)
+                if waiting is None:
+                    done = self._pending[key] = threading.Event()
+                    break
+            waiting.wait()  # another reader compiles it; it may fail
+        try:
+            out = self._jit.lower(*args).compile().out_info
+            with self._lock:
+                self._shapes[key] = out
+            return out
+        finally:
+            with self._lock:
+                del self._pending[key]
+            done.set()
+
+
+def read_program(static_argnums=()):
+    """Decorator: the function as a ReadProgram."""
+    return lambda fn: ReadProgram(fn, static_argnums)
 
 
 class StoreConfig(NamedTuple):
@@ -1053,7 +1146,7 @@ def svc_histogram(state: StoreState) -> Q.LogHistogram:
     return Q.LogHistogram(state.svc_hist, gamma, 1.0)
 
 
-@partial(jax.jit, static_argnums=(0,))
+@read_program(static_argnums=(0,))
 def _svc_scan_catalog_impl(dims, service_id_col, duration, row_gid,
                            ann_gid, ann_service_id, ann_value_id,
                            name_id_col, name_lc_col, indexable,
@@ -1096,7 +1189,7 @@ def _svc_scan_catalog_impl(dims, service_id_col, duration, row_gid,
     return name_row, dur_row, ann_row, bkey_row
 
 
-@partial(jax.jit, static_argnums=(0, 1))
+@read_program(static_argnums=(0, 1))
 def _overflow_presence_impl(base, n_over, ann_gid, ann_service_id,
                             bann_gid, bann_service_id):
     pres = jnp.zeros(n_over, jnp.int32)
@@ -2245,7 +2338,7 @@ def counter_block(state: StoreState) -> jnp.ndarray:
     ])
 
 
-@jax.jit
+@read_program()
 def _total_dep_impl(dep_moments, dep_banks, dep_window):
     banks = M.reduce_moments(dep_banks, axis=0)
     return M.combine(M.combine(dep_moments, banks), dep_window)
@@ -2260,7 +2353,7 @@ def total_dep_moments(state: "StoreState"):
     )
 
 
-@jax.jit
+@read_program()
 def _dep_in_range_impl(dep_moments, dep_banks, dep_bank_ts,
                        dep_overflow_ts, dep_window, dep_window_ts,
                        start_ts, end_ts):
@@ -2291,7 +2384,7 @@ def _compact_bank(bank, k: int):
     return nz, idx.astype(jnp.int32), bank[idx]
 
 
-@partial(jax.jit, static_argnums=(3,))
+@read_program(static_argnums=(3,))
 def total_dep_moments_compact(dep_moments, dep_banks, dep_window,
                               k: int):
     """total_dep_moments fused with _compact_bank in one launch."""
@@ -2301,7 +2394,7 @@ def total_dep_moments_compact(dep_moments, dep_banks, dep_window,
     )
 
 
-@partial(jax.jit, static_argnums=(8,))
+@read_program(static_argnums=(8,))
 def dep_in_range_compact(dep_moments, dep_banks, dep_bank_ts,
                          dep_overflow_ts, dep_window, dep_window_ts,
                          start_ts, end_ts, k: int):
@@ -2851,7 +2944,7 @@ def _span_slot(gid, row_gid, capacity: int):
 def _topk_candidates(tid, ts, valid, k: int):
     """Top-``k`` candidate rows by ts desc (validity folded into the
     key; valid rows have ts >= 0 by construction). Returns ONE stacked
-    [3, k] i64 array (tid, ts, ok).
+    [3, min(k, rows)] i64 array (tid, ts, ok).
 
     Callers dedup candidates by trace id on the host
     (store.base.dedup_rank_limit) and re-query with a bigger ``k`` when
@@ -2863,29 +2956,49 @@ def _topk_candidates(tid, ts, valid, k: int):
     traces found rank strictly above every excluded trace.
     """
     key = jnp.where(valid, ts, jnp.int64(-1))
-    vals, idx = jax.lax.top_k(key, k)
+    vals, idx = jax.lax.top_k(key, min(k, key.shape[0]))
     return jnp.stack([tid[idx], ts[idx], (vals >= 0).astype(jnp.int64)])
 
 
-@partial(jax.jit, static_argnums=(7, 8))
+def _owned(gid, hit, row_gid, capacity: int):
+    """Per span ring slot: whether the span there owns a side row that
+    ``hit`` marks. One scatter over the side rows, no gather: a gid is
+    its lap (gid // capacity; the page epoch in the paged layout) and
+    its slot, and the gids written at a slot only grow, so the newest
+    lap among the slot's hit rows is the span's own lap iff one of them
+    is the span's. The per-row gathers of span columns this replaces
+    took 20-30 ns a row on the chip (PERF.md 5); XLA lowers the scatter
+    as one sort of the (slot, lap) pairs. i32 max, not a bool scatter:
+    bool scatters serialize on this backend."""
+    slot = jnp.clip((gid % capacity).astype(jnp.int32), 0, capacity - 1)
+    lap = (gid // capacity).astype(jnp.int32) + 1
+    laps = jnp.zeros(capacity + 1, jnp.int32).at[
+        jnp.where(hit & (gid >= 0), slot, capacity)
+    ].max(lap, mode="drop")[:-1]
+    return (row_gid >= 0) & (laps == (row_gid // capacity).astype(
+        jnp.int32) + 1)
+
+
+@read_program(static_argnums=(7, 8))
 def _q_by_service_impl(
     ann_gid, ann_service_id, row_gid, indexable, name_lc_col, trace_id,
     ts_last, capacity: int, k: int, svc_id, name_lc_id, end_ts,
 ):
-    slot, live = _span_slot(ann_gid, row_gid, capacity)
-    ok = live & (ann_service_id == svc_id)
-    ok &= indexable[slot]
-    ok &= (name_lc_id < 0) | (name_lc_col[slot] == name_lc_id)
-    ts = ts_last[slot]
-    ok &= (ts >= 0) & (ts <= end_ts)
-    return _topk_candidates(trace_id[slot], ts, ok, k)
+    """One candidate per span: a span with a live annotation hosted by
+    ``svc_id`` (any side row it owns counts once)."""
+    ok = _owned(ann_gid, ann_service_id == svc_id, row_gid, capacity)
+    ok &= indexable
+    ok &= (name_lc_id < 0) | (name_lc_col == name_lc_id)
+    ok &= (ts_last >= 0) & (ts_last <= end_ts)
+    return _topk_candidates(trace_id, ts_last, ok, k)
 
 
 def query_trace_ids_by_service(
     state: StoreState, svc_id, name_lc_id, end_ts, k: int
 ):
     """Candidate spans of a service (any annotation host), optional
-    span-name match, last_ts <= end_ts, top ``k`` by last_ts desc.
+    span-name match, last_ts <= end_ts, top ``k`` by last_ts desc (one
+    candidate a span; never more than the span ring holds).
 
     Reference semantics: getTraceIdsByName (SpanStore.scala /
     CassieSpanStore.scala:366) with index ts = span last timestamp.
@@ -2902,7 +3015,7 @@ def query_trace_ids_by_service(
     )
 
 
-@partial(jax.jit, static_argnums=(10, 11))
+@read_program(static_argnums=(10, 11))
 def _q_by_annotation_impl(
     ann_gid, ann_service_id, ann_value_col, row_gid, indexable, ts_last,
     trace_id, bann_gid, bann_key_col, bann_value_col,
@@ -2910,44 +3023,24 @@ def _q_by_annotation_impl(
     svc_id, ann_value_id, bann_key_id, bann_value_id, bann_value_id2,
     end_ts,
 ):
-    a_slot, a_live = _span_slot(ann_gid, row_gid, capacity)
-    # Build: which span slots have an annotation hosted by svc_id.
-    hit = a_live & (ann_service_id == svc_id)
-    # i32 max instead of a bool scatter-set: bool scatters serialize on
-    # this backend (ann-ring-sized rows), i32 dup-index max vectorizes.
-    per_slot = jnp.zeros(capacity + 1, jnp.int32).at[
-        jnp.where(hit, a_slot, capacity)
-    ].max(hit.astype(jnp.int32), mode="drop")[:-1] > 0
-
-    a_ok = (
-        a_live
-        & (ann_value_col == ann_value_id) & (ann_value_id >= 0)
-        & indexable[a_slot]
-        & per_slot[a_slot]
-    )
-    a_ts = ts_last[a_slot]
-    a_ok &= (a_ts >= 0) & (a_ts <= end_ts)
-
-    b_slot, b_live = _span_slot(bann_gid, row_gid, capacity)
+    """One candidate per span: a span with a live annotation hosted by
+    ``svc_id`` and a live annotation of the value or binary annotation
+    of the key (and value) asked for."""
+    hosted = _owned(ann_gid, ann_service_id == svc_id, row_gid, capacity)
+    a_hit = _owned(
+        ann_gid, (ann_value_col == ann_value_id) & (ann_value_id >= 0),
+        row_gid, capacity)
     value_free = (bann_value_id < 0) & (bann_value_id2 < 0)
     value_hit = (
         ((bann_value_id >= 0) & (bann_value_col == bann_value_id))
         | ((bann_value_id2 >= 0) & (bann_value_col == bann_value_id2))
     )
-    b_ok = (
-        b_live
-        & (bann_key_col == bann_key_id) & (bann_key_id >= 0)
-        & (value_free | value_hit)
-        & indexable[b_slot]
-        & per_slot[b_slot]
-    )
-    b_ts = ts_last[b_slot]
-    b_ok &= (b_ts >= 0) & (b_ts <= end_ts)
-
-    tid = jnp.concatenate([trace_id[a_slot], trace_id[b_slot]])
-    ts = jnp.concatenate([a_ts, b_ts])
-    ok = jnp.concatenate([a_ok, b_ok])
-    return _topk_candidates(tid, ts, ok, k)
+    b_hit = _owned(
+        bann_gid, (bann_key_col == bann_key_id) & (bann_key_id >= 0)
+        & (value_free | value_hit), row_gid, capacity)
+    ok = hosted & (a_hit | b_hit) & indexable
+    ok &= (ts_last >= 0) & (ts_last <= end_ts)
+    return _topk_candidates(trace_id, ts_last, ok, k)
 
 
 def query_trace_ids_by_annotation(
@@ -2994,7 +3087,7 @@ def _iq_finish(entries, cnt, wm, row_gid, indexable, ts_last, trace_id,
     return mat, cnt <= depth, wm
 
 
-@partial(jax.jit, static_argnums=(7, 8, 9))
+@read_program(static_argnums=(7, 8, 9))
 def _iq_service_impl(entries, pos, wm, row_gid, indexable, trace_id,
                      ts_last, capacity: int, layout, k: int,
                      svc, end_ts):
@@ -3031,7 +3124,7 @@ def _key_lookup_wm(key_tab, key_wm, mixed):
     return found, wmv
 
 
-@partial(jax.jit, static_argnums=(7, 8, 9))
+@read_program(static_argnums=(7, 8, 9))
 def _iq_verify_impl(entries, pos, wm, row_gid, indexable, trace_id,
                     ts_last, capacity: int, layout, k: int,
                     key_parts, end_ts, key_tab, key_wm, write_pos,
@@ -3072,7 +3165,7 @@ def _iq_verify_impl(entries, pos, wm, row_gid, indexable, trace_id,
     return mat, complete | key_complete, out_wm
 
 
-@partial(jax.jit, static_argnums=(7, 8, 9))
+@read_program(static_argnums=(7, 8, 9))
 def _iq_verify2_impl(entries, pos, wm, row_gid, indexable, trace_id,
                      ts_last, capacity: int, layout, k: int,
                      key_parts1, key_parts2, end_ts,
@@ -3112,7 +3205,7 @@ def _iq_verify2_impl(entries, pos, wm, row_gid, indexable, trace_id,
     return mat, complete | key_complete, out_wm
 
 
-@partial(jax.jit, static_argnums=(7, 8, 9))
+@read_program(static_argnums=(7, 8, 9))
 def _iq_multi_impl(entries, pos, wm, row_gid, indexable, trace_id,
                    ts_last, capacity: int, k: int, k_max: int,
                    b_base, s_base, n_b, depth,
@@ -3291,7 +3384,7 @@ def iquery_trace_ids_by_annotation(state: StoreState, svc_id,
     )
 
 
-@partial(jax.jit, static_argnums=(8, 9))
+@read_program(static_argnums=(8, 9))
 def _iq_durations_impl(entries, pos, wm, trace_id, row_gid, ts_first,
                        ts_last, write_pos, capacity: int, layout,
                        sorted_qids):
@@ -3338,7 +3431,7 @@ def iquery_durations(state: StoreState, sorted_qids):
     )
 
 
-@partial(jax.jit, static_argnums=(10,))
+@read_program(static_argnums=(10,))
 def _iq_gather_impl(
     tr_entries, tr_pos, tr_wm,
     span_cols, ann_cols, bann_cols, sorted_qids,
@@ -3433,7 +3526,155 @@ def iquery_gather_trace_rows(
     )
 
 
-@jax.jit
+# A launch's rows as the host recorded them (TpuSpanStore's launch map):
+# span gids [wp0, next launch's wp0), annotation ring positions [a0, a1),
+# binary ring positions [b0, b1), and whether each side's rows were
+# written in span order (owner gids ascending over the positions).
+LAUNCH_COLS = ("wp0", "a0", "a1", "b0", "b1", "ordered")
+
+
+@read_program(static_argnums=(12,))
+def _iq_launch_gather_impl(
+    tr_entries, tr_pos, tr_wm,
+    span_cols, ann_cols, bann_cols, sorted_qids,
+    write_pos, ann_write_pos, bann_write_pos,
+    launches, floor,
+    statics,
+):
+    """Whole traces through the trace-membership SPAN family alone: the
+    bucket gives a queried trace's span rows (exact under its
+    displaced-gid gate), and each span's annotation and binary rows are
+    found where the launch that wrote the span put them. A launch
+    writes its spans' side rows as one run of ring positions, in span
+    order, so within [a0, a1) the owner gids ascend: a binary search
+    finds the first row of a span, and its rows follow. No side-row
+    bucket is read, so a trace too large for one (a 21-span trace
+    fills the 128-row annotation bucket) stays on the index.
+
+    With ``scan`` (a static) the span rows come from a scan of the
+    span ring instead of the bucket, for a trace whose span bucket
+    fails its gate: elementwise over the span ring, where the
+    whole-ring gather (_gather_impl) reads every side row's owner.
+
+    Returns (counts [3], span_mat, ann_mat, bann_mat, flags [4]):
+    flags = (span buckets exact, every found span known to the launch
+    map and its launch ordered, no span with more annotation rows than
+    ``per_a``, none with more binary rows than ``per_b``)."""
+    (capacity, ann_capacity, bann_capacity, lay_s, k_spans, k_anns,
+     k_banns, per_a, per_b, steps_a, steps_b, scan) = statics
+    trace_id = span_cols[0]
+    row_gid = span_cols[-1]
+    if scan:
+        nq = sorted_qids.shape[0]
+        at = jnp.clip(jnp.searchsorted(sorted_qids, trace_id), 0, nq - 1)
+        hit = (row_gid >= 0) & (sorted_qids[at] == trace_id)
+        count_s = hit.sum(dtype=jnp.int64)
+        vals_s, sslot = jax.lax.top_k(
+            jnp.where(hit, I64_MAX - row_gid, jnp.int64(-1)), k_spans)
+        s_ok = vals_s >= 0
+        s_gid = jnp.where(s_ok, row_gid[sslot], -1)
+        gate_s = jnp.bool_(True)
+    else:
+        b_base, s_base, _, depth = lay_s
+        lb = _bucket_of(_mixb([sorted_qids]), lay_s[2])
+        qb = jnp.int32(b_base) + lb
+        rows = (jnp.int32(s_base) + lb[:, None] * depth
+                + jnp.arange(depth, dtype=jnp.int32)[None, :])
+        s_gid = _arena_col(tr_entries, rows, 0)
+        gate_s = ((tr_pos[qb] <= depth)
+                  | (tr_wm[qb] < write_pos - capacity)).all()
+        s_slot = jnp.clip((s_gid % capacity).astype(jnp.int32), 0,
+                          capacity - 1)
+        s_ok = ((s_gid >= 0) & (row_gid[s_slot] == s_gid)
+                & (trace_id[s_slot] == sorted_qids[:, None]))
+        count_s = s_ok.sum(dtype=jnp.int64)
+        key_s = jnp.where(s_ok, I64_MAX - s_gid, jnp.int64(-1)).reshape(-1)
+        vals_s, sel_s = jax.lax.top_k(key_s, k_spans)  # oldest gid first
+        sslot = s_slot.reshape(-1)[sel_s]
+    span_mat = jnp.stack([c[sslot].astype(jnp.int64) for c in span_cols])
+    span_mat = jnp.where((vals_s >= 0)[None, :], span_mat, -1)
+
+    wp0, a0, a1, b0, b1, ordered = launches
+    z = jnp.searchsorted(wp0, s_gid, side="right") - 1
+    zc = jnp.clip(z, 0, wp0.shape[0] - 1)
+    # A span before the first launch the map keeps, but at or after
+    # its floor, has no side rows left in the rings (the map drops a
+    # launch only once both side rings have overwritten its rows).
+    in_map = s_ok & (z >= 0)
+    known = ~s_ok | (s_gid >= floor)
+    known &= ~in_map | ordered[zc]
+
+    def runs(lo0, hi0, ring_wp, ring_cap, cols, per, steps, k):
+        owner = cols[0]
+
+        def owner_at(pos):
+            return owner[jnp.clip((pos % ring_cap).astype(jnp.int32), 0,
+                                  ring_cap - 1)]
+
+        # the launch's rows still resident: the ring may have lapped
+        # into the front of the run, never into its middle
+        lo = jnp.where(in_map, jnp.maximum(lo0[zc], ring_wp - ring_cap), 0)
+        hi = jnp.where(in_map, jnp.maximum(hi0[zc], lo), 0)
+
+        def halve(_, lh):
+            lo_, hi_ = lh
+            mid = (lo_ + hi_) // 2
+            right = (mid < hi_) & (owner_at(mid) < s_gid)
+            return (jnp.where(right, mid + 1, lo_),
+                    jnp.where(right, hi_, mid))
+
+        first, _ = jax.lax.fori_loop(0, steps, halve, (lo, hi))
+        pos = first[..., None] + jnp.arange(per, dtype=jnp.int64)
+        ok = (in_map[..., None] & (pos < hi[..., None])
+              & (owner_at(pos) == s_gid[..., None]))
+        after = first + per
+        over = (in_map & (after < hi) & (owner_at(after) == s_gid)).any()
+        count = ok.sum(dtype=jnp.int64)
+        key = jnp.where(ok, I64_MAX - pos, jnp.int64(-1)).reshape(-1)
+        vals, sel = jax.lax.top_k(key, min(k, key.shape[0]))
+        rslot = jnp.clip((pos.reshape(-1)[sel] % ring_cap).astype(
+            jnp.int32), 0, ring_cap - 1)
+        mat = jnp.stack([c[rslot].astype(jnp.int64) for c in cols])
+        return count, jnp.where((vals >= 0)[None, :], mat, -1), over
+
+    count_a, ann_mat, over_a = runs(a0, a1, ann_write_pos, ann_capacity,
+                                    ann_cols, per_a, steps_a, k_anns)
+    count_b, bann_mat, over_b = runs(b0, b1, bann_write_pos,
+                                     bann_capacity, bann_cols, per_b,
+                                     steps_b, k_banns)
+    counts = jnp.stack([count_s, count_a, count_b])
+    flags = jnp.stack([gate_s, known.all(), ~over_a, ~over_b])
+    return counts, span_mat, ann_mat, bann_mat, flags
+
+
+def iquery_launch_gather_rows(state: StoreState, sorted_qids, launches,
+                              floor: int, k_spans: int, k_anns: int,
+                              k_banns: int, per_a: int, per_b: int,
+                              scan: bool = False):
+    """Host wrapper for _iq_launch_gather_impl. ``launches`` is the
+    tuple of LAUNCH_COLS numpy arrays (wp0 ascending, padded with
+    I64_MAX rows), ``floor`` the first span gid the map covers. The
+    binary searches take as many halvings as a run as long as the ring
+    needs (a run is never longer), so no launch recompiles them."""
+    c = state.config
+    tlay, _, _ = c.trace_layout
+    statics = (c.capacity, c.ann_capacity, c.bann_capacity,
+               tlay[StoreConfig.TR_SPAN], k_spans, k_anns, k_banns,
+               per_a, per_b, c.ann_capacity.bit_length(),
+               c.bann_capacity.bit_length(), scan)
+    return _iq_launch_gather_impl(
+        state.cand_idx, state.cand_pos, state.cand_wm,
+        tuple(getattr(state, col) for col in SPAN_MAT_COLS),
+        tuple(getattr(state, col) for col in ANN_MAT_COLS),
+        tuple(getattr(state, col) for col in BANN_MAT_COLS),
+        sorted_qids,
+        state.write_pos, state.ann_write_pos, state.bann_write_pos,
+        tuple(jnp.asarray(a) for a in launches), jnp.int64(floor),
+        statics,
+    )
+
+
+@read_program()
 def _q_durations_impl(trace_id, row_gid, ts_first, ts_last, sorted_qids):
     nq = sorted_qids.shape[0]
     live = row_gid >= 0
@@ -3490,7 +3731,7 @@ BANN_MAT_COLS = ("bann_gid", "bann_key_id", "bann_value_id", "bann_type",
                  "bann_service_id", "bann_endpoint_id")
 
 
-@partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12, 13))
+@read_program(static_argnums=(7, 8, 9, 10, 11, 12, 13))
 def _gather_impl(
     span_cols, ann_cols, bann_cols, sorted_qids,
     write_pos, ann_write_pos, bann_write_pos,
@@ -3658,7 +3899,7 @@ def gather_trace_rows(
     )
 
 
-@partial(jax.jit, static_argnums=(8, 9, 10, 11, 12, 13, 14))
+@read_program(static_argnums=(8, 9, 10, 11, 12, 13, 14))
 def _paged_gather_impl(
     span_cols, ann_cols, bann_cols, sorted_qids, pages, epochs,
     ann_write_pos, bann_write_pos,
@@ -3799,7 +4040,8 @@ _INGEST_JITS = (
 _QUERY_JITS = (
     _iq_multi_impl, _iq_service_impl, _iq_verify_impl,
     _iq_verify2_impl, _iq_durations_impl, _iq_gather_impl,
-    _q_by_service_impl, _q_by_annotation_impl, _q_durations_impl,
+    _iq_launch_gather_impl, _q_by_service_impl, _q_by_annotation_impl,
+    _q_durations_impl,
     _gather_impl, _paged_gather_impl, counter_block,
 )
 

@@ -100,6 +100,9 @@ class IngestUnit(NamedTuple):
     # through the eviction sink BEFORE the launch (per-page
     # captured-before-overwrite). Empty for ring units.
     reclaims: tuple = ()
+    # Every part's annotation and binary rows in span order (stage 1
+    # checks; TpuSpanStore's launch map trusts only such launches).
+    ordered: bool = False
 
 
 class _StageBase:

@@ -42,7 +42,7 @@ from zipkin_tpu.columnar.schema import SpanBatch
 from zipkin_tpu.models.constants import CORE_ANNOTATIONS
 from zipkin_tpu.models.dependencies import Dependencies, DependencyLink, Moments
 from zipkin_tpu.models.span import Span
-from zipkin_tpu.obs.stages import stage
+from zipkin_tpu.obs.stages import QUERY_FAMILY, query_stage, stage, stage_family
 from zipkin_tpu.ops import hll
 from zipkin_tpu.ops import quantile as Q
 from zipkin_tpu.store import device as dev
@@ -234,6 +234,42 @@ def gate_multi_probes(probes, limits, per_probe):
     return out
 
 
+@dev.read_program()
+def _fresh(tree):
+    """Copies of state leaves in buffers of their own: a read fetches
+    them after it has let go of the read lock, when the next donating
+    launch may already have taken the leaves themselves."""
+    return jax.tree_util.tree_map(jnp.copy, tree)
+
+
+@dev.read_program()
+def _row(table, i):
+    """Row ``i`` of a state leaf, in a buffer of its own (as _fresh)."""
+    return table[i]
+
+
+@dev.read_program()
+def _clip_ts(ts_min, ts_max, start_ts, end_ts):
+    """The state's timestamp range cut to [start_ts, end_ts]."""
+    return jnp.maximum(ts_min, start_ts), jnp.minimum(ts_max, end_ts)
+
+
+# The gates an index-tier read can fail (TpuSpanStore.note_index): for
+# an id lookup in each candidate family (service, service + span name,
+# service + annotation, service + binary annotation), a wrapped bucket
+# whose limit-th candidate ranks under its watermark, or a wrapped one
+# with fewer live candidates than the limit (or a saturated window);
+# for a whole trace, a span bucket whose displaced rows are still
+# resident, a span with more annotation rows than the widest read, one
+# with more binary rows, one the launch map cannot place (a state
+# restored or adopted); and the durations read's bucket.
+INDEX_GATES = (
+    *(f"{family}_{gate}" for family in ("svc", "name", "ann", "bann")
+      for gate in ("watermark", "underfull")),
+    "gather_span", "gather_ann", "gather_bann", "gather_unmapped",
+    "durations")
+
+
 @jax.jit
 def _launch_marker(write_pos):
     """A scalar that is ready once the launch that produced
@@ -277,6 +313,16 @@ def _pad_rows(n: int) -> int:
     p = _next_pow2(n)
     rung = p // 4 * 3
     return rung if n <= rung and rung >= _LADDER_MIN else p
+
+
+def _side_rows_ordered(group) -> bool:
+    """Whether every part's annotation and binary rows come in span
+    order (owner gids ascending along the ring: the launch map's
+    binary search needs it)."""
+    return all(
+        bool(np.all(np.diff(b.ann_span_idx) >= 0))
+        and bool(np.all(np.diff(b.bann_span_idx) >= 0))
+        for b, _, _ in group)
 
 
 def name_lc_ids(batch: SpanBatch, dicts: DictionarySet,
@@ -469,8 +515,17 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         # /metrics): how often the fast path answered vs degraded to the
         # O(ring) scan kernels — the observable for the sparse-key
         # aliasing rate the per-key cursor table exists to shrink.
-        self.index_hits = 0
-        self.index_fallbacks = 0
+        # Counted from every reader thread (note_index).
+        self._index_lock = threading.Lock()  # lock-order: 91 index-stats (leaf)
+        # Reads with a launch on the device (_fetch): the device runs
+        # its queue in order, so an ingest launch waits behind every
+        # read dispatched before it. As many reads as ingest launches
+        # (RUN_AHEAD) may be queued; a read past them waits on the host.
+        self._read_slots = threading.BoundedSemaphore(self.RUN_AHEAD)
+        self.index_hits = 0  # guarded-by: _index_lock
+        self.index_fallbacks = 0  # guarded-by: _index_lock
+        self.row_index_hits = 0  # guarded-by: _index_lock
+        self.index_fallback_gates = dict.fromkeys(INDEX_GATES, 0)  # guarded-by: _index_lock
         # name_id -> lowercased-name id, maintained incrementally.
         self._name_lc: Dict[int, int] = {}
         # Telemetry (zipkin_tpu.obs): the device counter block is
@@ -484,11 +539,28 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
 
         reg = registry or obs.default_registry()
         self._registry = reg
+        # The read path's spans (query.rw_wait, query.device,
+        # query.decode) observe into this family: registered here, not
+        # at the first read, where reader threads would race for it.
+        stage_family(QUERY_FAMILY)
         # Launch dispatch is ASYNC under JAX, so a per-step wall clock
         # only measures host dispatch. Every launch leaves a marker
         # scalar behind and waits for the marker RUN_AHEAD launches
         # back (see _observe_ingest); the dispatch sketch keeps the
         # always-on host-side number.
+        # The launch map: where each launch still in the side rings put
+        # its rows (dev.LAUNCH_COLS), so a whole-trace read finds a
+        # span's annotation and binary rows without the side-row
+        # buckets (dev.iquery_launch_gather_rows). Appended in the
+        # commit's write hold; spans from ``_launch_floor`` on are
+        # covered (a state written another way starts the map anew).
+        self._launches: Deque[tuple] = deque()  # guarded-by: _rw
+        self._launch_floor = 0  # guarded-by: _rw
+        self._launch_next = (0, 0, 0)  # guarded-by: _rw
+        self._launch_memo: Optional[tuple] = None  # guarded-by: _rw
+        # The map's padded length: it only grows, so a read's program
+        # is compiled again only when the map outgrows every pad so far.
+        self._launch_pad = self.LAUNCH_PAD  # guarded-by: _rw
         self._h_ingest = reg.register(obs.LatencySketch(
             "zipkin_store_ingest_step_seconds",
             "A launch's dispatch until the device had run it, queue "
@@ -1065,7 +1137,8 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
             )
             return IngestUnit(db, b.n_spans, b.n_annotations,
                               b.n_binary, 1, False, sketch=sketch,
-                              reclaims=plan.reclaims if plan else ())
+                              reclaims=plan.reclaims if plan else (),
+                              ordered=_side_rows_ordered(group))
         pad_s = _next_pow2(max(b.n_spans for b, _, _ in group))
         pad_a = _pad_rows(max(b.n_annotations for b, _, _ in group))
         pad_b = _pad_rows(max(b.n_binary for b, _, _ in group))
@@ -1091,6 +1164,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
             sum(b.n_binary for b, _, _ in group),
             len(group), True, sketch=sketch,
             reclaims=plan.reclaims if plan else (),
+            ordered=_side_rows_ordered(group),
         )
 
     def _commit_unit(self, unit: IngestUnit) -> None:
@@ -1126,8 +1200,10 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
             # always pairs the device cut with exactly-matching clocks
             # — the invariant deterministic replay (wal/recovery)
             # rebuilds launches from.
-            with self._rw.write():
+            with self._rw.write(wait=stage("store.rw_write_wait",
+                                           unit=unit_id)):
                 self.state = step(self.state, unit.db)
+                self._note_launch(unit)
                 # Mirror BEFORE the frontier bump: a sketch-tier read
                 # at frontier F must already include commit F's delta.
                 if unit.sketch is not None:
@@ -1157,6 +1233,57 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
                 launched.inc(pad * unit.n_parts)
                 padding.inc(pad * unit.n_parts - valid)
             self._observe_ingest()
+
+    def _note_launch(self, unit: IngestUnit) -> None:  # called-under: _rw.write
+        """Record where ``unit``'s launch put its rows (before the
+        mirrors advance), and forget launches whose annotation AND
+        binary rows both rings have overwritten since."""
+        if self._planner is not None:
+            return  # paged gids: whole traces go through page chains
+        c = self.config
+        if self._launch_next != (self._wp, self._awp, self._bwp):
+            self._launches.clear()  # a state written another way
+            self._launch_floor = self._wp
+        a1, b1 = self._awp + unit.n_anns, self._bwp + unit.n_banns
+        self._launches.append((self._wp, self._awp, a1, self._bwp, b1,
+                               unit.ordered))
+        self._launch_next = (self._wp + unit.n_spans, a1, b1)
+        self._launch_memo = None
+        while (self._launches
+               and self._launches[0][2] <= a1 - c.ann_capacity
+               and self._launches[0][4] <= b1 - c.bann_capacity):
+            self._launches.popleft()
+
+    # Launches the map's arrays hold before they grow: more than the side
+    # rings hold of the benchmark's 2048-span calls (683 and 1,024).
+    LAUNCH_PAD = 4096
+
+    def _launch_map_current(self) -> bool:  # called-under: _rw.read
+        """Whether the map ends where the state's rows do: a state
+        restored or adopted (its mirrors set anew) is not the one the
+        map describes, until the next commit starts the map over."""
+        return self._launch_next == (self._wp, self._awp, self._bwp)
+
+    def _launch_arrays(self):  # called-under: _rw.read
+        """(dev.LAUNCH_COLS arrays padded to ``_launch_pad``, floor) of
+        the snapshot being taken: memoized until the next commit. A map
+        that is not current places no span (floor: the write frontier).
+        Read off the lock (``dev.compiling``), only the padded shape."""
+        if dev.compiling() or not self._launch_map_current():
+            z = self._launch_pad
+            empty = np.zeros((6, z), np.int64)
+            empty[0] = dev.I64_MAX
+            return (*empty[:5], empty[5] > 0), self._wp
+        if self._launch_memo is None:
+            n = len(self._launches)
+            z = self._launch_pad = max(self._launch_pad, _next_pow2(n + 1))
+            cols = np.zeros((6, z), np.int64)
+            cols[0] = dev.I64_MAX
+            if n:
+                cols[:, :n] = np.asarray(self._launches, np.int64).T
+            arrs = (*cols[:5], cols[5] > 0)
+            self._launch_memo = (arrs, self._launch_floor)
+        return self._launch_memo
 
     def _write_device_many(self, group) -> None:
         """One chained launch over ≥2 chunks: pad every chunk to the
@@ -1683,6 +1810,8 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         mirror's lock — same order, no inversion)."""
         m = self.sketch_mirror
         if not m.warm:
+            # Held through the fetch: the mirror must adopt exactly the
+            # state the next commit's delta applies to.
             with self._rw.read():
                 st = self.state
                 host = jax.device_get((
@@ -1693,6 +1822,63 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
                 ))
                 m.adopt(*host)
         return m
+
+    # -- the read path's snapshot ----------------------------------------
+
+    def _fetch(self, launch):
+        """``launch(state)``'s device results on the host. The read
+        lock is held only to take the snapshot and dispatch: the
+        result is waited for outside it, so the commit's donating swap
+        never waits on a device queue for a reader (the device runs the
+        read before any launch dispatched after it, and a result lives
+        in buffers of its own; a launch must not return a state leaf
+        itself, ``_fresh`` copies one). First ``launch`` runs on the
+        state's shapes alone (``dev.compile_only``): what it will run
+        compiles there, off the lock, so the commit never waits behind
+        a compile (``query.compile`` times it; a launch must take every
+        state leaf through a ReadProgram). At most RUN_AHEAD reads have a
+        launch queued on the device (``_read_slots``). ``query.rw_wait``
+        times the wait for a slot and the lock, ``query.device``
+        dispatch until the result is on the host, the wait behind
+        queued ingest launches included."""
+        with query_stage("query.compile"), dev.compile_only():
+            launch(dev.shapes_of(self.state))
+        device = query_stage("query.device")
+        with query_stage("query.rw_wait") as wait, self._read_slots:
+            with self._rw.read():
+                wait.done()
+                device.__enter__()
+                try:
+                    out = launch(self.state)
+                except BaseException:
+                    device.done()
+                    raise
+            try:
+                return jax.device_get(out)
+            finally:
+                device.done()
+
+    def note_index(self, hit: bool, gate: str = "",
+                   lookup: bool = True) -> None:
+        """One index-tier read's outcome: answered from the index, or
+        sent to the O(ring) scan by ``gate`` (one of INDEX_GATES). An id
+        lookup counts in ``index_hits`` / ``index_fallbacks``; a
+        whole-trace or durations read (``lookup`` False) in
+        ``row_index_hits`` or its gate's count alone."""
+        with self._index_lock:
+            if hit and lookup:
+                self.index_hits += 1
+            elif hit:
+                self.row_index_hits += 1
+            else:
+                self.index_fallbacks += lookup
+                self.index_fallback_gates[gate] += 1
+
+    @staticmethod
+    def _cands(mat) -> list:
+        """[(trace id, ts)] of a fetched [3, k] candidate matrix."""
+        return [(int(t), int(ts))
+                for t, ts, v in zip(mat[0], mat[1], mat[2]) if v]
 
     # -- windowed analytics (aggregate/windows.py) ----------------------
     # windowed_quantiles / slo_burn / latency_heatmap come from the
@@ -1720,26 +1906,16 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         else:
             name_lc = -1
 
-        def fetch(k):
-            with self._rw.read():
-                mat = jax.device_get(dev.query_trace_ids_by_service(
-                    self.state, svc, name_lc, end_ts, k
-                ))
-            cands = [(int(t), int(ts))
-                     for t, ts, v in zip(mat[0], mat[1], mat[2]) if v]
-            return cands, len(cands) >= k
-
         def index_fetch(k):
-            with self._rw.read():
-                mat, complete, wm = jax.device_get(
-                    dev.iquery_trace_ids_by_service(
-                        self.state, svc, name_lc, end_ts, k
-                    )
-                )
-            cands = [(int(t), int(ts))
-                     for t, ts, v in zip(mat[0], mat[1], mat[2]) if v]
-            return cands, bool(complete), int(wm), mat.shape[1]
+            mat, complete, wm = self._fetch(
+                lambda st: dev.iquery_trace_ids_by_service(
+                    st, svc, name_lc, end_ts, k))
+            return (self._cands(mat), bool(complete), int(wm),
+                    mat.shape[1])
 
+        fetch = self._scan_fetch(
+            lambda st, k: dev.query_trace_ids_by_service(
+                st, svc, name_lc, end_ts, k))
         # Paged layout: the index read gates (wm < write_pos -
         # capacity trust checks) are FIFO-gid arithmetic, unsound
         # against epoch-encoded gids — id lookups take the exact
@@ -1748,16 +1924,27 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         if (self.config.use_index and not scan_only
                 and self._planner is None):
             return self._index_first(
-                limit, self.config.ann_capacity, index_fetch, fetch
-            )
-        return topk_ids_with_escalation(
-            limit, self.config.ann_capacity, fetch
-        )
+                limit, "svc" if name_lc < 0 else "name", index_fetch,
+                fetch)
+        return topk_ids_with_escalation(limit, self.config.capacity, fetch)
 
-    def _index_first(self, limit, k_max, index_fetch, scan_fetch):
-        """index_first_topk with hit/fallback accounting (→ /metrics)."""
-        return index_first_topk(limit, k_max, index_fetch, scan_fetch,
-                                stats=self)
+    def _scan_fetch(self, launch):
+        """``fetch(k)`` of topk_ids_with_escalation over an O(ring)
+        scan kernel, ``launch(state, k)``: one candidate a span, so
+        never more than the span ring holds."""
+        def fetch(k):
+            cands = self._cands(self._fetch(lambda st: launch(st, k)))
+            return cands, len(cands) >= k
+        return fetch
+
+    def _index_first(self, limit, family, index_fetch, scan_fetch):
+        """index_first_topk with hit/fallback accounting by candidate
+        family (→ /metrics)."""
+        def note(hit, gate=""):
+            self.note_index(hit, f"{family}_{gate}" if gate else "")
+
+        return index_first_topk(limit, self.config.capacity, index_fetch,
+                                scan_fetch, note=note)
 
     def get_trace_ids_by_annotation(
         self, service_name: str, annotation: str, value: Optional[bytes],
@@ -1774,29 +1961,20 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
             return []
         ann_value, bann_key, bann_value, bann_value2 = resolved
 
-        def fetch(k):
-            with self._rw.read():
-                mat = jax.device_get(dev.query_trace_ids_by_annotation(
-                    self.state, svc, ann_value, bann_key, bann_value,
-                    bann_value2, end_ts, k,
-                ))
-            cands = [(int(t), int(ts))
-                     for t, ts, v in zip(mat[0], mat[1], mat[2]) if v]
-            return cands, len(cands) >= k
-
         def index_fetch(k):
-            with self._rw.read():
-                mat, complete, wm = jax.device_get(
-                    dev.iquery_trace_ids_by_annotation(
-                        self.state, svc, ann_value, bann_key, bann_value,
-                        bann_value2, end_ts, k,
-                    )
-                )
-            cands = [(int(t), int(ts))
-                     for t, ts, v in zip(mat[0], mat[1], mat[2]) if v]
-            return cands, bool(complete), int(wm), mat.shape[1]
+            mat, complete, wm = self._fetch(
+                lambda st: dev.iquery_trace_ids_by_annotation(
+                    st, svc, ann_value, bann_key, bann_value, bann_value2,
+                    end_ts, k))
+            return (self._cands(mat), bool(complete), int(wm),
+                    mat.shape[1])
 
+        fetch = self._scan_fetch(
+            lambda st, k: dev.query_trace_ids_by_annotation(
+                st, svc, ann_value, bann_key, bann_value, bann_value2,
+                end_ts, k))
         c = self.config
+
         # A name present BOTH as a user-annotation value and as a
         # binary key matches through either side in the scan (OR
         # semantics); the index families are per-side, so the rare
@@ -1805,12 +1983,9 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         if (c.use_index and not mixed and not scan_only
                 and self._planner is None):
             return self._index_first(
-                limit, c.ann_capacity + c.bann_capacity, index_fetch,
-                fetch,
-            )
-        return topk_ids_with_escalation(
-            limit, c.ann_capacity + c.bann_capacity, fetch
-        )
+                limit, "ann" if ann_value >= 0 else "bann", index_fetch,
+                fetch)
+        return topk_ids_with_escalation(limit, c.capacity, fetch)
 
     def get_trace_ids_multi(self, queries) -> List[List[IndexedTraceId]]:
         """Batched index read: every query's bucket probe rides ONE
@@ -1826,28 +2001,25 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         )
         if probes:
             arrs, k, k_eff = build_probe_arrays(c, probes, limits)
-            with self._rw.read():
-                mats, completes, wms = jax.device_get(
-                    dev.iquery_trace_ids_multi(self.state, arrs, k)
-                )
-            per_probe = []
-            for pi, p in enumerate(probes):
-                mat = mats[pi]
-                cands = [
-                    (int(t), int(ts))
-                    for t, ts, v in zip(mat[0], mat[1], mat[2]) if v
-                ]
-                window_pi = min(k_eff, p[1][3])
-                per_probe.append((
-                    cands, bool(completes[pi]), int(wms[pi]),
-                    len(cands) >= window_pi,
-                ))
-            gated = gate_multi_probes(probes, limits, per_probe)
+            mats, completes, wms = self._fetch(
+                lambda st: dev.iquery_trace_ids_multi(st, arrs, k))
+            with query_stage("query.decode"):
+                per_probe = []
+                for pi, p in enumerate(probes):
+                    cands = self._cands(mats[pi])
+                    window_pi = min(k_eff, p[1][3])
+                    per_probe.append((
+                        cands, bool(completes[pi]), int(wms[pi]),
+                        len(cands) >= window_pi,
+                    ))
+                gated = gate_multi_probes(probes, limits, per_probe)
+            # A query the batch could not prove goes to its singular
+            # path, which probes again and counts its own outcome.
             for qi, ids in gated.items():
                 if ids is None:
                     fallback.append(qi)
                 else:
-                    self.index_hits += 1
+                    self.note_index(True)
                     results[qi] = ids
         for qi in fallback:
             q = queries[qi]
@@ -1877,14 +2049,13 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
     def _durations_mat(self, qids: np.ndarray) -> np.ndarray:
         """[4, nq] duration matrix: trace-membership fast path when its
         exactness gate holds, the full-ring scan otherwise."""
-        with self._rw.read():
-            if self.config.use_index and self._planner is None:
-                mat, exact = jax.device_get(
-                    dev.iquery_durations(self.state, qids)
-                )
-                if exact:
-                    return mat
-            return jax.device_get(dev.query_durations(self.state, qids))
+        if self.config.use_index and self._planner is None:
+            mat, exact = self._fetch(
+                lambda st: dev.iquery_durations(st, qids))
+            self.note_index(bool(exact), "durations", lookup=False)
+            if exact:
+                return mat
+        return self._fetch(lambda st: dev.query_durations(st, qids))
 
     def traces_exist(self, trace_ids: Sequence[int]) -> Set[int]:
         if not trace_ids:
@@ -1899,22 +2070,20 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         """Shared ring gather for whole-trace reads: (n_s, n_a, n_b,
         span_mat, ann_mat, bann_mat)."""
         qids = self._sorted_qids(trace_ids)
-        with self._rw.read():
-            st = self.state
-            payload = None
-            if self.config.use_index and self._planner is None:
-                payload = self._gather_via_index(st, qids)
-            if self._planner is not None:
-                payload = self._gather_via_pages(st, qids)
-            if payload is None:
-                def fetch(k_s, k_a, k_b):
-                    counts, s_m, a_m, b_m = jax.device_get(
-                        dev.gather_trace_rows(st, qids, k_s, k_a, k_b)
-                    )
-                    n_s, n_a, n_b = (int(x) for x in counts)
-                    return n_s, n_a, n_b, (n_s, n_a, n_b, s_m, a_m, b_m)
+        payload = None
+        if self.config.use_index and self._planner is None:
+            payload = self._gather_via_index(qids)
+        if self._planner is not None:
+            payload = self._gather_via_pages(qids)
+        if payload is None:
+            def fetch(k_s, k_a, k_b):
+                counts, s_m, a_m, b_m = self._fetch(
+                    lambda st: dev.gather_trace_rows(st, qids, k_s, k_a,
+                                                     k_b))
+                n_s, n_a, n_b = (int(x) for x in counts)
+                return n_s, n_a, n_b, (n_s, n_a, n_b, s_m, a_m, b_m)
 
-                payload = gather_with_escalation(self.config, fetch)
+            payload = gather_with_escalation(self.config, fetch)
         return payload
 
     def get_trace_rows(self, trace_ids: Sequence[int]
@@ -1930,11 +2099,12 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
             self._gather_trace_mats(trace_ids))
         if n_s == 0:
             return []
-        batch, gids = mats_to_batch(
-            n_s, n_a, n_b, span_mat, ann_mat, bann_mat)
-        return [
-            (int(g), s) for g, s in zip(gids, self.codec.decode(batch))
-        ]
+        with query_stage("query.decode"):
+            batch, gids = mats_to_batch(
+                n_s, n_a, n_b, span_mat, ann_mat, bann_mat)
+            return [
+                (int(g), s) for g, s in zip(gids, self.codec.decode(batch))
+            ]
 
     def get_spans_by_trace_ids(self, trace_ids: Sequence[int]
                                ) -> List[List[Span]]:
@@ -1942,9 +2112,10 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
             return []
         n_s, n_a, n_b, span_mat, ann_mat, bann_mat = (
             self._gather_trace_mats(trace_ids))
-        spans = self._decode_gathered(
-            n_s, n_a, n_b, span_mat, ann_mat, bann_mat
-        )
+        with query_stage("query.decode"):
+            spans = self._decode_gathered(
+                n_s, n_a, n_b, span_mat, ann_mat, bann_mat
+            )
         by_tid: Dict[int, List[Span]] = {}
         for span in spans:
             by_tid.setdefault(span.trace_id, []).append(span)
@@ -1967,7 +2138,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
             self.codec, n_s, n_a, n_b, span_mat, ann_mat, bann_mat
         )
 
-    def _gather_via_pages(self, st, qids: np.ndarray):
+    def _gather_via_pages(self, qids: np.ndarray):
         """Whole-trace gather over the queried traces' PAGE CHAINS —
         the paged layout's answer to the index gather: the kernel
         touches K·page_rows candidate rows
@@ -1987,31 +2158,68 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         ep[:len(epochs)] = epochs
 
         def fetch(k_s, k_a, k_b):
-            counts, s_m, a_m, b_m = jax.device_get(
-                dev.gather_paged_trace_rows(st, qids, pg, ep,
-                                            k_s, k_a, k_b)
-            )
+            counts, s_m, a_m, b_m = self._fetch(
+                lambda st: dev.gather_paged_trace_rows(
+                    st, qids, pg, ep, k_s, k_a, k_b))
             n_s, n_a, n_b = (int(x) for x in counts)
             return n_s, n_a, n_b, (n_s, n_a, n_b, s_m, a_m, b_m)
 
         return gather_with_escalation(self.config, fetch)
 
-    def _gather_via_index(self, st, qids: np.ndarray):
-        """Whole-trace gather through the trace-membership buckets (see
-        dev.iquery_gather_trace_rows). Returns the gather payload, or
-        None when any queried bucket fails its exactness gate — the
-        caller then runs the full-ring scan gather."""
-        from zipkin_tpu.store.base import index_gather_with_escalation
+    # Side rows a span may have before a whole-trace read through the
+    # launch map gives up on the index: the first read allows these,
+    # a span with more is read again at the second.
+    _PER_SPAN_ROWS = ((8, 4), (64, 32))
 
-        def fetch(k_s, k_a, k_b):
-            counts, s_m, a_m, b_m, exact = jax.device_get(
-                dev.iquery_gather_trace_rows(st, qids, k_s, k_a, k_b)
-            )
-            n_s, n_a, n_b = (int(x) for x in counts)
-            return (bool(exact), n_s, n_a, n_b,
-                    (n_s, n_a, n_b, s_m, a_m, b_m))
+    def _gather_via_index(self, qids: np.ndarray):
+        """Whole-trace gather through the trace-membership span buckets
+        and the launch map (see dev.iquery_launch_gather_rows). Returns
+        the gather payload, or None when a span has more side rows than
+        the widest read allows or the launch map cannot place it — the
+        caller then runs the full-ring scan gather (so does a span of a
+        state restored or adopted since the map began). A queried span
+        bucket that fails its exactness gate is answered by a scan of
+        the span ring and the launch map (a fallback all the same)."""
+        payload, gate = self._gather_via_launches(qids, False)
+        self.note_index(gate is None, gate or "", lookup=False)
+        if gate == "gather_span":
+            payload, gate = self._gather_via_launches(qids, True)
+        return payload if gate is None else None
 
-        return index_gather_with_escalation(self.config, len(qids), fetch)
+    def _gather_via_launches(self, qids: np.ndarray, scan: bool):
+        """(payload, None), or (None, the gate that refused): a span
+        bucket ("gather_span"; never with ``scan``, which reads the span
+        ring), a span the launch map cannot place ("gather_unmapped"),
+        one with more annotation or binary rows than the widest read
+        allows ("gather_ann", "gather_bann")."""
+        from zipkin_tpu.store.base import GATHER_K0, escalate_cap
+
+        c = self.config
+        cands = c.capacity if scan else len(qids) * c.TRACE_SPAN_DEPTH
+        for per_a, per_b in self._PER_SPAN_ROWS:
+            most = (cands, min(cands * per_a, c.ann_capacity),
+                    min(cands * per_b, c.bann_capacity))
+            k = [min(GATHER_K0, most[0]), min(2 * GATHER_K0, most[1]),
+                 min(GATHER_K0, most[2])]
+            while True:
+                def launch(st, k=tuple(k)):
+                    arrs, floor = self._launch_arrays()
+                    return dev.iquery_launch_gather_rows(
+                        st, qids, arrs, floor, *k, per_a, per_b, scan)
+
+                counts, s_m, a_m, b_m, flags = self._fetch(launch)
+                n = [int(x) for x in counts]
+                if all(x <= kx for x, kx in zip(n, k)):
+                    break
+                k = [escalate_cap(x, kx, m) for x, kx, m in zip(n, k, most)]
+            span_ok, known, fit_a, fit_b = (bool(f) for f in flags)
+            if not span_ok:
+                return None, "gather_span"
+            if not known:
+                return None, "gather_unmapped"
+            if fit_a and fit_b:
+                return (*n, s_m, a_m, b_m), None
+        return None, "gather_ann" if not fit_a else "gather_bann"
 
     def get_traces_duration(
         self, trace_ids: Sequence[int]
@@ -2027,8 +2235,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
     # -- name catalogs --------------------------------------------------
 
     def get_all_service_names(self) -> Set[str]:
-        with self._rw.read():
-            present = jax.device_get(self.state.ann_svc_counts) > 0
+        present = self._fetch(lambda st: _fresh(st.ann_svc_counts)) > 0
         d = self.dicts.services
         out = {
             d.decode(i) for i in np.flatnonzero(present)
@@ -2043,10 +2250,8 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         n_over = len(d) - S
         if n_over > 0:
             pad = 1 << max(0, (n_over - 1)).bit_length()
-            with self._rw.read():
-                pres = jax.device_get(
-                    dev.overflow_service_presence(self.state, pad)
-                )
+            pres = self._fetch(
+                lambda st: dev.overflow_service_presence(st, pad))
             out.update(
                 name for i in np.flatnonzero(pres[:n_over])
                 if (name := d.decode(S + int(i)))
@@ -2064,13 +2269,18 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         memo keyed on (svc, write position) lets a UI service page that
         calls all four endpoints pay ONE O(ring) scan + D2H instead of
         four."""
-        key = (svc, self._wp)
         cached = getattr(self, "_svc_scan_memo", None)
-        if cached is not None and cached[0] == key:
+        if cached is not None and cached[0] == (svc, self._wp):
             return cached[1]
-        with self._rw.read():
-            rows = jax.device_get(dev.svc_scan_catalog(self.state, svc))
-        self._svc_scan_memo = (key, rows)
+        key = []
+
+        def launch(st):
+            if not dev.compiling():
+                key.append((svc, self._wp))  # the snapshot's position
+            return dev.svc_scan_catalog(st, svc)
+
+        rows = self._fetch(launch)
+        self._svc_scan_memo = (key[0], rows)
         return rows
 
     def get_span_names(self, service: str) -> Set[str]:
@@ -2080,8 +2290,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         if service_scan_only(svc, self.config):
             row = self._svc_catalog_scan(svc)[0] > 0
         else:
-            with self._rw.read():
-                row = jax.device_get(self.state.name_presence[svc]) > 0
+            row = self._fetch(lambda st: _row(st.name_presence, svc)) > 0
         d = self.dicts.span_names
         return {
             d.decode(i) for i in np.flatnonzero(row)
@@ -2106,40 +2315,37 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
                     self._sweep_pending()
         S = self.config.max_services
         k = min(S * S, 1 << 14)
-        with self._rw.read():
-            st = self.state
+        s = dev.I64_MIN if start_ts is None else int(start_ts)
+        e = dev.I64_MAX if end_ts is None else int(end_ts)
+        windowed = start_ts is not None or end_ts is not None
+
+        def launch(st, full: bool):
             # Device-side compaction: ship the k densest link cells
             # (~400 KB) instead of the full [S*S, 5] bank (~20 MB —
             # the D2H was the whole dependencies p99). If more
             # than k links are live, transfer the full bank instead:
             # compaction never drops a link.
-            if start_ts is None and end_ts is None:
-                nz, idx, rows, ts_min, ts_max = jax.device_get((
-                    *dev.total_dep_moments_compact(
-                        st.dep_moments, st.dep_banks, st.dep_window, k
-                    ),
-                    st.ts_min, st.ts_max,
-                ))
-                if int(nz) > k:
-                    rows = None
-                    bank = jax.device_get(dev.total_dep_moments(st))
+            ts = _clip_ts(st.ts_min, st.ts_max, jnp.int64(s), jnp.int64(e))
+            if not windowed:
+                body = ((dev.total_dep_moments(st),) if full else
+                        dev.total_dep_moments_compact(
+                            st.dep_moments, st.dep_banks, st.dep_window,
+                            k))
+            elif full:
+                body = (dev.dep_moments_in_range(
+                    st, jnp.int64(s), jnp.int64(e)),)
             else:
-                s = dev.I64_MIN if start_ts is None else int(start_ts)
-                e = dev.I64_MAX if end_ts is None else int(end_ts)
-                nz, idx, rows, ts_min, ts_max = jax.device_get((
-                    *dev.dep_in_range_compact(
-                        st.dep_moments, st.dep_banks, st.dep_bank_ts,
-                        st.dep_overflow_ts, st.dep_window,
-                        st.dep_window_ts, jnp.int64(s), jnp.int64(e), k,
-                    ),
-                    jnp.maximum(st.ts_min, jnp.int64(s)),
-                    jnp.minimum(st.ts_max, jnp.int64(e)),
-                ))
-                if int(nz) > k:
-                    rows = None
-                    bank = jax.device_get(dev.dep_moments_in_range(
-                        st, jnp.int64(s), jnp.int64(e)
-                    ))
+                body = dev.dep_in_range_compact(
+                    st.dep_moments, st.dep_banks, st.dep_bank_ts,
+                    st.dep_overflow_ts, st.dep_window, st.dep_window_ts,
+                    jnp.int64(s), jnp.int64(e), k)
+            return (*body, *ts)
+
+        nz, idx, rows, ts_min, ts_max = self._fetch(
+            lambda st: launch(st, False))
+        if int(nz) > k:
+            rows = None
+            bank, ts_min, ts_max = self._fetch(lambda st: launch(st, True))
         if rows is not None:
             bank = np.zeros((S * S, rows.shape[1]), np.float32)
             bank[idx] = rows
@@ -2173,10 +2379,11 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
             c = self.config
             gamma = (1.0 + c.quantile_alpha) / (1.0 - c.quantile_alpha)
             return Q.quantiles_host(counts, gamma, 1.0, qs)
-        with self._rw.read():
-            hist = dev.svc_histogram(self.state)
-            counts = jax.device_get(hist.counts[svc])
-        return Q.quantiles_host(counts, hist.gamma, hist.min_value, qs)
+        # dev.svc_histogram's row: the state's histogram under its gamma.
+        c = self.config
+        gamma = (1.0 + c.quantile_alpha) / (1.0 - c.quantile_alpha)
+        counts = self._fetch(lambda st: _row(st.svc_hist, svc))
+        return Q.quantiles_host(counts, gamma, 1.0, qs)
 
     def top_annotations(self, service: str, k: int = 10) -> List[Tuple[str, int]]:
         svc = self._svc_id(service)
@@ -2185,8 +2392,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         if service_scan_only(svc, self.config):
             row = self._svc_catalog_scan(svc)[2]
         else:
-            with self._rw.read():
-                row = jax.device_get(self.state.ann_value_counts[svc])
+            row = self._fetch(lambda st: _row(st.ann_value_counts, svc))
         order = np.argsort(-row)[:k]
         d = self.dicts.annotations
         return [
@@ -2202,8 +2408,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         if service_scan_only(svc, self.config):
             row = self._svc_catalog_scan(svc)[3]
         else:
-            with self._rw.read():
-                row = jax.device_get(self.state.bann_key_counts[svc])
+            row = self._fetch(lambda st: _row(st.bann_key_counts, svc))
         order = np.argsort(-row)[:k]
         d = self.dicts.binary_keys
         return [
@@ -2212,8 +2417,7 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         ]
 
     def estimated_unique_traces(self) -> float:
-        with self._rw.read():
-            regs = jax.device_get(self.state.hll_traces)
+        regs = self._fetch(lambda st: _fresh(st.hll_traces))
         return float(hll.estimate(hll.HyperLogLog(regs)))
 
     def counter_block(self) -> Dict[str, int]:
@@ -2278,8 +2482,13 @@ class TpuSpanStore(WindowedAnalytics, SpanStore):
         # /metrics reads counters() generically).
         out["anns_truncated"] = float(self.anns_truncated)
         out["banns_truncated"] = float(self.banns_truncated)
-        out["index_hits"] = float(self.index_hits)
-        out["index_scan_fallbacks"] = float(self.index_fallbacks)
+        with self._index_lock:
+            out["index_hits"] = float(self.index_hits)
+            out["index_scan_fallbacks"] = float(self.index_fallbacks)
+            out["row_index_hits"] = float(self.row_index_hits)
+            # Which gate sent each fallback to the scan (INDEX_GATES).
+            out.update((f"index_fallback_{g}", float(n))
+                       for g, n in self.index_fallback_gates.items())
         # jit cache-miss tracking for the ingest/staging jits: a warmed
         # pipelined steady state must hold this flat (bench_smoke's
         # pipeline phase gates the delta at zero).

@@ -54,7 +54,11 @@ class ScribeRig:
             process_thrift=self.collector.ingest_thrift_durable)
         self.server = ScribeServer(self.receiver, host="127.0.0.1", port=0)
         self.server.serve_in_thread()
-        self.client = ScribeClient(*self.server.server_address)
+        # A first call compiles the fused step under the store's lock:
+        # on a loaded test host that takes longer than the client's
+        # default 10 s.
+        self.client = ScribeClient(*self.server.server_address,
+                                   timeout_s=120.0)
 
     def log(self, spans: Sequence[Span]) -> ResultCode:
         return self.client.log(log_entries(spans))
